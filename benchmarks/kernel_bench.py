@@ -1,4 +1,4 @@
-"""Kernel micro-benchmarks: GF(2) bit-matrix RS encode (Pallas, interpret)
+"""Kernel micro-benchmarks: GF(2) bit-matrix RS encode (Pallas)
 vs the table-based GF(256) jnp oracle, plus the unified codec engine's
 batched-throughput sweep (backend × batch × (n, k)).
 
@@ -36,7 +36,7 @@ def bench_gf2mm(n: int = 12, k: int = 6, B: int = 16384) -> list[str]:
     jdata = jnp.asarray(data)
 
     # jit the wrapper so both timed paths measure pure device dispatch
-    enc = jax.jit(lambda d: ops.rs_encode(d, n=n, k=k, interpret=True))
+    enc = jax.jit(lambda d: ops.rs_encode(d, n=n, k=k))
     enc(jdata).block_until_ready()
     with BenchTimer("kernel_rs_encode_pallas", calls=3) as t1:
         for _ in range(3):

@@ -19,6 +19,9 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true", help="reduced request counts")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import kernel_bench, paper_figures
 
     benches = list(paper_figures.ALL_FIGS) + list(kernel_bench.ALL_KERNEL)

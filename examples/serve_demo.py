@@ -7,9 +7,8 @@ model, tolerating injected read failures; the LM then prefills + decodes.
 The fetch runs twice: once on the unfused path (the proxy batch-decodes
 completions per admission round on the host codec) and once through the
 fused serving step — one jitted launch running the TOFEC admission update
-AND the batched MDS decode for the whole round. The fused step's codec
-backend follows ``REPRO_CODEC_BACKEND`` when that names a jitted backend
-(jnp / pallas) and falls back to jnp otherwise.
+AND the batched MDS decode for the whole round. The fused step runs the
+Pallas codec: the compiled kernel on a TPU, the interpreter on the CPU.
 
 ``--closed-loop`` runs the full serving tower instead: a ClosedLoopServer
 whose single jitted step covers admission update → batched decode →
@@ -71,9 +70,7 @@ def _setup(fast: bool, p_fail: float = 0.15):
 
     cls = RequestClass("prompt", prompt_len * 4 / 2**20, PAPER_READ_3MB,
                        k_max=4, r_max=2.0, n_max=8)
-    codec = get_codec()
-    if not codec.backend.jitted:  # numpy default is host-only; fuse on jnp
-        codec = get_codec("jnp")
+    codec = get_codec("pallas")
     return eng, layout, inner, store, keys, cls, codec, prompt_len, rng
 
 

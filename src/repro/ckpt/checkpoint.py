@@ -3,8 +3,8 @@
 Every checkpoint leaf (one array of the params/opt-state pytree) is:
   1. serialized (raw bytes + dtype/shape manifest entry, crc32 checksum),
   2. RS-encoded into n strips of size ⌈bytes/k⌉ through the unified batched
-     codec engine (:mod:`repro.coding.codec` — numpy / jnp / Pallas backend
-     per ``REPRO_CODEC_BACKEND``); leaves sharing an (n, k) plan are encoded
+     codec engine (:mod:`repro.coding.codec` — the Pallas kernel on a TPU,
+     the numpy oracle elsewhere); leaves sharing an (n, k) plan are encoded
      in ONE batched kernel call,
   3. written as n independent objects ``{prefix}/step{s}/{leaf}/strip{i}``.
 

@@ -26,10 +26,11 @@ Backends (registry-selected):
   (:func:`repro.kernels.gf2mm.gf2mm.gf2_rs_matmul_bytes`), batched over the
   grid with bitplane pack/unpack fused into the kernel.
 
-Selection: ``get_codec("jnp")`` explicitly, or ``get_codec()`` which reads
-``REPRO_CODEC_BACKEND`` (default ``numpy``). ``REPRO_PALLAS_INTERPRET=1``
-(the default in CPU containers) runs the Pallas backend in interpret mode;
-set it to 0 on real TPUs.
+Selection: ``get_codec("jnp")`` explicitly, or ``get_codec()``, which takes
+``REPRO_CODEC_BACKEND`` when it is set and otherwise follows the device:
+``pallas`` on a TPU, the ``numpy`` oracle elsewhere. The Pallas backend runs
+the interpreter on the CPU only (:func:`repro.kernels.gf2mm.gf2mm.
+resolve_interpret`); on a TPU it always runs the compiled kernel.
 
 Consumers: :mod:`repro.coding.layout` (file encode/reconstruct),
 :mod:`repro.storage.proxy` (batched write-queue encode per admission round),
@@ -66,19 +67,16 @@ def pow2_bucket(x: int, floor: int = 1) -> int:
     return b
 
 
-def default_pallas_interpret() -> bool:
-    """Resolve REPRO_PALLAS_INTERPRET (default on: CPU containers). The one
-    place this env var is parsed — backend, instance cache and the gf2mm ops
-    module all share it."""
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+def _resolve_interpret(interpret: bool | None) -> bool:
+    from repro.kernels.gf2mm.gf2mm import resolve_interpret
+
+    return resolve_interpret(interpret)
 
 
 def _is_traced(x) -> bool:
     """True when x is a JAX tracer (call made inside jit/vmap/grad)."""
-    try:
-        from jax.core import Tracer
-    except ImportError:  # pragma: no cover
-        return False
+    from jax.core import Tracer
+
     return isinstance(x, Tracer)
 
 
@@ -214,9 +212,7 @@ class PallasBackend(_Backend):
 
     def __init__(self, stats: CodecStats, interpret: bool | None = None):
         super().__init__(stats)
-        if interpret is None:
-            interpret = default_pallas_interpret()
-        self.interpret = interpret
+        self.interpret = _resolve_interpret(interpret)
 
     def _build(self, k: int):
         import jax
@@ -488,22 +484,25 @@ def available_backends() -> list[str]:
 
 
 def default_backend() -> str:
-    return os.environ.get("REPRO_CODEC_BACKEND", "numpy")
+    """``REPRO_CODEC_BACKEND`` when set; otherwise the Pallas kernel on a TPU
+    and the numpy oracle on any other backend."""
+    env = os.environ.get("REPRO_CODEC_BACKEND")
+    if env:
+        return env
+    import jax
+
+    return "pallas" if jax.default_backend() == "tpu" else "numpy"
 
 
 def get_codec(backend: str | None = None, *, interpret: bool | None = None) -> Codec:
     """Process-wide codec instance per (backend, resolved interpret) pair.
 
-    ``interpret`` only applies to the pallas backend; ``None`` resolves to
-    the ``REPRO_PALLAS_INTERPRET`` env default, so explicit and defaulted
-    callers share one instance (and its jit caches).
+    ``interpret`` only applies to the pallas backend; ``None`` resolves from
+    the device at first use (interpreter on the CPU only), so explicit and
+    defaulted callers share one instance (and its jit caches).
     """
     name = backend or default_backend()
-    if name == "pallas":
-        if interpret is None:
-            interpret = default_pallas_interpret()
-    else:
-        interpret = None
+    interpret = _resolve_interpret(interpret) if name == "pallas" else None
     key = (name, interpret)
     with _INSTANCES_LOCK:
         if key not in _INSTANCES:

@@ -11,8 +11,8 @@ object (cost r × file size) supports every chunking level, vs. Unique-Key's
 extra r × file size *per chunk size* (§III-A.1).
 
 Encode/decode route through the unified batched codec engine
-(:mod:`repro.coding.codec`); the backend follows ``REPRO_CODEC_BACKEND``
-(numpy oracle by default, ``jnp`` / ``pallas`` for bulk batched paths) and
+(:mod:`repro.coding.codec`); the backend is the codec default (Pallas on a
+TPU, the numpy oracle elsewhere, ``REPRO_CODEC_BACKEND`` to override) and
 can be overridden per call. :func:`encode_files` amortizes one kernel
 launch over a whole batch of same-class files — the proxy's write-queue
 drain uses it — and :func:`reconstruct_batch` is its read-side mirror: one

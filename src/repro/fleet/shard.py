@@ -79,12 +79,16 @@ def shard_grid(fn, mesh, in_axes: tuple):
     come back sharded on the grid axis. The wrapped body must consume
     positional args matching ``in_axes`` one-for-one.
     """
-    from jax.experimental.shard_map import shard_map
+    import jax
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]
     in_specs = tuple(P(axis) if ax == 0 else P() for ax in in_axes)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=P(axis))
+    # Rows are independent and the body runs no collectives, so there is no
+    # cross-device variance to track; the scan carries in the engines build
+    # their initial state from constants, which vma checking would reject.
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=P(axis),
+                         check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)

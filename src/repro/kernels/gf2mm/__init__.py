@@ -1,10 +1,10 @@
-from repro.kernels.gf2mm.gf2mm import gf2_matmul, gf2_rs_matmul_bytes, tpu_compiler_params
+from repro.kernels.gf2mm.gf2mm import gf2_matmul, gf2_rs_matmul_bytes, resolve_interpret
 from repro.kernels.gf2mm.ops import decode_blob, encode_blob, rs_decode, rs_encode
 
 __all__ = [
     "gf2_matmul",
     "gf2_rs_matmul_bytes",
-    "tpu_compiler_params",
+    "resolve_interpret",
     "rs_encode",
     "rs_decode",
     "encode_blob",

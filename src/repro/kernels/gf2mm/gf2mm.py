@@ -23,9 +23,9 @@ Two kernels are provided:
   (grid = (batch, M/bm, B/bn)), so a batch of codewords is one launch and
   ``bytes_to_bitplanes`` stops being a separate pass over HBM.
 
-Compat: the pinned JAX names the TPU compiler-params dataclass
-``TPUCompilerParams``; newer releases renamed it ``CompilerParams``.
-:func:`tpu_compiler_params` resolves whichever exists.
+Interpret mode follows the backend (:func:`resolve_interpret`): the Pallas
+interpreter runs on the CPU only, and the TPU always gets the compiled
+Mosaic kernel.
 """
 
 from __future__ import annotations
@@ -38,23 +38,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def tpu_compiler_params(**kwargs):
-    """Build TPU compiler params across the CompilerParams rename.
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """Interpret mode for the current backend: on for the CPU, off elsewhere.
 
-    JAX < 0.5 exposes ``pltpu.TPUCompilerParams``; newer versions renamed it
-    to ``pltpu.CompilerParams``. Returns None when neither exists (e.g. a
-    CPU-only build stripped of the TPU backend) so callers can omit the
-    argument entirely.
+    ``None`` follows ``jax.default_backend()``. An explicit ``False`` is
+    always allowed (compiling for a described TPU from a CPU host); asking
+    for the interpreter on an accelerator raises, so a chip run can never
+    fall back to it.
     """
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams", None)
-    return cls(**kwargs) if cls is not None else None
-
-
-def _pallas_call_kwargs(**kwargs):
-    """Drop compiler_params when the compat shim found no class."""
-    if kwargs.get("compiler_params") is None:
-        kwargs.pop("compiler_params", None)
-    return kwargs
+    on_cpu = jax.default_backend() == "cpu"
+    if interpret is None:
+        return on_cpu
+    if interpret and not on_cpu:
+        raise ValueError(
+            f"Pallas interpret mode requested on backend {jax.default_backend()!r}; "
+            "it is for the CPU only"
+        )
+    return bool(interpret)
 
 
 def _gf2mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k_tiles: int):
@@ -82,7 +82,7 @@ def gf2_matmul(
     block_n: int = 512,
     block_k: int = 128,
     out_dtype=jnp.uint8,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """(A @ B) mod 2 for 0/1 matrices. A: (M, K), B: (K, N) -> (M, N).
 
@@ -104,20 +104,18 @@ def gf2_matmul(
 
     out = pl.pallas_call(
         functools.partial(_gf2mm_kernel, n_k_tiles=n_k_tiles),
-        **_pallas_call_kwargs(
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((bm, bk), lambda i, j, t: (i, t)),
-                pl.BlockSpec((bk, bn), lambda i, j, t: (t, j)),
-            ],
-            out_specs=pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            compiler_params=tpu_compiler_params(
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
-            ),
-            interpret=interpret,
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((bm, bk), lambda i, j, t: (i, t)),
+            pl.BlockSpec((bk, bn), lambda i, j, t: (t, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        interpret=resolve_interpret(interpret),
     )(a_p, b_p)
     return out[:M, :N]
 
@@ -130,7 +128,8 @@ def _rs_bytes_kernel(a_ref, d_ref, o_ref, *, k: int):
            k ≤ 256 so 8k ≤ 2048 columns fit comfortably in VMEM).
     o_ref: (1, bm // 8, bn) raw output bytes.
     """
-    a = a_ref[0].astype(jnp.bfloat16)  # (bm, 8k)
+    # Mosaic has no uint8 -> bf16 cast; widen through int32 and f32.
+    a = a_ref[0].astype(jnp.int32).astype(jnp.float32).astype(jnp.bfloat16)  # (bm, 8k)
     d = d_ref[0]  # (k, bn) uint8
     bm = a.shape[0]
     bn = d.shape[1]
@@ -157,7 +156,7 @@ def gf2_rs_matmul_bytes(
     *,
     block_m: int = 128,
     block_n: int = 512,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Batched fused RS matmul on raw bytes.
 
@@ -191,18 +190,16 @@ def gf2_rs_matmul_bytes(
     grid = (batch, Mp // bm, Bp // bn)
     out = pl.pallas_call(
         functools.partial(_rs_bytes_kernel, k=k),
-        **_pallas_call_kwargs(
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bm, K8), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, k, bn), lambda b, i, j: (b, 0, j)),
-            ],
-            out_specs=pl.BlockSpec((1, bm // 8, bn), lambda b, i, j: (b, i, j)),
-            out_shape=jax.ShapeDtypeStruct((batch, Mp // 8, Bp), jnp.uint8),
-            compiler_params=tpu_compiler_params(
-                dimension_semantics=("parallel", "parallel", "parallel"),
-            ),
-            interpret=interpret,
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((1, bm, K8), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, k, bn), lambda b, i, j: (b, 0, j)),
+        ],
+        out_specs=pl.BlockSpec((1, bm // 8, bn), lambda b, i, j: (b, i, j)),
+        out_shape=jax.ShapeDtypeStruct((batch, Mp // 8, Bp), jnp.uint8),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
         ),
+        interpret=resolve_interpret(interpret),
     )(bitmats, data)
     return out[:, : M // 8, :B]

@@ -7,8 +7,8 @@ oracle in ``rs.py`` and the layout's own path); it now just routes
 single-codeword calls through the shared engine, inheriting its shape-
 bucketed jit caching and the fused bitplane pack/unpack kernel.
 
-``REPRO_PALLAS_INTERPRET=1`` (default in CPU containers) runs the kernel in
-interpret mode; flip to 0 on real TPUs.
+``interpret=None`` (the default) follows the backend: the Pallas
+interpreter on the CPU, the compiled kernel on a TPU.
 """
 
 from __future__ import annotations
@@ -17,18 +17,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.coding.codec import default_pallas_interpret
 
-INTERPRET = default_pallas_interpret()
-
-
-def _codec(interpret: bool):
+def _codec(interpret: bool | None):
     from repro.coding.codec import get_codec
 
     return get_codec("pallas", interpret=interpret)
 
 
-def rs_encode(data: jax.Array, *, n: int, k: int, interpret: bool = INTERPRET) -> jax.Array:
+def rs_encode(data: jax.Array, *, n: int, k: int, interpret: bool | None = None) -> jax.Array:
     """Systematic RS encode on TPU: (k, B) uint8 -> (n, B) uint8.
 
     Data rows pass through; parity rows come from the GF(2) bit-matrix
@@ -40,7 +36,7 @@ def rs_encode(data: jax.Array, *, n: int, k: int, interpret: bool = INTERPRET) -
 
 
 def rs_decode(
-    rows: jax.Array, *, n: int, k: int, present: tuple[int, ...], interpret: bool = INTERPRET
+    rows: jax.Array, *, n: int, k: int, present: tuple[int, ...], interpret: bool | None = None
 ) -> jax.Array:
     """Reconstruct (k, B) data from k surviving strips via the same kernel.
 
@@ -55,13 +51,13 @@ def rs_decode(
 
 def encode_blob(payload: np.ndarray, *, n: int, k: int) -> np.ndarray:
     """Host convenience: 1-D uint8 payload -> (n, ceil(len/k)) coded strips."""
-    return _codec(INTERPRET).encode_blob(np.asarray(payload, np.uint8), n=n, k=k)
+    return _codec(None).encode_blob(np.asarray(payload, np.uint8), n=n, k=k)
 
 
 def decode_blob(
     strips: np.ndarray, present: tuple[int, ...], *, n: int, k: int, payload_len: int
 ) -> np.ndarray:
     """Host convenience: any k strips (k, strip) + ids -> payload bytes."""
-    return _codec(INTERPRET).decode_blob(
+    return _codec(None).decode_blob(
         strips, tuple(int(i) for i in present), n=n, k=k, payload_len=payload_len
     )

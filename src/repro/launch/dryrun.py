@@ -38,18 +38,6 @@ RESULTS_DIR = os.path.join(os.path.dirname(__file__), "../../../benchmarks/resul
 _FLOPS_CACHE: dict[tuple[str, str], float] = {}
 
 
-def cost_dict(ca) -> dict:
-    """Normalize {lowered, compiled}.cost_analysis() across JAX versions.
-
-    Older JAX returns a one-element list of dicts from compiled artifacts;
-    newer versions return the dict directly (and lowered.cost_analysis()
-    already does). Accept both.
-    """
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def global_flops(arch_name: str, shape_name: str) -> float:
     """True executed FLOPs: unsharded lowering with scans unrolled.
 
@@ -67,7 +55,7 @@ def global_flops(arch_name: str, shape_name: str) -> float:
     fcfg = flops_pass_cfg(cfg, shape)
     jfn, args = dryrun_target(arch_name, shape_name, None, cfg_override=fcfg)
     lowered = jfn.lower(*args)
-    ca = cost_dict(lowered.cost_analysis())
+    ca = lowered.cost_analysis()
     flops = float(ca.get("flops", 0.0)) + slstm_flops_correction(cfg, shape)
     _FLOPS_CACHE[key] = flops
     return flops
@@ -119,7 +107,7 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool, *, save: bool = T
                 mem = compiled.memory_analysis()
                 print(f"[dryrun] {tag}: memory_analysis:")
                 print(f"    {mem}")
-                ca = cost_dict(compiled.cost_analysis())
+                ca = compiled.cost_analysis()
                 print(f"[dryrun] {tag}: cost_analysis(per-device, loops-once): "
                       f"flops={ca.get('flops', 0):.3e} "
                       f"bytes={ca.get('bytes accessed', 0):.3e}")

@@ -11,16 +11,22 @@ from __future__ import annotations
 import jax
 
 
+def make_auto_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with Auto axes: the models place activations with
+    ``with_sharding_constraint``, which Explicit axes (the default) refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever devices exist, as a 1-D 'data' mesh (tests/examples)."""
-    n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return make_auto_mesh((len(jax.devices()),), ("data",))
 
 
 def make_grid_mesh(n: int | None = None):

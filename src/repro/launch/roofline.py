@@ -18,8 +18,25 @@ import dataclasses
 import math
 import re
 
-PEAK_FLOPS = 197e12  # bf16 per chip (v5e)
-HBM_BW = 819e9  # B/s per chip
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks."""
+
+    flops: float  # bf16 FLOP/s
+    hbm_bw: float  # HBM bytes/s
+    source: str
+
+
+#: Per-chip peaks keyed by ``jax.Device.device_kind``. A device that is not
+#: listed has no roofline here: callers report None rather than borrow a row.
+PEAKS: dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(197e12, 819e9, 'Google Cloud documentation, "TPU v5e"'),
+}
+
+# The dry-run models v5e pods.
+PEAK_FLOPS = PEAKS["TPU v5 lite"].flops
+HBM_BW = PEAKS["TPU v5 lite"].hbm_bw
 ICI_BW = 50e9  # B/s per link
 
 _DTYPE_BYTES = {
@@ -155,8 +172,6 @@ class Roofline:
 def analyze(compiled, chips: int) -> Roofline:
     """Extract roofline terms from a jax compiled artifact."""
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older jax returns [dict]
-        ca = ca[0]
     flops = float(ca.get("flops", 0.0))
     hbm = float(ca.get("bytes accessed", 0.0))
     try:

@@ -328,15 +328,22 @@ def decode_attention(
 def fill_cache_from_prefill(
     k: jax.Array, v: jax.Array, Smax: int
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Arrange the last Smax of (B, S, Hkv, hd) prefill K/V into ring slots."""
+    """Arrange the last Smax of (B, S, Hkv, hd) prefill K/V into ring slots.
+
+    Position p lives in slot p mod Smax. The kept positions are consecutive,
+    so the ring is the zero-padded tail rotated by a static shift: a pad and
+    a roll, no scatter (the TPU compiler aborts on this batched scatter).
+    """
     B, S, Hkv, hd = k.shape
     take = min(S, Smax)
-    positions = jnp.arange(S - take, S)
-    slots = jnp.mod(positions, Smax)
-    ck = jnp.zeros((B, Smax, Hkv, hd), k.dtype).at[:, slots].set(k[:, S - take :])
-    cv = jnp.zeros((B, Smax, Hkv, hd), v.dtype).at[:, slots].set(v[:, S - take :])
-    sp = jnp.full((Smax,), -(2**30), jnp.int32).at[slots].set(positions.astype(jnp.int32))
-    return ck, cv, sp
+    shift = (S - take) % Smax
+    pad = ((0, 0), (0, Smax - take), (0, 0), (0, 0))
+    ck = jnp.roll(jnp.pad(k[:, S - take :], pad), shift, axis=1)
+    cv = jnp.roll(jnp.pad(v[:, S - take :], pad), shift, axis=1)
+    positions = np.arange(S - take, S)
+    sp = np.full((Smax,), -(2**30), np.int32)
+    sp[positions % Smax] = positions
+    return ck, cv, jnp.asarray(sp)
 
 
 # ---------------------------------------------------------------------------

@@ -53,6 +53,23 @@ def moe_logical_axes(cfg: ModelConfig):
     return p
 
 
+def _expert_einsum(spec: str, x: jax.Array, w: jax.Array) -> jax.Array:
+    """Expert contraction: operands in cfg.dtype, fp32 accumulation.
+
+    XLA:CPU has no batched BF16 x BF16 = F32 dot, so the CPU lowering upcasts
+    the operands first (exact: bf16 values and their products fit fp32).
+    Every other platform lowers the contraction exactly as written.
+    """
+
+    def native(x, w):
+        return jnp.einsum(spec, x, w, preferred_element_type=jnp.float32)
+
+    def cpu(x, w):
+        return native(x.astype(jnp.float32), w.astype(jnp.float32))
+
+    return jax.lax.platform_dependent(x, w, cpu=cpu, default=native)
+
+
 def moe_mlp(
     params, cfg: ModelConfig, x: jax.Array, *, dropless: bool = False
 ) -> tuple[jax.Array, jax.Array]:
@@ -108,16 +125,16 @@ def moe_mlp(
     # Expert FFN over slots; d_ff TP-sharded over "model". Contractions
     # accumulate in fp32 (MXU-native); operands stay in cfg.dtype.
     wi = use_weight(cfg, params["wi"], None, None, "ff")
-    h = jnp.einsum("becd,edf->becf", buf, wi, preferred_element_type=jnp.float32)
+    h = _expert_einsum("becd,edf->becf", buf, wi)
     if cfg.glu:
         wg = use_weight(cfg, params["wg"], None, None, "ff")
-        g = jnp.einsum("becd,edf->becf", buf, wg, preferred_element_type=jnp.float32)
+        g = _expert_einsum("becd,edf->becf", buf, wg)
         h = _act(cfg, g) * h
     else:
         h = _act(cfg, h)
     h = constrain(h, "batch", "experts", None, "ff").astype(x.dtype)
     wo = use_weight(cfg, params["wo"], None, "ff", None)
-    y = jnp.einsum("becf,efd->becd", h, wo, preferred_element_type=jnp.float32)
+    y = _expert_einsum("becf,efd->becd", h, wo)
 
     # Combine in fp32: gather each choice's slot, weight, sum over K.
     y = jnp.concatenate([y, jnp.zeros((B, E, 1, d), y.dtype)], axis=2)

@@ -28,6 +28,7 @@ import os
 
 import numpy as np
 
+from repro.obs.profile import fmt_bound
 from repro.obs.timeline import rolling_percentile
 
 _SPARK = "▁▂▃▄▅▆▇█"
@@ -417,7 +418,7 @@ def _profile_table(profile: dict) -> str:
         rows.append((
             html.escape(label), f"{r['flops']:.3g}", f"{r['bytes']:.3g}",
             f"{r['wall_s'] * 1e3:.3f}", f"{r['gflops']:.2f}",
-            f"{r['gbps']:.2f}", r["bound"], f"{r['frac_peak'] * 100:.2f}",
+            f"{r['gbps']:.2f}", *fmt_bound(r),
         ))
     body = "".join(
         "<tr>" + "".join(f"<td>{c}</td>" for c in row) + "</tr>"

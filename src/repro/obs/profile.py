@@ -4,10 +4,10 @@
 reads XLA's ``cost_analysis`` (FLOPs, bytes accessed), measures post-warmup
 wallclock (best of ``iters`` blocked calls), and derives the roofline view:
 achieved GFLOP/s and GB/s, arithmetic intensity, the compute-vs-memory
-bound side, and the fraction of the configured peak achieved.  Peaks
-default to the v5e constants of :mod:`repro.launch.roofline` — override
-per call for other hosts; on CPU the fractions are indicative only, the
-measured wallclock and the FLOPs/bytes are the portable numbers.
+bound side, and the fraction of the chip's peak achieved.  Peaks come from
+:data:`repro.launch.roofline.PEAKS`, keyed by the device's ``device_kind``;
+on a device that is not in that table (the CPU included) ``bound`` and
+``frac_peak`` are None.
 
 Each profile registers a labeled :class:`repro.obs.compile.CompileStats`
 (held strongly here, so the weak registry keeps it), which makes profiled
@@ -29,17 +29,8 @@ _PROFILES: dict[str, dict] = {}
 _STATS: dict[str, CompileStats] = {}
 
 
-def _cost_dict(ca) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions
-    (dict | [dict] | None)."""
-    if isinstance(ca, list):
-        ca = ca[0] if ca else None
-    return dict(ca) if ca else {}
-
-
 def profile_launch(label: str, fn, *args, warmup: int = 1, iters: int = 3,
-                   peak_flops: float | None = None,
-                   peak_bw: float | None = None, **kwargs) -> dict:
+                   **kwargs) -> dict:
     """Profile one jitted callable at one argument shape; returns the record.
 
     ``fn`` must be a ``jax.jit`` product (anything with ``.lower``).  The
@@ -47,14 +38,13 @@ def profile_launch(label: str, fn, *args, warmup: int = 1, iters: int = 3,
     best of ``iters`` blocked calls is the wallclock."""
     import jax
 
-    from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+    from repro.launch.roofline import PEAKS
 
-    peak_flops = PEAK_FLOPS if peak_flops is None else float(peak_flops)
-    peak_bw = HBM_BW if peak_bw is None else float(peak_bw)
+    peaks = PEAKS.get(jax.devices()[0].device_kind)
 
     with _trace.get_tracer().span("obs.profile_compile", label=label):
         compiled = fn.lower(*args, **kwargs).compile()
-    ca = _cost_dict(compiled.cost_analysis())
+    ca = compiled.cost_analysis() or {}
     flops = float(ca.get("flops", 0.0))
     nbytes = float(ca.get("bytes accessed", 0.0))
 
@@ -66,8 +56,13 @@ def profile_launch(label: str, fn, *args, warmup: int = 1, iters: int = 3,
         jax.block_until_ready(compiled(*args, **kwargs))
         best = min(best, time.perf_counter() - t0)
 
-    t_compute = flops / peak_flops
-    t_memory = nbytes / peak_bw
+    bound = frac_peak = None
+    if peaks is not None and best > 0:
+        t_compute = flops / peaks.flops
+        t_memory = nbytes / peaks.hbm_bw
+        bound = "compute" if t_compute >= t_memory else "memory"
+        # Efficiency vs the binding roofline term at the chip's peaks.
+        frac_peak = max(t_compute, t_memory) / best
     rec = {
         "label": label,
         "flops": flops,
@@ -76,9 +71,8 @@ def profile_launch(label: str, fn, *args, warmup: int = 1, iters: int = 3,
         "gflops": flops / best / 1e9 if best > 0 else 0.0,
         "gbps": nbytes / best / 1e9 if best > 0 else 0.0,
         "intensity": flops / nbytes if nbytes else 0.0,
-        "bound": "compute" if t_compute >= t_memory else "memory",
-        # Efficiency vs the binding roofline term at the configured peaks.
-        "frac_peak": (max(t_compute, t_memory) / best) if best > 0 else 0.0,
+        "bound": bound,
+        "frac_peak": frac_peak,
     }
     _PROFILES[label] = rec
     stats = _STATS.get(label)
@@ -113,6 +107,13 @@ def _fmt_qty(v: float) -> str:
     return f"{v:.0f}"
 
 
+def fmt_bound(r: dict) -> tuple[str, str]:
+    """(bound, peak %) table cells; "n/a" off the peaks table."""
+    if r["frac_peak"] is None:
+        return "n/a", "n/a"
+    return r["bound"], f"{r['frac_peak'] * 100:.2f}"
+
+
 def format_profile(snap: dict | None = None) -> str:
     """ASCII roofline/efficiency table over :func:`profile_snapshot`."""
     snap = profile_snapshot() if snap is None else snap
@@ -122,7 +123,7 @@ def format_profile(snap: dict | None = None) -> str:
         rows.append((
             label, _fmt_qty(r["flops"]), _fmt_qty(r["bytes"]),
             f"{r['wall_s'] * 1e3:.3f}", f"{r['gflops']:.2f}",
-            f"{r['gbps']:.2f}", r["bound"], f"{r['frac_peak'] * 100:.2f}",
+            f"{r['gbps']:.2f}", *fmt_bound(r),
             str(r.get("launches", "")),
         ))
     widths = [max(len(row[i]) for row in rows) for i in range(len(rows[0]))]

@@ -508,6 +508,10 @@ class ClosedLoopResult:
     codes: list[tuple[int, int]]  # read (n, k) per served key
     next_code: tuple[int, int]  # controller's pick, pushed to the write policy
     storage_total_s: list[float]  # proxy read delays per served key
+    # Device arrays at the padded bucket batch (rows past len(served_keys)
+    # are bucket padding), left on device so a round adds no host copy:
+    prompts: jax.Array  # (B, prompt_len) int32 decoded prompts
+    first_logits: jax.Array  # (B, 1, vocab) f32 prefill logits
 
 
 class ClosedLoopServer:
@@ -742,14 +746,15 @@ class ClosedLoopServer:
                 # leading axis, already in the cache key).
                 delays = np.zeros(rows_p.shape[0], np.float32)
                 delays[: len(good)] = [r.total_s for r in good]
-                (carry, n_nxt, k_nxt, _toks, logits, cache,
+                (carry, n_nxt, k_nxt, toks, logits, cache,
                  self._mbuf, self._tlbuf) = fn(
                     *args, self._mbuf, jnp.int32(len(keys)),
                     jnp.int32(len(good)), jnp.int32(len(keys) - len(good)),
                     self._tlbuf, jnp.asarray(delays),
                 )
             else:
-                carry, n_nxt, k_nxt, _toks, logits, cache = fn(*args)
+                carry, n_nxt, k_nxt, toks, logits, cache = fn(*args)
+        first_logits = logits
         t_launch = time.monotonic()
         self.stats.launches += 1
         self.step.carry = carry
@@ -792,4 +797,6 @@ class ClosedLoopServer:
             codes=[(r.n, r.k) for r in good],
             next_code=next_code,
             storage_total_s=[r.total_s for r in good],
+            prompts=toks,
+            first_logits=first_logits,
         )

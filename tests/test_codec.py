@@ -148,3 +148,53 @@ def test_encode_rejects_bad_shapes():
         c.encode(np.zeros((3, 8), np.uint8), n=2, k=3)  # n < k
     with pytest.raises(ValueError):
         c.decode(np.zeros((3, 8), np.uint8), (0, 1), n=6, k=3)  # short present
+
+
+# ---------------------------------------------------------------------------
+# Device-derived defaults
+# ---------------------------------------------------------------------------
+
+
+def test_defaults_follow_the_device(monkeypatch):
+    """Interpret mode and the default backend come from jax.default_backend():
+    the CPU gets the interpreter and the numpy oracle, a TPU the compiled
+    kernel, and interpret mode on a TPU is refused. REPRO_CODEC_BACKEND
+    stays an explicit override."""
+    import jax
+
+    from repro.coding import codec as codec_mod
+    from repro.kernels.gf2mm.gf2mm import resolve_interpret
+
+    monkeypatch.delenv("REPRO_CODEC_BACKEND", raising=False)
+    assert jax.default_backend() == "cpu"
+    assert resolve_interpret(None) is True and resolve_interpret(False) is False
+    assert codec_mod.default_backend() == "numpy"
+    assert get_codec("pallas").backend.interpret is True
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    with pytest.raises(ValueError, match="interpret"):
+        resolve_interpret(True)
+    with pytest.raises(ValueError, match="interpret"):
+        get_codec("pallas", interpret=True)
+    assert codec_mod.default_backend() == "pallas"
+    monkeypatch.setenv("REPRO_CODEC_BACKEND", "jnp")
+    assert codec_mod.default_backend() == "jnp"
+
+
+def test_compile_cache_dir_is_fixed_or_the_environment(monkeypatch):
+    import jax
+
+    from repro import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before  # JAX reads the env itself
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.CACHE_DIR) == jax.config.jax_compilation_cache_dir
+        assert compile_cache.CACHE_DIR.parent.joinpath("chip_smoke.py").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -31,9 +31,6 @@ def _run_py(code: str, devices: int = 8) -> str:
 def test_scan_flops_counted_once_and_unroll_corrects():
     out = _run_py("""
         import jax, jax.numpy as jnp
-        # same normalization as repro.launch.dryrun.cost_dict (that module
-        # must not be imported here: it forces 512 host devices on import)
-        def cost_dict(ca): return (ca[0] if ca else {}) if isinstance(ca, (list, tuple)) else ca
         def body(c, _): return c @ c, None
         def f(unroll):
             def g(x):
@@ -41,8 +38,8 @@ def test_scan_flops_counted_once_and_unroll_corrects():
                 return y
             return g
         x = jax.ShapeDtypeStruct((128, 128), jnp.float32)
-        rolled = cost_dict(jax.jit(f(False)).lower(x).compile().cost_analysis())["flops"]
-        unrolled = cost_dict(jax.jit(f(True)).lower(x).cost_analysis())["flops"]
+        rolled = jax.jit(f(False)).lower(x).compile().cost_analysis()["flops"]
+        unrolled = jax.jit(f(True)).lower(x).cost_analysis()["flops"]
         print(f"RATIO {unrolled / rolled}")
     """)
     ratio = float(out.split("RATIO ")[1])
@@ -80,6 +77,7 @@ def test_mini_dryrun_cell_sharded_compile_and_roofline():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.models.registry import get
         from repro.models.sharding import axis_rules, spec_for
+        from repro.launch.mesh import make_auto_mesh
         from repro.launch.roofline import analyze
         from repro.launch.specs import _specs_tree, _batch_shardings, batch_specs
         from repro.train.train_step import make_train_step
@@ -88,7 +86,7 @@ def test_mini_dryrun_cell_sharded_compile_and_roofline():
 
         arch = get("qwen1.5-0.5b", smoke=True)
         shape = ShapeSpec("mini", "train", seq=64, batch=8)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_auto_mesh((2, 4), ("data", "model"))
         with mesh:
             with axis_rules(mesh):
                 params = jax.eval_shape(lambda: arch.init(jax.random.key(0)))

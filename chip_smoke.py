@@ -54,12 +54,26 @@ import numpy as np
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
+MIB = 2**20
+
+# Phase sizes. Storage: the paper's 3 MB class.
+OBJECTS = 32  # 3 MiB objects written and read back
+STRIP_BYTES = MIB // 2  # K=6 strips of 512 KiB
+ORACLE_SAMPLES = 4  # stored objects compared with the numpy oracle's strips
+TIME_SCALE = 1e-2  # emulated S3 delays, scaled
+# Serving: qwen1.5-0.5b at published widths.
+MODEL = "qwen1.5-0.5b"
+BATCH, PROMPT_LEN, DECODE_STEPS, ROUNDS = 8, 512, 16, 3
+# Exact engine: 8 rates x 4 policies x 2 seeds = 64 cases.
+RATES, ARRIVALS, TRACE_SAMPLES = 8, 2000, 2048
+MESH_DEVICES = 4
+
 #: Closed-loop vs plain-prefill logits: max |diff| <= LOGIT_RTOL * max |ref|.
-LOGIT_RTOL = 0.05
+#: Both run the same bf16 prefill; on the chip they agreed bit for bit.
+#: Weights rounded through float8_e4m3 move the logits by about 4% of max|ref|.
+LOGIT_RTOL = 1e-2
 #: Engine vs event oracle, as in tests/test_taskq.py.
 ORACLE_RTOL, ORACLE_ATOL = 1e-3, 2e-3
-
-MIB = 2**20
 
 
 class SmokeFailure(Exception):
@@ -134,21 +148,20 @@ def check_default_codec(codec) -> None:
 # ---------------------------------------------------------------------------
 
 
-def phase_storage(clock, seed: int, *, objects: int = 32, strip_bytes: int = MIB // 2,
-                  samples: int = 4, time_scale: float = 1e-2) -> None:
+def phase_storage(clock, seed: int) -> None:
     from repro.coding.codec import get_codec
     from repro.coding.layout import SharedKeyLayout
     from repro.core import PAPER_READ_3MB, PAPER_WRITE_3MB, RequestClass, TOFECPolicy
     from repro.storage import FaultyStore, LatencyStore, MemoryStore, Proxy
 
-    layout = SharedKeyLayout(K=6, r=2, strip_bytes=strip_bytes)
+    layout = SharedKeyLayout(K=6, r=2, strip_bytes=STRIP_BYTES)
     with Phase(clock, "storage") as ph:
         rng = np.random.default_rng(seed)
-        payloads = rng.integers(0, 256, (objects, layout.file_bytes), dtype=np.uint8)
+        payloads = rng.integers(0, 256, (OBJECTS, layout.file_bytes), dtype=np.uint8)
         inner = MemoryStore()
         store = FaultyStore(
             LatencyStore(inner, PAPER_READ_3MB, PAPER_WRITE_3MB,
-                         time_scale=time_scale, seed=seed),
+                         time_scale=TIME_SCALE, seed=seed),
             p_fail=0.1, seed=seed + 1,
         )
         cls = RequestClass("read3mb", layout.file_bytes / MIB, PAPER_READ_3MB,
@@ -156,7 +169,7 @@ def phase_storage(clock, seed: int, *, objects: int = 32, strip_bytes: int = MIB
         proxy = Proxy(store, TOFECPolicy.for_classes([cls], L=16), L=16)
         try:
             check_default_codec(proxy.codec)
-            keys = [f"obj/{i}" for i in range(objects)]
+            keys = [f"obj/{i}" for i in range(OBJECTS)]
             for key, p in zip(keys, payloads):
                 res = proxy.write(key, layout, p.tobytes())
                 check(res.ok, f"write {key} failed")
@@ -179,16 +192,16 @@ def phase_storage(clock, seed: int, *, objects: int = 32, strip_bytes: int = MIB
         finally:
             proxy.close()
         oracle = get_codec("numpy")
-        for i in rng.choice(objects, size=min(samples, objects), replace=False):
-            obj = np.frombuffer(inner.get(keys[i]), np.uint8).reshape(-1, strip_bytes)
-            want = oracle.encode(payloads[i].reshape(layout.K, strip_bytes),
+        for i in rng.choice(OBJECTS, size=ORACLE_SAMPLES, replace=False):
+            obj = np.frombuffer(inner.get(keys[i]), np.uint8).reshape(-1, STRIP_BYTES)
+            want = oracle.encode(payloads[i].reshape(layout.K, STRIP_BYTES),
                                  layout.N, layout.K)
             check(np.array_equal(obj, want[: obj.shape[0]]),
                   f"{keys[i]}: stored strips differ from the numpy oracle's")
-        ph.sizes = (f"{objects} objects x {layout.file_bytes / MIB:g} MiB "
-                    f"(K=6, r=2, {strip_bytes // 1024} KiB strips), write codes {codes}, "
+        ph.sizes = (f"{OBJECTS} objects x {layout.file_bytes / MIB:g} MiB "
+                    f"(K=6, r=2, {STRIP_BYTES // 1024} KiB strips), write codes {codes}, "
                     f"read codes {read_codes}, {failed_reads} reads retried, "
-                    f"{min(samples, objects)} objects match the numpy oracle")
+                    f"{ORACLE_SAMPLES} objects match the numpy oracle")
 
 
 # ---------------------------------------------------------------------------
@@ -196,8 +209,7 @@ def phase_storage(clock, seed: int, *, objects: int = 32, strip_bytes: int = MIB
 # ---------------------------------------------------------------------------
 
 
-def phase_serving(clock, seed: int, *, arch=None, batch: int = 8, prompt_len: int = 512,
-                  steps: int = 16, rounds: int = 3, time_scale: float = 1e-2):
+def phase_serving(clock, seed: int):
     """Returns the served parameters (phase 4 checkpoints them)."""
     import jax
     import jax.numpy as jnp
@@ -209,15 +221,15 @@ def phase_serving(clock, seed: int, *, arch=None, batch: int = 8, prompt_len: in
     from repro.serve import ClosedLoopServer, FusedServingStep, ServePolicy, ServingEngine
     from repro.storage import LatencyStore, MemoryStore, Proxy
 
-    arch = arch or get("qwen1.5-0.5b")
+    arch = get(MODEL)
     cfg = arch.cfg
     with Phase(clock, "serving") as ph:
         params = jax.jit(arch.init)(jax.random.key(seed))
-        engine = ServingEngine(arch, params, max_seq=prompt_len + steps)
-        # 4-byte tokens over K=4 strips: one strip per prompt_len/4 tokens.
-        layout = SharedKeyLayout(K=4, r=2, strip_bytes=prompt_len)
+        engine = ServingEngine(arch, params, max_seq=PROMPT_LEN + DECODE_STEPS)
+        # 4-byte tokens over K=4 strips: one strip per PROMPT_LEN/4 tokens.
+        layout = SharedKeyLayout(K=4, r=2, strip_bytes=PROMPT_LEN)
         rng = np.random.default_rng(seed + 2)
-        prompts = rng.integers(0, cfg.vocab, (rounds * batch, prompt_len), dtype=np.int32)
+        prompts = rng.integers(0, cfg.vocab, (ROUNDS * BATCH, PROMPT_LEN), dtype=np.int32)
         inner = MemoryStore()
         keys = [f"prompt/{i}" for i in range(len(prompts))]
         for key, toks in zip(keys, prompts):
@@ -227,17 +239,17 @@ def phase_serving(clock, seed: int, *, arch=None, batch: int = 8, prompt_len: in
         codec = get_codec()
         check_default_codec(codec)
         step = FusedServingStep.for_policy(ServePolicy.tofec(), cls, 16, codec=codec)
-        proxy = Proxy(LatencyStore(inner, PAPER_READ_3MB, time_scale=time_scale, seed=seed),
+        proxy = Proxy(LatencyStore(inner, PAPER_READ_3MB, time_scale=TIME_SCALE, seed=seed),
                       TOFECPolicy.for_classes([cls], L=16), L=16)
         ref_prefill = jax.jit(lambda p, t: arch.prefill(p, {"tokens": t},
                                                         max_seq=engine.max_seq))
-        srv = ClosedLoopServer(engine, proxy, layout, step, prompt_len=prompt_len)
+        srv = ClosedLoopServer(engine, proxy, layout, step, prompt_len=PROMPT_LEN)
         worst = 0.0
         served = 0
         try:
-            for rnd in range(rounds):
-                rkeys = keys[rnd * batch:(rnd + 1) * batch]
-                res = srv.serve_round(rkeys, steps=steps)
+            for rnd in range(ROUNDS):
+                rkeys = keys[rnd * BATCH:(rnd + 1) * BATCH]
+                res = srv.serve_round(rkeys, steps=DECODE_STEPS)
                 G = len(res.served_keys)
                 check(G > 0, f"round {rnd}: nothing served")
                 idx = [keys.index(k) for k in res.served_keys]
@@ -245,7 +257,7 @@ def phase_serving(clock, seed: int, *, arch=None, batch: int = 8, prompt_len: in
                 got = np.asarray(res.prompts)[:G]
                 check(np.array_equal(got, want),
                       f"round {rnd}: decoded prompt tokens differ from the stored ones")
-                check(res.tokens.shape == (G, steps)
+                check(res.tokens.shape == (G, DECODE_STEPS)
                       and ((res.tokens >= 0) & (res.tokens < cfg.vocab)).all(),
                       f"round {rnd}: generated tokens out of range")
                 ref, _ = ref_prefill(params, jnp.asarray(want))
@@ -263,8 +275,9 @@ def phase_serving(clock, seed: int, *, arch=None, batch: int = 8, prompt_len: in
         finally:
             proxy.close()
         ph.sizes = (f"{cfg.name} ({cfg.n_layers}L, d_model {cfg.d_model}, vocab {cfg.vocab}), "
-                    f"{rounds} rounds x batch {batch}, prompt_len {prompt_len}, "
-                    f"{steps} decode steps, {served} prompts served, {srv.traces} trace(s); "
+                    f"{ROUNDS} rounds x batch {BATCH}, prompt_len {PROMPT_LEN}, "
+                    f"{DECODE_STEPS} decode steps, {served} prompts served, "
+                    f"{srv.traces} trace(s); "
                     f"logits max|diff|/max|ref| = {worst:.3g} (limit {LOGIT_RTOL})")
     return params
 
@@ -315,34 +328,34 @@ def phase_checkpoint(clock, seed: int, params) -> None:
 # ---------------------------------------------------------------------------
 
 
-def taskq_setup(seed: int, *, lams: int = 8, count: int = 2000, samples: int = 2048):
+def taskq_setup(seed: int):
     from repro.core import PAPER_READ_3MB, RequestClass
     from repro.core.traces import TraceStore
     from repro.fleet import PolicySpec, grid_cases
 
     cls = RequestClass("read3mb", 3.0, PAPER_READ_3MB, k_max=6, r_max=2.0, n_max=12)
     sizes = tuple(cls.file_mb / k for k in range(1, cls.k_max + 1))
-    pools = TraceStore.generate(PAPER_READ_3MB, sizes, threads=cls.n_max, samples=samples,
+    pools = TraceStore.generate(PAPER_READ_3MB, sizes, threads=cls.n_max, samples=TRACE_SAMPLES,
                                 correlation=0.14, seed=seed).device_pools(n_max=cls.n_max)
     policies = [PolicySpec.tofec(), PolicySpec.static(1, 1), PolicySpec.static(12, 6),
                 PolicySpec.greedy()]
-    cases = grid_cases(np.linspace(6.0, 48.0, lams), policies, [seed, seed + 1], cls, 16)
-    return cls, pools, cases, count
+    cases = grid_cases(np.linspace(6.0, 48.0, RATES), policies, [seed, seed + 1], cls, 16)
+    return cls, pools, cases
 
 
-def phase_taskq(clock, seed: int, **grid) -> None:
+def phase_taskq(clock, seed: int) -> None:
     from repro.core import StaticPolicy
     from repro.core.simulator import simulate
     from repro.taskq import TaskqSweep, taskq_streams
 
     with Phase(clock, "exact engine") as ph:
-        cls, pools, cases, count = taskq_setup(seed, **grid)
-        res = TaskqSweep(chunk=64).run(cases, count, pools)
+        cls, pools, cases = taskq_setup(seed)
+        res = TaskqSweep(chunk=64).run(cases, ARRIVALS, pools)
         out = res.to_numpy()
         check(np.isfinite(out["total"]).all(), "non-finite delays in the grid")
         i = next(j for j, c in enumerate(cases) if c.policy.name == "static(12,6)")
         case = cases[i]
-        inter, idx = taskq_streams(case, count, pools.n_rows)
+        inter, idx = taskq_streams(case, ARRIVALS, pools.n_rows)
         host = simulate(StaticPolicy(12, 6), np.cumsum(inter.astype(np.float64)),
                         pools.host_sampler(cls.file_mb, idx), L=case.L, warmup_frac=0.0)
         for name, want in (("total", host.totals()), ("queueing", host.queueing()),
@@ -351,21 +364,21 @@ def phase_taskq(clock, seed: int, **grid) -> None:
             check(np.allclose(got, want, rtol=ORACLE_RTOL, atol=ORACLE_ATOL),
                   f"grid point {i} ({case.policy.name}): {name} differs from the "
                   f"event oracle by up to {np.abs(got - want).max():.3g}")
-        ph.sizes = (f"{len(cases)} cases x {count} arrivals, {res.launches} launch(es), "
+        ph.sizes = (f"{len(cases)} cases x {ARRIVALS} arrivals, {res.launches} launch(es), "
                     f"{res.compiles} compile(s); point {i} ({case.policy.name}, "
                     f"lam={case.lam:g}) matches the event oracle")
 
 
-def phase_taskq_sharded(clock, seed: int, devices: int = 4, **grid) -> None:
+def phase_taskq_sharded(clock, seed: int) -> None:
     from repro.fleet import frontier_points
     from repro.taskq import TaskqSweep
 
-    with Phase(clock, f"sharded exact engine ({devices} devices)") as ph:
-        cls, pools, cases, count = taskq_setup(seed, **grid)
+    with Phase(clock, f"sharded exact engine ({MESH_DEVICES} devices)") as ph:
+        _, pools, cases = taskq_setup(seed)
         one = TaskqSweep(chunk=64)
-        ref = one.run(cases, count, pools, stream=True)
-        mesh = TaskqSweep(chunk=64, mesh=devices)
-        st = mesh.run(cases, count, pools, stream=True)
+        ref = one.run(cases, ARRIVALS, pools, stream=True)
+        mesh = TaskqSweep(chunk=64, mesh=MESH_DEVICES)
+        st = mesh.run(cases, ARRIVALS, pools, stream=True)
         a, b = ref.streamed.red, st.streamed.red
         check(sorted(a) == sorted(b), "streamed statistics differ in name")
         for name in a:
@@ -375,15 +388,17 @@ def phase_taskq_sharded(clock, seed: int, devices: int = 4, **grid) -> None:
         pb = [json.dumps(p.to_dict()) for p in frontier_points(st)]
         check(pa == pb, "frontier points: sharded differs from one device")
         # Placement: the sharded launch's outputs must sit on every device.
-        mat = mesh.run(cases, count, pools)
+        mat = mesh.run(cases, ARRIVALS, pools)
         total = mat.out["total"]
         shards = sorted((s.device.id, s.data.shape[0]) for s in total.addressable_shards)
-        check(len({d for d, _ in shards}) == devices
-              and all(rows == len(cases) // devices for _, rows in shards),
-              f"sharded outputs are not split over {devices} devices: {shards}")
-        check(np.array_equal(np.asarray(total), one.run(cases, count, pools).to_numpy()["total"]),
+        check(len({d for d, _ in shards}) == MESH_DEVICES
+              and all(rows == len(cases) // MESH_DEVICES for _, rows in shards),
+              f"sharded outputs are not split over {MESH_DEVICES} devices: {shards}")
+        check(np.array_equal(np.asarray(total),
+                             one.run(cases, ARRIVALS, pools).to_numpy()["total"]),
               "materialized sharded delays differ from one device")
-        ph.sizes = (f"{len(cases)} cases x {count} arrivals streamed over a {devices}-device "
+        ph.sizes = (f"{len(cases)} cases x {ARRIVALS} arrivals streamed over a "
+                    f"{MESH_DEVICES}-device "
                     f"grid mesh: {len(a)} statistics and {len(pa)} frontier points bit-exact "
                     f"vs one device; output shards (device, rows) {shards}")
 
@@ -392,7 +407,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__,
                                  formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+    ap.add_argument("--chips", type=int, choices=(1, MESH_DEVICES), default=1,
                     help="4: run only the mesh-sharded sweep and its one-device twin")
     args = ap.parse_args(argv)
 
@@ -401,8 +416,8 @@ def main(argv=None) -> int:
 
     print(f"compile cache: {enable_compile_cache()}", flush=True)
     clock = CompileClock()
-    if args.chips == 4:
-        phase_taskq_sharded(clock, args.seed, devices=4)
+    if args.chips == MESH_DEVICES:
+        phase_taskq_sharded(clock, args.seed)
     else:
         phase_storage(clock, args.seed)
         params = phase_serving(clock, args.seed)

@@ -8,7 +8,9 @@ The fetch runs twice: once on the unfused path (the proxy batch-decodes
 completions per admission round on the host codec) and once through the
 fused serving step — one jitted launch running the TOFEC admission update
 AND the batched MDS decode for the whole round. The fused step runs the
-Pallas codec: the compiled kernel on a TPU, the interpreter on the CPU.
+default codec when it is jitted (``REPRO_CODEC_BACKEND=jnp`` or ``pallas``,
+or the Pallas kernel a TPU defaults to) and the Pallas codec otherwise: the
+compiled kernel on a TPU, the interpreter on the CPU.
 
 ``--closed-loop`` runs the full serving tower instead: a ClosedLoopServer
 whose single jitted step covers admission update → batched decode →
@@ -70,7 +72,9 @@ def _setup(fast: bool, p_fail: float = 0.15):
 
     cls = RequestClass("prompt", prompt_len * 4 / 2**20, PAPER_READ_3MB,
                        k_max=4, r_max=2.0, n_max=8)
-    codec = get_codec("pallas")
+    codec = get_codec()
+    if not codec.backend.jitted:  # the host numpy oracle cannot run fused
+        codec = get_codec("pallas")
     return eng, layout, inner, store, keys, cls, codec, prompt_len, rng
 
 

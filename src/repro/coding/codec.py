@@ -67,12 +67,6 @@ def pow2_bucket(x: int, floor: int = 1) -> int:
     return b
 
 
-def _resolve_interpret(interpret: bool | None) -> bool:
-    from repro.kernels.gf2mm.gf2mm import resolve_interpret
-
-    return resolve_interpret(interpret)
-
-
 def _is_traced(x) -> bool:
     """True when x is a JAX tracer (call made inside jit/vmap/grad)."""
     from jax.core import Tracer
@@ -211,8 +205,10 @@ class PallasBackend(_Backend):
     jitted = True
 
     def __init__(self, stats: CodecStats, interpret: bool | None = None):
+        from repro.kernels.gf2mm.gf2mm import resolve_interpret
+
         super().__init__(stats)
-        self.interpret = _resolve_interpret(interpret)
+        self.interpret = resolve_interpret(interpret)
 
     def _build(self, k: int):
         import jax
@@ -501,8 +497,10 @@ def get_codec(backend: str | None = None, *, interpret: bool | None = None) -> C
     the device at first use (interpreter on the CPU only), so explicit and
     defaulted callers share one instance (and its jit caches).
     """
+    from repro.kernels.gf2mm.gf2mm import resolve_interpret
+
     name = backend or default_backend()
-    interpret = _resolve_interpret(interpret) if name == "pallas" else None
+    interpret = resolve_interpret(interpret) if name == "pallas" else None
     key = (name, interpret)
     with _INSTANCES_LOCK:
         if key not in _INSTANCES:

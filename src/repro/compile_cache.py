@@ -2,8 +2,8 @@
 
 Call :func:`enable_compile_cache` once, before the first compile. Where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing is set
-here. Otherwise the cache goes to ``<checkout>/.jax_cache`` (git-ignored):
-the path is part of the cache key, so it must never move between runs.
+here. Otherwise the cache goes to ``<checkout>/.jax_cache`` (git-ignored), a
+path that never moves, so each run finds what earlier runs cached.
 """
 
 from __future__ import annotations

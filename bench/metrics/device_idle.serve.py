@@ -1,0 +1,7 @@
+"""Share of the traced window in which no op ran on the device, serving."""
+
+from benchlib import readers
+
+
+def read(run):
+    return readers.device_idle_pct(run)

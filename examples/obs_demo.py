@@ -22,16 +22,20 @@ then exports everything the layer produces:
   pool thread) + ``flight_records.ndjson`` (``repro.obs/flight/v1``), and
   the serving loop's per-round phase ring;
 * the shared compile-accounting snapshot across every engine touched;
-* the host span table (compile/launch/fetch/finalize boundaries) and the
-  Chrome ``trace_event`` JSON — load it in ``chrome://tracing`` / Perfetto.
+* a JAX profiler trace of the whole run: the host spans (compile / launch /
+  fetch / admission / task / decode boundaries) beside the device's ops on
+  one clock, written under ``profile/`` with a ``perfetto_trace.json.gz``
+  that Perfetto loads.
 
 Run:  PYTHONPATH=src python examples/obs_demo.py [--fast] [--out DIR]
 """
 
 import argparse
 import dataclasses
+import glob
 import json
 import os
+import shutil
 
 import jax
 import jax.numpy as jnp
@@ -129,20 +133,28 @@ def main() -> None:
     args = ap.parse_args()
 
     obs.set_enabled(True)
-    obs.reset_trace()
     obs.reset_profiles()
+    out_dir = os.path.abspath(args.out)
+    trace_dir = os.path.join(out_dir, "profile")
+    shutil.rmtree(trace_dir, ignore_errors=True)
 
-    serve_snap, serve_tl, serve_flight = serve_rounds(
-        rounds=2 if args.fast else 4, steps=2 if args.fast else 4)
-    taskq_snap, taskq_tl, flight_log = taskq_grid(
-        count=128 if args.fast else 512)
-    profile_kernels(count=128 if args.fast else 1024)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0  # spans and XLA events, no Python calls
+    with jax.profiler.trace(trace_dir, create_perfetto_trace=True,
+                            profiler_options=opts):
+        serve_snap, serve_tl, serve_flight = serve_rounds(
+            rounds=2 if args.fast else 4, steps=2 if args.fast else 4)
+        taskq_snap, taskq_tl, flight_log = taskq_grid(
+            count=128 if args.fast else 512)
+        profile_kernels(count=128 if args.fast else 1024)
 
-    spec = obs.SLOSpec(target_s=0.25, percentile=0.99, window=4)
-    events = obs.EventLog("obs_demo")
-    exemplars = flight_log.exemplars(3)
-    report = obs.slo_report(serve_tl, spec, label="obs_demo", events=events,
-                            exemplars=exemplars)
+        spec = obs.SLOSpec(target_s=0.25, percentile=0.99, window=4)
+        events = obs.EventLog("obs_demo")
+        exemplars = flight_log.exemplars(3)
+        report = obs.slo_report(serve_tl, spec, label="obs_demo", events=events,
+                                exemplars=exemplars)
+    (trace_path,) = glob.glob(os.path.join(trace_dir, "**", "perfetto_trace.json.gz"),
+                              recursive=True)
     profile = obs.profile_snapshot()
 
     print("== serving metrics ==")
@@ -161,12 +173,7 @@ def main() -> None:
                               slo=report, profile=profile,
                               exemplars=exemplars))
 
-    print("== span table ==")
-    print(obs.get_tracer().format_table())
-
-    out_dir = os.path.abspath(args.out)
     os.makedirs(out_dir, exist_ok=True)
-    trace_path = obs.write_trace(os.path.join(out_dir, "obs_trace.json"))
     snap_path = os.path.join(out_dir, "obs_metrics.json")
     with open(snap_path, "w") as f:
         json.dump({"meta": obs.run_meta(), "serve": serve_snap,

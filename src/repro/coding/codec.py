@@ -215,11 +215,11 @@ class PallasBackend(_Backend):
 
         from repro.kernels.gf2mm.gf2mm import gf2_rs_matmul_bytes
 
-        def fn(bitmats, data):
+        def rs_codec_matmul(bitmats, data):
             self.stats.traces += 1  # runs at trace time only
             return gf2_rs_matmul_bytes(bitmats, data, interpret=self.interpret)
 
-        return jax.jit(fn)
+        return jax.jit(rs_codec_matmul)
 
     def prep_mats(self, mats):
         """GF(2) bit-expansion (batch, m, k) → (batch, 8m, 8k); host-side."""

@@ -37,6 +37,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+#: the name both kernels carry into the HLO (the custom call's op name and
+#: its ``kernel_name``), so a device trace finds them after a refactor
+KERNEL_NAME = "gf2_rs_matmul"
+
 
 def resolve_interpret(interpret: bool | None = None) -> bool:
     """Interpret mode for the current backend: on for the CPU, off elsewhere.
@@ -116,6 +120,7 @@ def gf2_matmul(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=resolve_interpret(interpret),
+        name=KERNEL_NAME,
     )(a_p, b_p)
     return out[:M, :N]
 
@@ -201,5 +206,6 @@ def gf2_rs_matmul_bytes(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=resolve_interpret(interpret),
+        name=KERNEL_NAME,
     )(bitmats, data)
     return out[:, : M // 8, :B]

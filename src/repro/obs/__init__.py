@@ -9,10 +9,10 @@ Layers (see ROADMAP "Conventions"):
   delay-histogram deltas; windowed percentiles are recoverable host-side;
 * SLO / convergence monitoring — :class:`SLOSpec` burn rates and
   pick-settling over timeline snapshots, with structured NDJSON events
-  (:class:`EventLog`) mirrored into the span trace as instant marks;
-* host span tracing — :func:`span` / :func:`traced` around compile /
-  launch / upload / finalize boundaries, exported as Chrome trace JSON via
-  :func:`write_trace` and aggregate tables via :func:`aggregate`;
+  (:class:`EventLog`) mirrored into the profiler's trace as instant marks;
+* host spans — :func:`span` / :func:`instant` around compile / launch /
+  admission / task / decode boundaries, written into the JAX profiler's
+  trace on the device trace's clock (:mod:`repro.obs.trace`);
 * shared compile accounting — :class:`CompileStats` behind every engine's
   ``stats`` object, queryable in one shot via :func:`compile_snapshot`;
 * launch profiling — :func:`profile_launch` cost-model + wallclock records
@@ -24,8 +24,9 @@ Layers (see ROADMAP "Conventions"):
   streams, p99 exemplar mining) and :class:`FlightRing` for the serving
   loop's per-round phase breakdown.
 
-Everything is gated on ``REPRO_OBS=1`` (or :func:`set_enabled`); disabled,
-the layer costs one branch per site and changes no compiled graph.
+The device-resident planes are gated on ``REPRO_OBS=1`` (or
+:func:`set_enabled`); disabled, they cost one branch per site and change no
+compiled graph. Host spans are gated by the profiler's session alone.
 """
 from repro.obs.state import enabled, set_enabled
 from repro.obs.compile import CompileStats, compile_snapshot, register_stats
@@ -66,18 +67,9 @@ from repro.obs.flight import (
     FlightRing,
     exemplar_panel,
     oracle_task_rows,
-)
-from repro.obs.trace import (
-    Tracer,
-    aggregate,
-    get_tracer,
-    instant,
-    reset_trace,
-    span,
-    traced,
-    write_trace,
     write_trace_doc,
 )
+from repro.obs.trace import instant, span
 from repro.obs.meta import SCHEMA_VERSION, git_rev, run_meta
 
 __all__ = [
@@ -116,15 +108,9 @@ __all__ = [
     "FlightRing",
     "exemplar_panel",
     "oracle_task_rows",
-    "Tracer",
-    "span",
-    "traced",
-    "instant",
-    "get_tracer",
-    "write_trace",
     "write_trace_doc",
-    "aggregate",
-    "reset_trace",
+    "span",
+    "instant",
     "SCHEMA_VERSION",
     "git_rev",
     "run_meta",

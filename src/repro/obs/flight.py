@@ -25,11 +25,11 @@ and the departure instant for every request.  This module is the host side:
   clock (rounds butt against each other, no inter-round idle), so serve
   dashboards show where breached rounds spent their budget.
 
-Clock convention: :class:`repro.obs.trace.Tracer` spans are **wallclock**
-(monotonic µs since tracer epoch); flight traces are **simulated seconds**
-scaled to µs (``ts = sim_s * 1e6``).  Both serialize through the shared
-:func:`repro.obs.trace.write_trace_doc` writer, so either file loads in
-Perfetto — they are different timelines, not different formats.
+Clock convention: flight traces are **simulated seconds** scaled to µs
+(``ts = sim_s * 1e6``), written as Chrome ``trace_event`` JSON by
+:func:`write_trace_doc`, which Perfetto loads. Host spans
+(:func:`repro.obs.span`) live in the JAX profiler's trace on the host's
+clock instead: the two are different timelines.
 
 NDJSON record schema (one JSON object per line)::
 
@@ -55,9 +55,18 @@ from collections import deque
 
 import numpy as np
 
-from repro.obs.trace import write_trace_doc
-
 FLIGHT_SCHEMA = "repro.obs/flight/v1"
+
+
+def write_trace_doc(path: str, events: list) -> str:
+    """Write a Chrome ``trace_event`` list as a document Perfetto and
+    ``chrome://tracing`` load; returns the path."""
+    doc = {"traceEvents": list(events), "displayTimeUnit": "ms"}
+    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+    with open(path, "w") as fh:
+        json.dump(doc, fh)
+    return path
+
 
 #: kind ids shared with the oracle's event_log rows: index = device/oracle
 #: integer kind, value = the NDJSON kind string.

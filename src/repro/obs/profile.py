@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import time
 
-from repro.obs import trace as _trace
+from repro.obs.trace import span
 from repro.obs.compile import CompileStats
 
 #: Strong refs so the weak compile registry keeps profiled labels alive.
@@ -42,7 +42,7 @@ def profile_launch(label: str, fn, *args, warmup: int = 1, iters: int = 3,
 
     peaks = PEAKS.get(jax.devices()[0].device_kind)
 
-    with _trace.get_tracer().span("obs.profile_compile", label=label):
+    with span("obs.profile_compile", label=label):
         compiled = fn.lower(*args, **kwargs).compile()
     ca = compiled.cost_analysis() or {}
     flops = float(ca.get("flops", 0.0))

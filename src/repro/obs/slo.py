@@ -13,8 +13,8 @@ plane (:mod:`repro.obs.timeline`) into first-class measurements:
   rounded (n, k) pick never changes again) and per-code dwell fractions —
   the paper's Fig.-style convergence story as numbers.
 * :func:`slo_report` — one dict tying it together, emitting breach /
-  converge events both as instant marks into the span trace
-  (:meth:`repro.obs.trace.Tracer.instant`) and as structured NDJSON lines
+  converge events both as instant marks into the profiler's trace
+  (:func:`repro.obs.trace.instant`) and as structured NDJSON lines
   through :class:`EventLog`.
 
 Everything here is host-side numpy over :meth:`TimelineBuf.snapshot`
@@ -35,7 +35,7 @@ import time
 
 import numpy as np
 
-from repro.obs import trace as _trace
+from repro.obs.trace import instant
 from repro.obs.timeline import bucket_edges, rolling_percentile
 
 EVENT_SCHEMA = "repro.obs/event/v1"
@@ -83,9 +83,9 @@ class EventLog:
         ev = {"schema": EVENT_SCHEMA, "ts": time.time(), "kind": kind,
               "label": self.label, **fields}
         self.events.append(ev)
-        # Mirror into the span trace as an instant mark so breaches line up
-        # with the compile/launch spans on the Perfetto timeline.
-        _trace.get_tracer().instant(f"obs.{kind}", label=self.label, **fields)
+        # Mirror into the profiler's trace as an instant mark so breaches
+        # line up with the compile/launch spans on the Perfetto timeline.
+        instant(f"obs.{kind}", label=self.label, **fields)
         return ev
 
     def write(self, path: str) -> str:

@@ -1,11 +1,13 @@
-"""Enablement switch for the telemetry layer.
+"""Enablement switch for the device-resident telemetry planes.
 
-One flag drives both the device-resident metrics and the host span tracer:
 ``REPRO_OBS=1`` in the environment, or :func:`set_enabled` for programmatic
-control (tests).  The flag is read at *call* time, never baked into module
-state, so flipping it mid-process works — engines that jit-cache on it put
-the flag into their cache key, which keeps compile-count pins exact: a
-constant flag yields exactly the same bucket counts as before this layer
+control (tests), turns on the ``collect`` variants: the metrics and timeline
+buffers (and the serving loop's flight ring) that ride the jitted launches.
+Host spans (:func:`repro.obs.span`) do not consult it: the JAX profiler's
+session is their switch. The flag is read at *call* time, never baked into
+module state, so flipping it mid-process works — engines that jit-cache on
+it put the flag into their cache key, which keeps compile-count pins exact:
+a constant flag yields exactly the same bucket counts as before this layer
 existed.
 """
 from __future__ import annotations
@@ -16,7 +18,7 @@ _OVERRIDE: list = [None]
 
 
 def set_enabled(value: bool | None) -> None:
-    """Force telemetry on/off; ``None`` restores env (``REPRO_OBS``) control."""
+    """Force collection on/off; ``None`` restores env (``REPRO_OBS``) control."""
     _OVERRIDE[0] = None if value is None else bool(value)
 
 

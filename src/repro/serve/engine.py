@@ -67,6 +67,11 @@ from repro import obs
 POL_THRESH = 0
 POL_MPC = 1
 
+#: ``jax.named_scope`` of the fused admission → decode → prefill launch and of
+#: one cached decode step: the prefix of their ops' names in the HLO metadata
+FUSED_SCOPE = "serve.fused_launch"
+DECODE_SCOPE = "serve.decode_step"
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -404,7 +409,14 @@ class ServingEngine:
         self._prefill = jax.jit(
             lambda p, b: arch.prefill(p, b, max_seq=self.max_seq)
         )
-        self._decode = jax.jit(arch.decode_step)
+
+        # The XLA module is named after this function (``jit_decode_step``);
+        # the scope names its ops in the HLO metadata and the device trace.
+        def decode_step(params, token, cache):
+            with jax.named_scope(DECODE_SCOPE):
+                return arch.decode_step(params, token, cache)
+
+        self._decode = jax.jit(decode_step)
 
     # -- storage integration -------------------------------------------------
 
@@ -616,13 +628,15 @@ class ClosedLoopServer:
 
         def core(tables, carry, mats, rows, q, dt, params):
             self.traces += 1  # runs at trace time only
-            carry, n_nxt, k_nxt = serve_policy_step(carry, q, dt, tables)
-            data = backend.matmul_traced(mats, rows)
-            toks = tokens_from_strips(data, K, b, plen)
-            # Bucket-padding rows decode to zeros; clip keeps any stray bytes
-            # inside the embedding table instead of relying on gather clamping.
-            toks = jnp.clip(toks, 0, vocab - 1)
-            logits, cache = arch.prefill_tokens(params, toks, max_seq=max_seq)
+            with jax.named_scope(FUSED_SCOPE):
+                carry, n_nxt, k_nxt = serve_policy_step(carry, q, dt, tables)
+                data = backend.matmul_traced(mats, rows)
+                toks = tokens_from_strips(data, K, b, plen)
+                # Bucket-padding rows decode to zeros; clip keeps any stray
+                # bytes inside the embedding table instead of relying on
+                # gather clamping.
+                toks = jnp.clip(toks, 0, vocab - 1)
+                logits, cache = arch.prefill_tokens(params, toks, max_seq=max_seq)
             return carry, n_nxt, k_nxt, toks, logits, cache
 
         if collect:

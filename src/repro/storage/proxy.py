@@ -42,6 +42,14 @@ that want the raw chunks instead (e.g. the fused serving step in
 :mod:`repro.serve.engine`, which decodes *inside* its jitted step) pass
 ``raw=True``; those requests skip proxy-side decode and return their
 surviving chunks + indices in :attr:`RequestResult.chunks`.
+
+Every answered request carries its phases on the host clock (arrival →
+tasks injected → first task starts → k-th chunk → answer) and its task
+counts on :class:`RequestResult`; :attr:`Proxy.thread_busy_s` sums the
+threads' time inside store calls. Under a JAX profiler session the admit
+loop, the workers and the batched decode also write spans
+(``proxy.admit_round``, ``proxy.admit_wait``, ``proxy.task``,
+``proxy.reconstruct``) into the trace, tagged with the request's ``rid``.
 """
 
 from __future__ import annotations
@@ -70,6 +78,10 @@ _WAKE = object()
 
 @dataclasses.dataclass
 class RequestResult:
+    """One answered request. Times are the host's ``time.monotonic()``; on
+    an answered request ``t_arrival <= t_injected <= t_first_start <= t_kth
+    <= t_done``."""
+
     key: str
     op: str
     n: int
@@ -82,6 +94,16 @@ class RequestResult:
     failures: int = 0
     #: raw reads only: surviving chunk index -> chunk bytes (data stays None)
     chunks: dict[int, bytes] | None = None
+    #: the proxy's sequence number of the request, the tag of its spans
+    rid: int = -1
+    #: the admit loop put the request's tasks on the task queue
+    t_injected: float | None = None
+    #: the k-th chunk arrived (read) or the k-th part became durable (write)
+    t_kth: float | None = None
+    #: tasks put on the task queue
+    n_issued: int = 0
+    #: tasks a worker ran (those skipped after cancellation are not counted)
+    tasks_started: int = 0
 
     @property
     def total_s(self) -> float:
@@ -97,7 +119,9 @@ class RequestResult:
 
 
 class _Request:
-    def __init__(self, op, key, layout, payload, payload_len, n, k, cls_id, raw=False):
+    def __init__(self, rid, op, key, layout, payload, payload_len, n, k, cls_id,
+                 raw=False):
+        self.rid = rid
         self.op = op
         self.key = key
         self.layout: SharedKeyLayout = layout
@@ -108,7 +132,10 @@ class _Request:
         self.cls_id = cls_id
         self.raw = raw
         self.t_arrival = time.monotonic()
+        self.t_injected = None
         self.t_first_start = None
+        self.t_kth = None
+        self.tasks_started = 0
         self.done = threading.Event()
         self.lock = threading.Lock()
         self.completed: dict[int, bytes] = {}
@@ -147,6 +174,9 @@ class Proxy:
         # queued from the policy's point of view (TOFEC's q signal).
         self._admit_backlog = 0
         self._state_lock = threading.Lock()
+        self._next_rid = 0
+        #: thread-seconds spent inside store calls, summed over the L threads
+        self.thread_busy_s = 0.0
         self._shutdown = False
         self.results: list[RequestResult] = []
         self._threads = [
@@ -242,6 +272,8 @@ class Proxy:
         with self._state_lock:
             q_len = self._request_q.qsize() + self._admit_backlog
             idle = self._idle
+            rid = self._next_rid
+            self._next_rid += 1
         pol = self.write_policy if (op == "write" and self.write_policy is not None) \
             else self.policy
         n, k = pol.select(q=q_len, idle=idle, cls_id=cls_id, now=time.monotonic())
@@ -249,7 +281,7 @@ class Proxy:
         k = max(kk for kk in layout.supported_k() if kk <= k)
         n_max, _, _ = layout.code_for_k(k)
         n = max(k, min(n, n_max))
-        req = _Request(op, key, layout, payload, payload_len, n, k, cls_id, raw=raw)
+        req = _Request(rid, op, key, layout, payload, payload_len, n, k, cls_id, raw=raw)
         if op == "write":
             with self._state_lock:
                 self._write_reqs.append(req)
@@ -283,21 +315,23 @@ class Proxy:
                 pending.append(req)
             with self._state_lock:
                 self._admit_backlog = len(pending)
-            self._flush_completed_reads()
-            self._encode_pending_writes(pending)
-            req = pending.popleft()
-            with self._state_lock:
-                self._admit_backlog = len(pending)
-            # Paper's admission rule: wait until the task queue is drained
-            # and a thread is idle before injecting the next batch.
-            while not self._shutdown:
+            with obs.span("proxy.admit_round", pending=len(pending)):
+                self._flush_completed_reads()
+                self._encode_pending_writes(pending)
+                req = pending.popleft()
                 with self._state_lock:
-                    ready = self._idle > 0 and self._task_q.empty()
-                if ready:
-                    break
-                self._flush_completed_reads()  # decode while tasks drain
-                time.sleep(1e-4)
-            self._inject(req)
+                    self._admit_backlog = len(pending)
+                # Paper's admission rule: wait until the task queue is drained
+                # and a thread is idle before injecting the next batch.
+                with obs.span("proxy.admit_wait", rid=req.rid):
+                    while not self._shutdown:
+                        with self._state_lock:
+                            ready = self._idle > 0 and self._task_q.empty()
+                        if ready:
+                            break
+                        self._flush_completed_reads()  # decode while tasks drain
+                        time.sleep(1e-4)
+                self._inject(req)
         self._flush_completed_reads()
 
     def _flush_completed_reads(self) -> None:
@@ -321,9 +355,10 @@ class Proxy:
             groups.setdefault(r.layout, []).append(r)
         for lay, group in groups.items():
             try:
-                datas = lay.reconstruct_batch(
-                    [(r.k, r.completed, r.payload_len) for r in group], codec=self.codec
-                )
+                with obs.span("proxy.reconstruct", batch=len(group)):
+                    datas = lay.reconstruct_batch(
+                        [(r.k, r.completed, r.payload_len) for r in group],
+                        codec=self.codec)
             except Exception as batch_err:
                 # Torn batch (e.g. one malformed chunk): fall back to
                 # per-request decode so one bad item can't wedge the rest.
@@ -381,6 +416,7 @@ class Proxy:
                     req.cancelled = True
                     self._finish(req, False)
                 return
+            req.t_injected = time.monotonic()
             for ci in issue:
                 self._task_q.put((req, int(ci), None))
         else:
@@ -389,6 +425,7 @@ class Proxy:
                 coded = req.layout.encode_file(req.payload, codec=self.codec,
                                                n=req.n, k=req.k)
             req.n_issued = req.n
+            req.t_injected = time.monotonic()
             for ci in range(req.n):
                 off, ln = req.layout.chunk_range(req.k, ci)
                 self._task_q.put((req, int(ci), coded[off : off + ln]))
@@ -403,21 +440,26 @@ class Proxy:
                 continue
             with self._state_lock:
                 self._idle -= 1
-            if req.t_first_start is None:
-                req.t_first_start = time.monotonic()
+                req.tasks_started += 1
+                if req.t_first_start is None:
+                    req.t_first_start = time.monotonic()
+            t0 = time.monotonic()
             try:
-                if req.op == "read":
-                    off, ln = req.layout.chunk_range(req.k, ci)
-                    data = self.store.get_range(req.key, off, ln)
-                else:
-                    self.store.upload_part(req.key, ci, blob)
-                    data = blob
+                with obs.span("proxy.task", rid=req.rid, chunk=ci):
+                    if req.op == "read":
+                        off, ln = req.layout.chunk_range(req.k, ci)
+                        data = self.store.get_range(req.key, off, ln)
+                    else:
+                        self.store.upload_part(req.key, ci, blob)
+                        data = blob
                 ok = True
             except StorageError:
                 ok = False
             finally:
+                t1 = time.monotonic()
                 with self._state_lock:
                     self._idle += 1
+                    self.thread_busy_s += t1 - t0
             self._on_task_done(req, ci, data if ok else None, ok)
 
     def _on_task_done(self, req: _Request, ci: int, data, ok: bool):
@@ -431,6 +473,7 @@ class Proxy:
                 else:
                     req.failures += 1
                 if len(req.completed) >= req.k:
+                    req.t_kth = time.monotonic()
                     req.cancelled = True  # preemptive cancellation of the rest
                     if not req.raw:
                         # Hand off to the admit loop: the round's completions
@@ -455,6 +498,7 @@ class Proxy:
                 req.failures += 1
             if req.result is None:
                 if len(req.completed) >= req.k:
+                    req.t_kth = time.monotonic()
                     self._finish(req, True)
                 elif req.failures > req.n_issued - req.k:
                     self._finish(req, False)
@@ -520,6 +564,11 @@ class Proxy:
             t_done=time.monotonic(),
             failures=req.failures,
             chunks=chunks,
+            rid=req.rid,
+            t_injected=req.t_injected,
+            t_kth=req.t_kth,
+            n_issued=req.n_issued,
+            tasks_started=req.tasks_started,
         )
         self.results.append(req.result)
         req.done.set()

@@ -293,3 +293,34 @@ def test_closed_loop_round_is_one_launch_and_feeds_writes():
         assert back.ok and back.data == payload
     finally:
         proxy.close()
+
+
+def test_jitted_steps_keep_module_names_under_named_scopes():
+    """The device trace keys on the XLA module names ``jit_core`` (the fused
+    launch) and ``jit_decode_step``; their ops carry the named scopes."""
+    from repro.serve.engine import DECODE_SCOPE, FUSED_SCOPE
+
+    arch = get("qwen1.5-0.5b", smoke=True)
+    params = arch.init(jax.random.key(0))
+    engine = ServingEngine(arch, params, max_seq=32)
+    layout = SharedKeyLayout(K=4, r=2, strip_bytes=16)
+    codec = Codec("jnp")
+    step = FusedServingStep.for_policy(ServePolicy.tofec(), CLS, L, codec=codec)
+    proxy = Proxy(MemoryStore(), StaticPolicy(8, 4), L=4)
+    try:
+        srv = ClosedLoopServer(engine, proxy, layout, step, prompt_len=16)
+        present = np.tile(np.arange(4, 8), (2, 1))
+        mats = codec.decode_mats(present, layout.N, layout.K)
+        rows = np.zeros((2, layout.K, layout.strip_bytes), np.uint8)
+        mats_p, rows_p, bkey = codec.pad_to_bucket("dec", mats, rows, layout.N, layout.K)
+        fused = srv._fn(("pfd", *bkey, 16, layout.strip_bytes, False)).lower(
+            step.tables, step.carry, codec.backend.prep_mats(mats_p), rows_p,
+            np.float32(2), np.float32(-1.0), params)
+    finally:
+        proxy.close()
+    decode = engine._decode.lower(params, jnp.zeros((2, 1), jnp.int32),
+                                  arch.init_cache(2, 32))
+    for lowered, fn, scope in [(fused, "core", FUSED_SCOPE),
+                               (decode, "decode_step", DECODE_SCOPE)]:
+        assert lowered.as_text().startswith(f"module @jit_{fn} ")
+        assert f'op_name="jit({fn})/{scope}/' in lowered.compile().as_text()

@@ -1,9 +1,11 @@
 """repro.obs: collection invariance (bit-identical primary outputs and
 pinned compile counts with telemetry on), device-folded histogram
 correctness against host recounts, exact taskq cancellation accounting,
-span-tree nesting + Chrome-trace JSON validity, the Prometheus formatter,
-the shared CompileStats registry, and the perf-gate comparison rules."""
+spans in the JAX profiler's trace (nesting, tags, instant marks, no session
+no record), the Prometheus formatter, the shared CompileStats registry, and
+the perf-gate comparison rules."""
 
+import gzip
 import json
 
 import numpy as np
@@ -17,6 +19,8 @@ from repro.core.traces import TraceStore
 from repro.fleet import FleetSweep, PolicySpec, grid_cases
 from repro.taskq import TaskqSweep
 
+from profiler_events import named, profiled
+
 CLS = RequestClass("read3mb", 3.0, PAPER_READ_3MB, k_max=6, r_max=2.0, n_max=12)
 L = 16
 SIZES = tuple(CLS.file_mb / k for k in range(1, CLS.k_max + 1))
@@ -25,10 +29,8 @@ SIZES = tuple(CLS.file_mb / k for k in range(1, CLS.k_max + 1))
 @pytest.fixture
 def obs_on():
     obs.set_enabled(True)
-    obs.reset_trace()
     yield
     obs.set_enabled(None)
-    obs.reset_trace()
 
 
 @pytest.fixture
@@ -235,9 +237,9 @@ def test_closed_loop_metrics_invariant_and_exact(tmp_path):
     finally:
         obs.set_enabled(None)
     obs.set_enabled(True)
-    obs.reset_trace()
     try:
-        toks_on, server_on = _serve_tokens()
+        with profiled(tmp_path) as events:
+            toks_on, server_on = _serve_tokens()
         # Generated tokens bit-identical with collection on; still one trace.
         for a, b in zip(toks_off, toks_on):
             np.testing.assert_array_equal(a, b)
@@ -252,17 +254,14 @@ def test_closed_loop_metrics_invariant_and_exact(tmp_path):
         assert sum(snap["hists"]["serve_batch"]) == 2
         assert sum(snap["hists"]["serve_pick_n"]) == 2
         assert snap["highs"]["serve_q_hi"] >= 0.0
-        # The round's host spans export as a loadable Chrome trace.
-        names = {ev["name"] for ev in obs.get_tracer().events()}
+        # The round's host spans are in the profiler's trace, one per round.
+        names = {ev[0] for ev in events}
         assert {"serve.round", "serve.fetch", "serve.launch"} <= names
-        path = obs.write_trace(str(tmp_path / "serve_trace.json"))
-        doc = json.load(open(path))
-        assert any(ev["name"] == "serve.round" for ev in doc["traceEvents"])
+        assert len(named(events, "serve.round")) == 2
         # Prometheus exposition of the same snapshot is well-formed.
         assert "repro_serve_rounds_total 2" in obs.to_prometheus(snap)
     finally:
         obs.set_enabled(None)
-        obs.reset_trace()
 
 
 # ---------------------------------------------------------------------------
@@ -270,62 +269,56 @@ def test_closed_loop_metrics_invariant_and_exact(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_span_nesting_and_chrome_trace_json(obs_on, tmp_path):
-    tr = obs.get_tracer()
-    with obs.span("outer", mesh=[1]):
-        with obs.span("inner", bucket="(4, 64)"):
-            pass
-        with obs.span("inner"):
-            pass
-    by_name: dict = {}
-    for ev in tr.events():  # spans record at exit: inner events come first
-        by_name.setdefault(ev["name"], []).append(ev)
-    (outer,), inners = by_name["outer"], by_name["inner"]
-    assert outer["args"]["depth"] == 0
-    assert outer["args"]["parent"] is None
-    assert all(ev["args"]["depth"] == 1 for ev in inners)
-    assert all(ev["args"]["parent"] == "outer" for ev in inners)
-    assert inners[0]["args"]["bucket"] == "(4, 64)"
-    # Chrome trace_event document: loads back, complete events, µs fields.
-    path = obs.write_trace(str(tmp_path / "trace.json"))
-    doc = json.load(open(path))
-    assert doc["displayTimeUnit"] == "ms"
-    assert len(doc["traceEvents"]) == 3
-    for ev in doc["traceEvents"]:
-        assert ev["ph"] == "X" and ev["dur"] >= 0 and "pid" in ev and "tid" in ev
-    agg = obs.aggregate()
-    assert agg["inner"]["count"] == 2
-    assert agg["outer"]["total_us"] >= agg["outer"]["max_us"]
-    assert "outer" in tr.format_table()
+def test_span_nesting_and_chrome_trace_json(tmp_path):
+    with profiled(tmp_path, perfetto=True) as events:
+        with obs.span("outer", mesh=[1]):
+            with obs.span("inner", bucket="(4, 64)", items=3):
+                pass
+            with obs.span("inner"):
+                pass
+    ((_, o0, o1, o_tags),) = named(events, "outer")
+    inners = sorted(named(events, "inner"), key=lambda e: e[1])
+    assert len(inners) == 2
+    # Nesting is by interval: both inner spans lie inside the outer one,
+    # one after the other.
+    assert o0 <= inners[0][1] <= inners[0][2] <= inners[1][1] <= inners[1][2] <= o1
+    # Tags are the event's stats; the name stays the span's name.
+    assert inners[0][3] == {"bucket": "(4, 64)", "items": 3}
+    assert inners[1][3] == {} and o_tags == {"mesh": "[1]"}
+    # The same spans in the Perfetto (Chrome trace_event) export.
+    (path,) = tmp_path.glob("**/perfetto_trace.json.gz")
+    doc = json.load(gzip.open(path))
+    evs = doc["traceEvents"] if isinstance(doc, dict) else doc
+    assert sum(ev.get("name") == "inner" for ev in evs) == 2
 
 
-def test_spans_disabled_record_nothing():
-    obs.set_enabled(False)
-    obs.reset_trace()
+def test_spans_disabled_record_nothing(tmp_path):
+    # No profiler session: a span records nothing, whatever REPRO_OBS says.
+    obs.set_enabled(True)
     try:
         with obs.span("never"):
             pass
-        assert obs.get_tracer().events() == []
+        obs.instant("never.mark")
     finally:
         obs.set_enabled(None)
+    with profiled(tmp_path) as events:
+        pass
+    assert not named(events, "never") and not named(events, "never.mark")
 
 
-def test_traced_decorator(obs_on):
-    calls = []
-
-    @obs.traced("deco.fn", tag=1)
-    def fn(x):
-        calls.append(x)
-        return x + 1
-
-    assert fn(1) == 2 and calls == [1]
-    ev = [e for e in obs.get_tracer().events() if e["name"] == "deco.fn"]
-    assert len(ev) == 1 and ev[0]["args"]["tag"] == 1
+def test_spans_record_with_collection_off(obs_off, tmp_path):
+    # The profiler's session is the only switch: REPRO_OBS off still traces.
+    with profiled(tmp_path) as events:
+        with obs.span("always", rid=7):
+            pass
+    ((_, t0, t1, tags),) = named(events, "always")
+    assert t1 >= t0 and tags == {"rid": 7}
 
 
-def test_sweep_run_emits_spans(obs_on):
-    FleetSweep(chunk=4).run(_grid(n_seeds=1), 64)
-    names = {ev["name"] for ev in obs.get_tracer().events()}
+def test_sweep_run_emits_spans(obs_on, tmp_path):
+    with profiled(tmp_path) as events:
+        FleetSweep(chunk=4).run(_grid(n_seeds=1), 64)
+    names = {ev[0] for ev in events}
     assert {"sweep.chunk", "sweep.launch", "sweep.trace"} <= names
 
 
@@ -517,7 +510,6 @@ def test_sweep_timeline_rejects_bad_window():
 
 def test_serve_timeline_and_slo_report():
     obs.set_enabled(True)
-    obs.reset_trace()
     try:
         toks, server = _serve_tokens(rounds=3)
         assert server.traces == 1  # the collect variant still compiles once
@@ -538,7 +530,6 @@ def test_serve_timeline_and_slo_report():
         assert "controller_converged" in kinds and "slo_breach" not in kinds
     finally:
         obs.set_enabled(None)
-        obs.reset_trace()
 
 
 def test_serve_timeline_absent_when_disabled(obs_off):
@@ -556,7 +547,8 @@ def test_slo_burn_rate_and_breach_events(obs_on, tmp_path):
             "hists": {"delay": hist}}
     spec = obs.SLOSpec(target_s=1.0, percentile=0.99, window=2)
     events = obs.EventLog("synthetic")
-    report = obs.slo_report(snap, spec, label="synthetic", events=events)
+    with profiled(tmp_path / "prof") as trace_events:
+        report = obs.slo_report(snap, spec, label="synthetic", events=events)
     burn = np.asarray(report["burn_rate"])
     assert (burn[:4] == 0).all() and (burn[4:] >= 1.0).all()
     assert report["breach_slots"] == 4
@@ -570,9 +562,9 @@ def test_slo_burn_rate_and_breach_events(obs_on, tmp_path):
     lines = [json.loads(ln) for ln in open(path)]
     assert all(ev["schema"] == "repro.obs/event/v1" for ev in lines)
     assert {ev["kind"] for ev in lines} == {"slo_breach", "controller_converged"}
-    # Breach events mirror into the span trace as instant marks.
-    marks = [e for e in obs.get_tracer().events() if e.get("ph") == "i"]
-    assert any(e["name"] == "obs.slo_breach" for e in marks)
+    # Breach events mirror into the profiler's trace as zero-length marks.
+    (mark,) = named(trace_events, "obs.slo_breach")
+    assert mark[3]["label"] == "synthetic"
 
 
 def test_slo_recovery_edge():
@@ -611,48 +603,22 @@ def test_prometheus_help_type_and_label_escaping():
 
 
 # ---------------------------------------------------------------------------
-# Trace hygiene: unclosed spans, instant marks
+# Instant marks
 # ---------------------------------------------------------------------------
 
 
-def test_unclosed_spans_autoclose_and_warn_once(obs_on, tmp_path):
-    import warnings
+def test_instant_marks_export_and_skip_aggregate(tmp_path):
+    import time
 
-    sp1 = obs.span("dangling.outer", tag=1)
-    sp1.__enter__()
-    sp2 = obs.span("dangling.inner")
-    sp2.__enter__()
-    with pytest.warns(RuntimeWarning, match="dangling"):
-        path = obs.write_trace(str(tmp_path / "t.json"))
-    doc = json.load(open(path))
-    bad = {e["name"]: e for e in doc["traceEvents"]
-           if e["args"].get("incomplete")}
-    assert set(bad) == {"dangling.outer", "dangling.inner"}
-    assert bad["dangling.outer"]["args"]["tag"] == 1
-    # The late real exits are no-ops; a second export neither warns again
-    # nor duplicates the auto-closed records.
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        sp2.__exit__(None, None, None)
-        sp1.__exit__(None, None, None)
-        sp3 = obs.span("dangling.late")
-        sp3.__enter__()
-        path2 = obs.write_trace(str(tmp_path / "t2.json"))
-    doc2 = json.load(open(path2))
-    names = [e["name"] for e in doc2["traceEvents"]]
-    assert names.count("dangling.outer") == 1
-    assert "dangling.late" in names
-
-
-def test_instant_marks_export_and_skip_aggregate(obs_on, tmp_path):
-    obs.instant("mark.one", detail="x")
-    with obs.span("real"):
-        pass
-    doc = json.load(open(obs.write_trace(str(tmp_path / "t.json"))))
-    marks = [e for e in doc["traceEvents"] if e["ph"] == "i"]
-    assert len(marks) == 1 and marks[0]["args"]["detail"] == "x"
-    agg = obs.aggregate()  # duration table ignores the durationless marks
-    assert "real" in agg and "mark.one" not in agg
+    with profiled(tmp_path) as events:
+        obs.instant("mark.one", detail="x")
+        with obs.span("real"):
+            time.sleep(1e-3)
+    ((_, m0, m1, tags),) = named(events, "mark.one")
+    assert tags == {"detail": "x"}
+    # A mark is a zero-length span: it adds nothing to a sum of durations.
+    ((_, r0, r1, _),) = named(events, "real")
+    assert m1 - m0 < 1e5 <= r1 - r0 and m1 <= r0
 
 
 # ---------------------------------------------------------------------------

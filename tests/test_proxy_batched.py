@@ -2,8 +2,10 @@
 paper's heavy-load adaptation (backlog pressure → fewer/larger chunks)."""
 
 import threading
+import time
 
 import numpy as np
+import pytest
 
 from repro.coding.layout import SharedKeyLayout
 from repro.core import (
@@ -228,3 +230,100 @@ def test_closed_write_path_recodes_after_midrun_switch():
             assert res.ok and res.data == p
     finally:
         proxy.close()
+
+
+# ---------------------------------------------------------------------------
+# Phase timestamps, task counts and the thread-busy counter
+# ---------------------------------------------------------------------------
+
+
+def _latency_proxy(L=4, seed=0):
+    """A proxy over the paper's task delays at 2 ms of wall time per emulated s."""
+    from repro.storage import LatencyStore
+
+    inner = MemoryStore()
+    store = LatencyStore(inner, PAPER_READ_3MB, time_scale=2e-3, seed=seed)
+    cls = RequestClass("latency", LAYOUT.file_bytes / 2**20, PAPER_READ_3MB,
+                       k_max=6, r_max=2.0, n_max=12)
+    return inner, Proxy(store, TOFECPolicy.for_classes([cls], L=L), L=L)
+
+
+@pytest.mark.parametrize("op", ["read", "write"])
+def test_answered_requests_keep_phase_order_and_task_counts(op):
+    rng = np.random.default_rng(21)
+    inner, proxy = _latency_proxy()
+    payloads = _payloads(rng, 12, LAYOUT.file_bytes)
+    try:
+        if op == "read":
+            for i, p in enumerate(payloads):
+                store_coded_object(inner, f"o/{i}", LAYOUT, p)
+            reqs = [proxy.read_async(f"o/{i}", LAYOUT, LAYOUT.file_bytes)
+                    for i in range(len(payloads))]
+        else:
+            reqs = [proxy.write_async(f"o/{i}", LAYOUT, p)
+                    for i, p in enumerate(payloads)]
+        results = [proxy.wait(r, 30.0) for r in reqs]
+        proxy.flush_writes(timeout=30.0)
+    finally:
+        proxy.close()
+    assert all(r.ok for r in results)
+    if op == "read":
+        assert [r.data for r in results] == payloads
+    assert sorted(r.rid for r in results) == list(range(len(payloads)))
+    for r in results:
+        assert r.t_arrival <= r.t_injected <= r.t_first_start <= r.t_kth <= r.t_done
+        assert r.k <= r.tasks_started <= r.n_issued <= r.n
+
+
+def test_thread_busy_counts_store_time_within_its_bounds():
+    L = 4
+    rng = np.random.default_rng(22)
+    inner, proxy = _latency_proxy(L=L, seed=1)
+    payloads = _payloads(rng, 16, LAYOUT.file_bytes)
+    for i, p in enumerate(payloads):
+        store_coded_object(inner, f"o/{i}", LAYOUT, p)
+    t0 = time.monotonic()
+    try:
+        assert proxy.thread_busy_s == 0.0
+        results = proxy.read_many([f"o/{i}" for i in range(len(payloads))], LAYOUT,
+                                  LAYOUT.file_bytes, timeout=30.0)
+        # a cancelled task still running finishes within its emulated delay
+        time.sleep(0.2)
+        elapsed = time.monotonic() - t0
+        busy = proxy.thread_busy_s
+    finally:
+        proxy.close()
+    assert all(r.ok for r in results)
+    # every task sleeps its emulated delay inside the store call
+    started = sum(r.tasks_started for r in results)
+    assert started * 2e-3 * PAPER_READ_3MB.delta_bar / 8 < busy <= L * elapsed
+
+
+def test_profiler_trace_tags_a_reads_spans_with_its_rid(tmp_path):
+    from profiler_events import named, profiled
+
+    rng = np.random.default_rng(23)
+    inner, proxy = _latency_proxy()
+    payloads = _payloads(rng, 6, LAYOUT.file_bytes)
+    for i, p in enumerate(payloads):
+        store_coded_object(inner, f"o/{i}", LAYOUT, p)
+    try:
+        with profiled(tmp_path) as events:
+            results = proxy.read_many([f"o/{i}" for i in range(len(payloads))],
+                                      LAYOUT, LAYOUT.file_bytes, timeout=30.0)
+            proxy.flush_writes()
+    finally:
+        proxy.close()
+    assert all(r.ok for r in results)
+    waits = {ev[3]["rid"]: ev for ev in named(events, "proxy.admit_wait")}
+    tasks = named(events, "proxy.task")
+    assert named(events, "proxy.admit_round") and named(events, "proxy.reconstruct")
+    for r in results:
+        mine = [ev for ev in tasks if ev[3]["rid"] == r.rid]
+        # one task span per task started, each after the read's admission wait
+        assert len(mine) == r.tasks_started >= r.k
+        assert all(ev[1] >= waits[r.rid][2] for ev in mine)
+        # distinct chunks of the read's chunk-level code
+        chunks = [ev[3]["chunk"] for ev in mine]
+        assert len(set(chunks)) == len(chunks)
+        assert max(chunks) < LAYOUT.code_for_k(r.k)[0]

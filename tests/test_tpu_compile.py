@@ -127,3 +127,21 @@ def test_moe_expert_contraction_stays_bf16_on_v5e(one_chip):
     for ln in experts:
         operands, result = ln.rsplit("->", 1)
         assert operands.count("bf16>") == 2 and "xf32>" in result, ln
+
+
+def test_codec_kernel_keeps_its_names_for_v5e(one_chip):
+    """The device trace finds the codec by its custom call (``tpu_custom_call``),
+    now named after the kernel, inside the codec's named jitted function."""
+    from repro.coding.codec import get_codec
+    from repro.kernels.gf2mm.gf2mm import KERNEL_NAME
+
+    fn = get_codec("pallas", interpret=False).backend._build(6)
+    mats = jax.ShapeDtypeStruct((2, 64, 48), jnp.uint8, sharding=one_chip)
+    data = jax.ShapeDtypeStruct((2, 6, 2**19), jnp.uint8, sharding=one_chip)
+    lowered = fn.lower(mats, data)
+    assert lowered.as_text().startswith("module @jit_rs_codec_matmul ")
+    assert f'kernel_name = "{KERNEL_NAME}"' in lowered.as_text()
+    calls = [ln for ln in lowered.compile().as_text().splitlines()
+             if "custom-call(" in ln]
+    assert len(calls) == 1
+    assert f"%{KERNEL_NAME}" in calls[0] and 'custom_call_target="tpu_custom_call"' in calls[0]

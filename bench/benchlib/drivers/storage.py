@@ -189,8 +189,6 @@ def run(ctx) -> RunRecord:
         rec.t0 = time.monotonic()
         rec.setup_s = rec.t0 - ctx.t_proc0
         rec.t_end = rec.t0 + ctx.seconds
-        if getattr(proxy, "thread_busy_s", None) is not None:
-            rec.thread_busy.append((rec.t0, proxy.thread_busy_s, proxy.L))
         stop = threading.Event()
         flusher = None
         if op == "write":
@@ -228,18 +226,11 @@ def run(ctx) -> RunRecord:
                     h = proxy.write_async(f"obj/{ki}", layout, pool_bytes[pj])
                 last_write[ki] = (h, pj)
                 handles.append((Request("write", t_due, t_send, key_index=ki, answer=pj), h))
-        if getattr(proxy, "thread_busy_s", None) is not None:
-            rec.thread_busy.append((time.monotonic(), proxy.thread_busy_s, proxy.L))
         for req, h in handles:
             h.done.wait(max(rec.t_end + ANSWER_TIMEOUT_S - time.monotonic(), 0.0))
             res = h.result
             if res is not None:
                 req.first_start, req.done, req.ok = res.t_first_start, res.t_done, res.ok
-                req.arrival, req.k = res.t_arrival, res.k
-                req.injected = getattr(res, "t_injected", None)
-                req.kth = getattr(res, "t_kth", None)
-                req.n_issued = getattr(res, "n_issued", 0)
-                req.tasks_started = getattr(res, "tasks_started", 0)
                 if op == "read":
                     req.answer = res.data
             rec.requests.append(req)

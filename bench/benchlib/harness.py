@@ -144,14 +144,6 @@ class Request:
     #: what the request returned, kept for the check after the window
     answer: object = None
     key_index: int = -1
-    #: the program's own phase times and task counts (``RequestResult``);
-    #: None or 0 where the program does not record them
-    arrival: float | None = None
-    injected: float | None = None
-    kth: float | None = None
-    k: int = 0
-    n_issued: int = 0
-    tasks_started: int = 0
 
 
 @dataclasses.dataclass
@@ -218,9 +210,6 @@ class RunRecord:
     failed: int = 0
     memory_peak_bytes: int | None = None
     compiles_in_window: int = 0
-    #: samples of the proxy's ``thread_busy_s``: (host time, thread-seconds,
-    #: threads), at the window's start and after the generator's last send
-    thread_busy: list = dataclasses.field(default_factory=list)
 
     @property
     def trace(self) -> trace_mod.TraceSummary | None:

@@ -5,4 +5,4 @@ from benchlib import program_readers
 
 
 def read(run):
-    return program_readers.phase_mean_ms(run, "read", "kth", "done")
+    return program_readers.phase_mean_ms(run, "read", "t_kth", "t_done")

@@ -5,4 +5,4 @@ from benchlib import program_readers
 
 
 def read(run):
-    return program_readers.phase_mean_ms(run, "read", "first_start", "kth")
+    return program_readers.phase_mean_ms(run, "read", "t_first_start", "t_kth")

@@ -46,7 +46,9 @@ surviving chunks + indices in :attr:`RequestResult.chunks`.
 Every answered request carries its phases on the host clock (arrival →
 tasks injected → first task starts → k-th chunk → answer) and its task
 counts on :class:`RequestResult`; :attr:`Proxy.thread_busy_s` sums the
-threads' time inside store calls. Under a JAX profiler session the admit
+threads' time inside store calls. :data:`TELEMETRY` keeps both for every
+proxy of the process (results without payloads), for readers that come
+after the fact. Under a JAX profiler session the admit
 loop, the workers and the batched decode also write spans
 (``proxy.admit_round``, ``proxy.admit_wait``, ``proxy.task``,
 ``proxy.reconstruct``) into the trace, tagged with the request's ``rid``.
@@ -55,6 +57,7 @@ loop, the workers and the batched decode also write spans
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import logging
 import queue as _queue
 import threading
@@ -74,6 +77,7 @@ _log = logging.getLogger(__name__)
 
 #: admit-loop wakeup marker: a completed read is waiting for batched decode.
 _WAKE = object()
+_serials = itertools.count()
 
 
 @dataclasses.dataclass
@@ -116,6 +120,23 @@ class RequestResult:
     @property
     def service_s(self) -> float:
         return self.t_done - self.t_first_start
+
+
+class Telemetry:
+    """What every proxy of the process did, kept for readers after the fact
+    (a benchmark's metric readers, a dashboard), as
+    :func:`repro.obs.compile_snapshot` keeps compiles. Bounded: the oldest
+    entries go first."""
+
+    def __init__(self, maxlen: int = 1 << 14):
+        #: (proxy serial, RequestResult with ``data`` and ``chunks`` None)
+        self.results: deque = deque(maxlen=maxlen)
+        #: (proxy serial, host time, Proxy.thread_busy_s, L): one when the
+        #: proxy starts, then one per task a worker finished
+        self.busy: deque = deque(maxlen=8 * maxlen)
+
+
+TELEMETRY = Telemetry()
 
 
 class _Request:
@@ -177,6 +198,9 @@ class Proxy:
         self._next_rid = 0
         #: thread-seconds spent inside store calls, summed over the L threads
         self.thread_busy_s = 0.0
+        #: this proxy's number in :data:`TELEMETRY`
+        self.serial = next(_serials)
+        TELEMETRY.busy.append((self.serial, time.monotonic(), 0.0, L))
         self._shutdown = False
         self.results: list[RequestResult] = []
         self._threads = [
@@ -456,10 +480,11 @@ class Proxy:
             except StorageError:
                 ok = False
             finally:
-                t1 = time.monotonic()
                 with self._state_lock:
+                    t1 = time.monotonic()
                     self._idle += 1
                     self.thread_busy_s += t1 - t0
+                    TELEMETRY.busy.append((self.serial, t1, self.thread_busy_s, self.L))
             self._on_task_done(req, ci, data if ok else None, ok)
 
     def _on_task_done(self, req: _Request, ci: int, data, ok: bool):
@@ -571,6 +596,8 @@ class Proxy:
             tasks_started=req.tasks_started,
         )
         self.results.append(req.result)
+        TELEMETRY.results.append(
+            (self.serial, dataclasses.replace(req.result, data=None, chunks=None)))
         req.done.set()
 
 

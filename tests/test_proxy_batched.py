@@ -330,3 +330,33 @@ def test_profiler_trace_tags_a_reads_spans_with_its_rid(tmp_path):
         chunks = [ev[3]["chunk"] for ev in mine]
         assert len(set(chunks)) == len(chunks)
         assert max(chunks) < LAYOUT.code_for_k(r.k)[0]
+
+
+def test_telemetry_keeps_answers_without_payloads_and_busy_samples():
+    from repro.storage.proxy import TELEMETRY
+
+    rng = np.random.default_rng(24)
+    inner, proxy = _latency_proxy(L=4, seed=2)
+    payloads = _payloads(rng, 8, LAYOUT.file_bytes)
+    for i, p in enumerate(payloads):
+        store_coded_object(inner, f"o/{i}", LAYOUT, p)
+    try:
+        results = proxy.read_many([f"o/{i}" for i in range(len(payloads))], LAYOUT,
+                                  LAYOUT.file_bytes, timeout=30.0)
+        # a cancelled task still running finishes within its emulated delay
+        time.sleep(0.2)
+        busy = proxy.thread_busy_s
+    finally:
+        proxy.close()
+    assert [r.data for r in results] == payloads
+    mine = {res.t_done: res for s, res in list(TELEMETRY.results) if s == proxy.serial}
+    assert sorted(mine) == sorted(r.t_done for r in results)
+    for r in results:
+        kept = mine[r.t_done]
+        assert kept.data is None and kept.chunks is None
+        assert (kept.rid, kept.t_injected, kept.t_kth, kept.tasks_started) == \
+            (r.rid, r.t_injected, r.t_kth, r.tasks_started)
+    samples = [(t, b, n) for s, t, b, n in list(TELEMETRY.busy) if s == proxy.serial]
+    assert samples[0][1] == 0.0 and {n for _, _, n in samples} == {4}
+    assert all(a[0] <= b[0] and a[1] <= b[1] for a, b in zip(samples, samples[1:]))
+    assert samples[-1][1] == busy

@@ -4,8 +4,8 @@ Prompts live in the emulated store as Shared-Key coded objects; the proxy
 fetches them with adaptive (n, k) ranged reads under an S3-like latency
 model, tolerating injected read failures; the LM then prefills + decodes.
 
-The fetch runs twice: once on the unfused path (the proxy batch-decodes
-completions per admission round on the host codec) and once through the
+The fetch runs twice: once on the unfused path (the proxy's decoder thread
+batch-decodes completions on the host codec) and once through the
 fused serving step — one jitted launch running the TOFEC admission update
 AND the batched MDS decode for the whole round. The fused step runs the
 default codec when it is jitted (``REPRO_CODEC_BACKEND=jnp`` or ``pallas``,

@@ -16,9 +16,8 @@ TPU, the numpy oracle elsewhere, ``REPRO_CODEC_BACKEND`` to override) and
 can be overridden per call. :func:`encode_files` amortizes one kernel
 launch over a whole batch of same-class files — the proxy's write-queue
 drain uses it — and :func:`reconstruct_batch` is its read-side mirror: one
-batched decode with per-item ``present`` masks reconstructs a whole
-admission round of completed reads, across heterogeneous chunk levels and
-erasure patterns.
+batched decode with per-item ``present`` masks reconstructs many completed
+reads at once, across heterogeneous chunk levels and erasure patterns.
 """
 
 from __future__ import annotations
@@ -215,7 +214,7 @@ class SharedKeyLayout:
 
         ``items`` is a sequence of (k_level, chunks, payload_len) triples.
         All reads of one layout share the strip-level (N, K) code no matter
-        which chunk level k served them, so the whole admission round — with
+        which chunk level k served them, so a whole batch of reads — with
         heterogeneous chunk levels *and* heterogeneous erasure patterns —
         collapses into a single ``codec.decode`` call with per-item
         ``present`` masks (the proxy's read-side amortization, the mirror of

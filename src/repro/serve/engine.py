@@ -9,8 +9,8 @@ LM path is the substrate it feeds.
 Three fetch paths:
 
 * **unfused** — :meth:`ServingEngine.fetch_prompts` submits the whole round
-  through :meth:`Proxy.read_many`; the proxy batch-decodes completions per
-  admission round on the host codec.
+  through :meth:`Proxy.read_many`; the proxy's decoder thread batch-decodes
+  completions on the host codec.
 * **fused** — pass a :class:`FusedServingStep`: the proxy returns raw chunks
   (``raw=True``) and ONE jitted launch then runs the admission update *and*
   the batched MDS decode for the whole round. The controller is runtime data
@@ -430,7 +430,7 @@ class ServingEngine:
     ) -> tuple[np.ndarray, list[float], list[tuple[int, int]], tuple[int, int] | None]:
         """Batched prompt fetch: the whole round is submitted up front (the
         proxy's policy sees it as backlog) and reconstructed batched — by the
-        proxy's admission round (unfused) or by ``fused``'s single jitted
+        proxy's decoder thread (unfused) or by ``fused``'s single jitted
         admission+decode launch (raw chunks in, payloads out).
 
         Reads that exhaust their n − k failure budget (the backlog-adapted

@@ -30,14 +30,17 @@ FAST CLOUD §IV):
 * writes — each round drains every queued write and encodes all same-layout
   payloads with ONE batched :meth:`SharedKeyLayout.encode_files` call;
 * reads — completed reads accumulate (workers only collect chunks and hand
-  the finished request to the admit loop) and each round reconstructs the
-  whole accumulation with ONE batched :meth:`SharedKeyLayout.reconstruct_batch`
-  call, per-item ``present`` masks carrying each request's own erasure
-  pattern and chunk level through a single ``codec.decode``.
+  the finished request to the proxy's decoder thread) and the decoder
+  reconstructs them in batched :meth:`SharedKeyLayout.reconstruct_batch`
+  calls of at most :data:`DECODE_BATCH_MAX` reads, per-item ``present``
+  masks carrying each request's own erasure pattern and chunk level through
+  a single ``codec.decode``.
 
 The admission *rule* (inject the next request's tasks only when the task
-queue is drained and a thread idles) is unchanged — batching moves coding
-off the per-request critical path, not the paper's queueing model. Callers
+queue is drained and a thread idles) is the paper's, and the admit loop
+does nothing else on the read path: a decode never holds up an injection.
+The backlog each request's policy sees is the paper's q, the requests
+submitted whose tasks are not yet on the task queue. Callers
 that want the raw chunks instead (e.g. the fused serving step in
 :mod:`repro.serve.engine`, which decodes *inside* its jitted step) pass
 ``raw=True``; those requests skip proxy-side decode and return their
@@ -48,8 +51,8 @@ tasks injected → first task starts → k-th chunk → answer) and its task
 counts on :class:`RequestResult`; :attr:`Proxy.thread_busy_s` sums the
 threads' time inside store calls. :data:`TELEMETRY` keeps both for every
 proxy of the process (results without payloads), for readers that come
-after the fact. Under a JAX profiler session the admit
-loop, the workers and the batched decode also write spans
+after the fact, with each batched decode's times. Under a JAX profiler
+session the admit loop, the workers and the decoder also write spans
 (``proxy.admit_round``, ``proxy.admit_wait``, ``proxy.task``,
 ``proxy.reconstruct``) into the trace, tagged with the request's ``rid``.
 """
@@ -75,9 +78,13 @@ from repro.storage.backend import ObjectStore, StorageError
 
 _log = logging.getLogger(__name__)
 
-#: admit-loop wakeup marker: a completed read is waiting for batched decode.
-_WAKE = object()
 _serials = itertools.count()
+#: most reads per batched decode. On a TPU v5e host a 3 MiB read takes about
+#: 9 ms of host time to decode (gather, pad, copies to and from the device)
+#: whatever the batch, padding a batch to its power-of-two bucket costs as
+#: much as a real read, and a batch holds each of its reads until all of it
+#: is back: small power-of-two batches keep the decoder ahead of arrivals.
+DECODE_BATCH_MAX = 4
 
 
 @dataclasses.dataclass
@@ -108,6 +115,8 @@ class RequestResult:
     n_issued: int = 0
     #: tasks a worker ran (those skipped after cancellation are not counted)
     tasks_started: int = 0
+    #: the backlog the policy was given at submission (TOFEC's q)
+    q: int | None = None
 
     @property
     def total_s(self) -> float:
@@ -134,6 +143,9 @@ class Telemetry:
         #: (proxy serial, host time, Proxy.thread_busy_s, L): one when the
         #: proxy starts, then one per task a worker finished
         self.busy: deque = deque(maxlen=8 * maxlen)
+        #: (proxy serial, host start, host end, reads): one per batched
+        #: reconstruct of the decoder thread
+        self.decodes: deque = deque(maxlen=maxlen)
 
 
 TELEMETRY = Telemetry()
@@ -141,8 +153,9 @@ TELEMETRY = Telemetry()
 
 class _Request:
     def __init__(self, rid, op, key, layout, payload, payload_len, n, k, cls_id,
-                 raw=False):
+                 raw=False, q=None):
         self.rid = rid
+        self.q = q
         self.op = op
         self.key = key
         self.layout: SharedKeyLayout = layout
@@ -187,13 +200,15 @@ class Proxy:
         self._write_reqs: list[_Request] = []
         self._task_q: _queue.Queue = _queue.Queue()
         self._request_q: _queue.Queue = _queue.Queue()
-        # Completed (non-raw) reads awaiting the admission round's ONE
-        # batched reconstruct; fed by workers, drained by the admit loop.
+        # Completed (non-raw) reads awaiting a batched reconstruct; fed by
+        # the workers, drained by the decoder thread. The last worker to
+        # exit closes it with None.
         self._decode_q: _queue.Queue = _queue.Queue()
         self._idle = L
-        # Requests the admit loop has drained but not yet injected: still
-        # queued from the policy's point of view (TOFEC's q signal).
-        self._admit_backlog = 0
+        self._workers_live = L
+        # Submitted requests whose tasks are not yet on the task queue: the
+        # paper's q (TOFEC's backlog signal).
+        self._backlog = 0
         self._state_lock = threading.Lock()
         self._next_rid = 0
         #: thread-seconds spent inside store calls, summed over the L threads
@@ -207,10 +222,14 @@ class Proxy:
             threading.Thread(target=self._worker, daemon=True, name=f"proxy-{i}")
             for i in range(L)
         ]
-        self._admitter = threading.Thread(target=self._admit_loop, daemon=True)
+        self._admitter = threading.Thread(target=self._admit_loop, daemon=True,
+                                          name="proxy-admit")
+        self._decoder = threading.Thread(target=self._decode_loop, daemon=True,
+                                         name="proxy-decoder")
         for t in self._threads:
             t.start()
         self._admitter.start()
+        self._decoder.start()
 
     # -- public API ---------------------------------------------------------
 
@@ -241,7 +260,7 @@ class Proxy:
         """Batched fetch: submit every key up front, then collect.
 
         Submitting the whole round before waiting lets the policy see the
-        true backlog (TOFEC's q signal) and lets the admit loop reconstruct
+        true backlog (TOFEC's q signal) and lets the decoder reconstruct
         the completions in batched decode calls instead of one per request.
         """
         with obs.span("proxy.read_many", keys=len(keys), raw=raw):
@@ -285,6 +304,8 @@ class Proxy:
                     raise TimeoutError(f"write {r.key} did not settle")
 
     def close(self):
+        """Stop admitting. Tasks already queued still run, and every read
+        they complete is decoded and answered before the decoder stops."""
         self._shutdown = True
         self._request_q.put(None)
         for _ in self._threads:
@@ -293,8 +314,12 @@ class Proxy:
     # -- internals ----------------------------------------------------------
 
     def _submit(self, op, key, layout, payload, payload_len, cls_id, raw=False) -> _Request:
+        # q: the requests submitted before this one whose tasks are not yet
+        # on the task queue, the head-of-line one the admit loop holds
+        # included; simulate_shared_pool's observed_q for one FIFO class.
         with self._state_lock:
-            q_len = self._request_q.qsize() + self._admit_backlog
+            q_len = self._backlog
+            self._backlog += 1
             idle = self._idle
             rid = self._next_rid
             self._next_rid += 1
@@ -305,7 +330,8 @@ class Proxy:
         k = max(kk for kk in layout.supported_k() if kk <= k)
         n_max, _, _ = layout.code_for_k(k)
         n = max(k, min(n, n_max))
-        req = _Request(rid, op, key, layout, payload, payload_len, n, k, cls_id, raw=raw)
+        req = _Request(rid, op, key, layout, payload, payload_len, n, k, cls_id,
+                       raw=raw, q=q_len)
         if op == "write":
             with self._state_lock:
                 self._write_reqs.append(req)
@@ -318,33 +344,21 @@ class Proxy:
             if not pending:
                 req = self._request_q.get()
                 if req is None:
-                    break
-                if req is _WAKE:  # a read completed while we were idle
-                    self._flush_completed_reads()
-                    continue
+                    return
                 pending.append(req)
             # Drain everything else that already arrived, then batch-encode
-            # all queued writes (and batch-decode all completed reads) in one
-            # codec call per layout class.
+            # all queued writes in one codec call per (layout, n, k).
             while True:
                 try:
                     req = self._request_q.get_nowait()
                 except _queue.Empty:
                     break
                 if req is None:
-                    self._flush_completed_reads()
                     return
-                if req is _WAKE:
-                    continue
                 pending.append(req)
-            with self._state_lock:
-                self._admit_backlog = len(pending)
             with obs.span("proxy.admit_round", pending=len(pending)):
-                self._flush_completed_reads()
                 self._encode_pending_writes(pending)
                 req = pending.popleft()
-                with self._state_lock:
-                    self._admit_backlog = len(pending)
                 # Paper's admission rule: wait until the task queue is drained
                 # and a thread is idle before injecting the next batch.
                 with obs.span("proxy.admit_wait", rid=req.rid):
@@ -353,31 +367,46 @@ class Proxy:
                             ready = self._idle > 0 and self._task_q.empty()
                         if ready:
                             break
-                        self._flush_completed_reads()  # decode while tasks drain
                         time.sleep(1e-4)
                 self._inject(req)
-        self._flush_completed_reads()
 
-    def _flush_completed_reads(self) -> None:
+    def _decode_loop(self):
+        """The decoder thread: reads are decoded in arrival order, each codec
+        call taking the largest power of two of the queued reads up to
+        :data:`DECODE_BATCH_MAX`, until the last worker has exited (``None``,
+        the last item ever queued)."""
+        pending: deque[_Request] = deque()
+        closing = False
+        while pending or not closing:
+            if not pending:
+                item = self._decode_q.get()
+                if item is None:
+                    return
+                pending.append(item)
+            while not closing:
+                try:
+                    item = self._decode_q.get_nowait()
+                except _queue.Empty:
+                    break
+                if item is None:
+                    closing = True
+                else:
+                    pending.append(item)
+            batch = 1 << (min(len(pending), DECODE_BATCH_MAX).bit_length() - 1)
+            self._reconstruct([pending.popleft() for _ in range(batch)])
+
+    def _reconstruct(self, reqs: list[_Request]) -> None:
         """One batched reconstruct per layout group of completed reads.
 
-        This is the read-side twin of :meth:`_encode_pending_writes`: all
-        reads that finished since the last round — any mix of chunk levels
-        and erasure patterns — decode in a single ``codec.decode`` per
-        layout via per-item ``present`` masks.
+        This is the read-side twin of :meth:`_encode_pending_writes`: any mix
+        of chunk levels and erasure patterns decodes in a single
+        ``codec.decode`` per layout via per-item ``present`` masks.
         """
-        reqs: list[_Request] = []
-        while True:
-            try:
-                reqs.append(self._decode_q.get_nowait())
-            except _queue.Empty:
-                break
-        if not reqs:
-            return
         groups: dict[SharedKeyLayout, list[_Request]] = {}
         for r in reqs:
             groups.setdefault(r.layout, []).append(r)
         for lay, group in groups.items():
+            t0 = time.monotonic()
             try:
                 with obs.span("proxy.reconstruct", batch=len(group)):
                     datas = lay.reconstruct_batch(
@@ -388,19 +417,18 @@ class Proxy:
                 # per-request decode so one bad item can't wedge the rest.
                 _log.warning("batched reconstruct failed (%s); retrying "
                              "per-request", batch_err)
-                for r in group:
-                    try:
-                        data = lay.reconstruct(r.k, r.completed, r.payload_len,
-                                               codec=self.codec)
-                        self._finish(r, True, data=data)
-                    except Exception:
-                        _log.exception("reconstruct failed for read %r "
-                                       "(k=%d, chunks=%s)", r.key, r.k,
-                                       sorted(r.completed))
-                        self._finish(r, False)
-                continue
+                datas = [self._reconstruct_one(lay, r) for r in group]
+            TELEMETRY.decodes.append((self.serial, t0, time.monotonic(), len(group)))
             for r, data in zip(group, datas):
-                self._finish(r, True, data=data)
+                self._finish(r, data is not None, data=data)
+
+    def _reconstruct_one(self, lay: SharedKeyLayout, r: _Request) -> bytes | None:
+        try:
+            return lay.reconstruct(r.k, r.completed, r.payload_len, codec=self.codec)
+        except Exception:
+            _log.exception("reconstruct failed for read %r (k=%d, chunks=%s)",
+                           r.key, r.k, sorted(r.completed))
+            return None
 
     def _encode_pending_writes(self, pending: "deque[_Request]") -> None:
         """One batched encode per (layout, n, k) group of queued writes.
@@ -421,6 +449,7 @@ class Proxy:
                 r.coded = c
 
     def _inject(self, req: _Request):
+        """Put the request's tasks on the task queue: it leaves the backlog."""
         if req.op == "read":
             n_max, _, m = req.layout.code_for_k(req.k)
             with self._state_lock:
@@ -433,31 +462,33 @@ class Proxy:
                         if all(s in avail for s in range(ci * m, (ci + 1) * m))]
             # Prefer spread of chunk indices across the object (diversity).
             order = np.random.default_rng(hash(req.key) & 0xFFFF).permutation(len(cand))
-            issue = [cand[i] for i in order[: req.n]]
-            req.n_issued = len(issue)
-            if req.n_issued < req.k:
-                with req.lock:
-                    req.cancelled = True
-                    self._finish(req, False)
-                return
-            req.t_injected = time.monotonic()
-            for ci in issue:
-                self._task_q.put((req, int(ci), None))
+            tasks = [(req, int(cand[i]), None) for i in order[: req.n]]
         else:
-            coded = req.coded
-            if coded is None:  # direct _inject callers outside the admit loop
-                coded = req.layout.encode_file(req.payload, codec=self.codec,
-                                               n=req.n, k=req.k)
-            req.n_issued = req.n
-            req.t_injected = time.monotonic()
+            tasks = []
             for ci in range(req.n):
                 off, ln = req.layout.chunk_range(req.k, ci)
-                self._task_q.put((req, int(ci), coded[off : off + ln]))
+                tasks.append((req, ci, req.coded[off : off + ln]))
+        req.n_issued = len(tasks)
+        if req.n_issued < req.k:
+            with req.lock:
+                req.cancelled = True
+                self._finish(req, False)
+        else:
+            req.t_injected = time.monotonic()
+            for task in tasks:
+                self._task_q.put(task)
+        with self._state_lock:
+            self._backlog -= 1
 
     def _worker(self):
         while True:
             item = self._task_q.get()
             if item is None:
+                with self._state_lock:
+                    self._workers_live -= 1
+                    last = self._workers_live == 0
+                if last:
+                    self._decode_q.put(None)
                 return
             req, ci, blob = item
             if req.cancelled:
@@ -501,14 +532,10 @@ class Proxy:
                     req.t_kth = time.monotonic()
                     req.cancelled = True  # preemptive cancellation of the rest
                     if not req.raw:
-                        # Hand off to the admit loop: the round's completions
-                        # reconstruct together in one batched decode.
+                        # Hand off to the decoder thread: reads that complete
+                        # while it decodes reconstruct together in its next
+                        # batched decode.
                         self._decode_q.put(req)
-                        self._request_q.put(_WAKE)
-                        if self._shutdown:
-                            # The admit loop may already have done its final
-                            # flush; decode inline so the waiter isn't stranded.
-                            self._flush_completed_reads()
                     else:
                         self._finish(req, True)
                 elif req.failures > req.n_issued - req.k:
@@ -570,10 +597,6 @@ class Proxy:
             # partially-failed batch item carries its own per-item error mask
             # (ok=False) + partial data instead of wedging the whole batch.
             chunks = dict(req.completed)
-        elif ok and req.op == "read" and data is None:
-            # direct callers bypassing the admit loop
-            data = req.layout.reconstruct(req.k, req.completed, req.payload_len,
-                                          codec=self.codec)
         # writes: k parts durable → request complete; the remaining uploads
         # keep running in background (footnote 1) and _finalize_write
         # assembles the readable object once they all resolve.
@@ -594,6 +617,7 @@ class Proxy:
             t_kth=req.t_kth,
             n_issued=req.n_issued,
             tasks_started=req.tasks_started,
+            q=req.q,
         )
         self.results.append(req.result)
         TELEMETRY.results.append(

@@ -1,5 +1,6 @@
-"""Proxy batched read path: admission-round decode, raw reads, and the
-paper's heavy-load adaptation (backlog pressure → fewer/larger chunks)."""
+"""Proxy batched read path: the decoder thread's batched decode, raw reads,
+and the paper's heavy-load adaptation (backlog pressure → fewer/larger
+chunks)."""
 
 import threading
 import time
@@ -51,7 +52,7 @@ def _payloads(rng, count, nbytes):
 def test_read_many_batch_decodes_heterogeneous_erasures():
     """One round of concurrent reads with random per-item failures: every
     item reconstructs despite each surviving a different erasure pattern
-    (the admission round's single batched decode path)."""
+    (the decoder's batched decode path)."""
     rng = np.random.default_rng(0)
     inner = MemoryStore()
     payloads = _payloads(rng, 8, LAYOUT.file_bytes - 11)
@@ -360,3 +361,155 @@ def test_telemetry_keeps_answers_without_payloads_and_busy_samples():
     assert samples[0][1] == 0.0 and {n for _, _, n in samples} == {4}
     assert all(a[0] <= b[0] and a[1] <= b[1] for a, b in zip(samples, samples[1:]))
     assert samples[-1][1] == busy
+
+
+# ---------------------------------------------------------------------------
+# The decoder thread and the paper's backlog
+# ---------------------------------------------------------------------------
+
+
+class _SlowDecodeCodec:
+    """The default codec with every decode held ``delay`` seconds; records
+    the thread and the batch of each decode."""
+
+    def __init__(self, delay):
+        from repro.coding.codec import get_codec
+
+        self.inner, self.delay = get_codec(), delay
+        self.threads: list[str] = []
+        self.batches: list[int] = []
+        self.started = threading.Event()
+
+    def decode(self, rows, *args, **kwargs):
+        self.threads.append(threading.current_thread().name)
+        self.batches.append(len(rows))
+        self.started.set()
+        time.sleep(self.delay)
+        return self.inner.decode(rows, *args, **kwargs)
+
+    def __getattr__(self, name):
+        return getattr(self.inner, name)
+
+
+def _stored(store, rng, count, prefix="o"):
+    payloads = _payloads(rng, count, LAYOUT.file_bytes)
+    for i, p in enumerate(payloads):
+        store_coded_object(store, f"{prefix}/{i}", LAYOUT, p)
+    return payloads
+
+
+def test_slow_decode_does_not_hold_up_injection():
+    """A read submitted while an earlier one is being decoded has its task
+    started before that decode ends, and no decode runs on the admit loop."""
+    rng = np.random.default_rng(31)
+    store = MemoryStore()
+    payloads = _stored(store, rng, 2)
+    codec = _SlowDecodeCodec(0.5)
+    proxy = Proxy(store, StaticPolicy(1, 1), L=1, codec=codec)
+    try:
+        handles = [proxy.read_async("o/0", LAYOUT, LAYOUT.file_bytes)]
+        assert codec.started.wait(5.0)
+        handles.append(proxy.read_async("o/1", LAYOUT, LAYOUT.file_bytes))
+        first, second = [proxy.wait(h, 10.0) for h in handles]
+    finally:
+        proxy.close()
+    assert [first.data, second.data] == payloads
+    assert second.t_first_start < first.t_done
+    assert first.t_done - first.t_kth >= 0.5
+    assert codec.threads and set(codec.threads) == {"proxy-decoder"}
+
+
+class _KeyGatedStore(_GatedStore):
+    """A :class:`_GatedStore` whose ranged reads of each key also wait for
+    that key's release, and mark that they entered the store."""
+
+    def __init__(self, keys):
+        super().__init__()
+        self.gate.set()
+        self.entered = {k: threading.Event() for k in keys}
+        self.released = {k: threading.Event() for k in keys}
+
+    def get_range(self, key, offset, length):
+        self.entered[key].set()
+        self.released[key].wait()
+        return super().get_range(key, offset, length)
+
+
+def test_backlog_is_the_papers_q():
+    """With every connection held, the q each submission hands the policy
+    counts the head-of-line request the admit loop waits to inject, and a
+    read's completion does not add to it."""
+    rng = np.random.default_rng(32)
+    keys = [f"o/{i}" for i in range(6)]
+    store = _KeyGatedStore(keys)
+    payloads = _stored(store, rng, len(keys))
+    proxy = Proxy(store, StaticPolicy(1, 1), L=2)
+    handles = []
+
+    def submit(i):
+        handles.append(proxy.read_async(keys[i], LAYOUT, LAYOUT.file_bytes))
+
+    try:
+        for i in range(2):  # both connections held, one read each
+            submit(i)
+            assert store.entered[keys[i]].wait(5.0)
+        for i in range(2, 5):  # o/2 is the head of the line, then o/3, o/4
+            submit(i)
+        store.released[keys[0]].set()
+        assert proxy.wait(handles[0], 5.0).ok
+        assert store.entered[keys[2]].wait(5.0)  # o/2 took the freed connection
+        submit(5)  # behind o/3 (head of the line) and o/4
+        for k in keys:
+            store.released[k].set()
+        results = [proxy.wait(h, 10.0) for h in handles]
+    finally:
+        for k in keys:
+            store.released[k].set()
+        proxy.close()
+    assert [r.data for r in results] == payloads
+    assert [r.q for r in results] == [0, 0, 0, 1, 2, 2]
+
+
+def test_close_answers_reads_handed_to_the_decoder():
+    rng = np.random.default_rng(33)
+    store = MemoryStore()
+    payloads = _stored(store, rng, 3)
+    codec = _SlowDecodeCodec(0.3)
+    proxy = Proxy(store, StaticPolicy(1, 1), L=4, codec=codec)
+    handles = [proxy.read_async(f"o/{i}", LAYOUT, LAYOUT.file_bytes) for i in range(3)]
+    deadline = time.monotonic() + 5.0
+    while any(h.t_kth is None for h in handles) and time.monotonic() < deadline:
+        time.sleep(1e-3)
+    assert all(h.t_kth is not None for h in handles)  # all handed off
+    assert codec.started.wait(5.0)
+    proxy.close()  # while the decoder is still busy
+    results = [proxy.wait(h, 10.0) for h in handles]
+    assert [r.data for r in results] == payloads
+    proxy._decoder.join(5.0)
+    assert not proxy._decoder.is_alive()
+
+
+def test_decoder_splits_a_backlog_into_power_of_two_batches():
+    """Reads that pile up behind a slow decode go through in arrival order,
+    in codec calls of the largest power of two queued, at most
+    DECODE_BATCH_MAX: no batch is padded, and none grows past the cap."""
+    from repro.storage.proxy import DECODE_BATCH_MAX
+
+    rng = np.random.default_rng(34)
+    store = MemoryStore()
+    assert DECODE_BATCH_MAX == 4
+    count = 8  # one alone, then the 7 behind it as 4 + 2 + 1
+    payloads = _stored(store, rng, count)
+    codec = _SlowDecodeCodec(0.3)
+    proxy = Proxy(store, StaticPolicy(1, 1), L=count, codec=codec)
+    try:
+        handles = [proxy.read_async("o/0", LAYOUT, LAYOUT.file_bytes)]
+        assert codec.started.wait(5.0)
+        handles += [proxy.read_async(f"o/{i}", LAYOUT, LAYOUT.file_bytes)
+                    for i in range(1, count)]
+        results = [proxy.wait(h, 10.0) for h in handles]
+    finally:
+        proxy.close()
+    assert [r.data for r in results] == payloads
+    assert codec.batches == [1, 4, 2, 1]
+    assert [r.t_done for r in results] == sorted(r.t_done for r in results)

@@ -36,11 +36,11 @@ def _record() -> tuple[RunRecord, Telemetry]:
     tel.results.append((3, _result(50.0, 51.0, q=20)))  # another proxy
     tel.results.extend((7, res) for res in results)
     tel.decodes.extend([
-        (7, 99.0, 99.5, 4),  # before the window
-        (7, 100.88, 100.89, 1),  # 10 ms
-        (3, 101.0, 101.2, 2),  # another proxy
-        (7, 101.37, 101.40, 2),  # 30 ms
-        (7, 101.6, 101.7, 1),  # after the window's last answer
+        (7, 99.0, 99.5),  # before the window
+        (7, 100.88, 100.89),  # 10 ms
+        (3, 101.0, 101.2),  # another proxy
+        (7, 101.37, 101.40),  # 30 ms
+        (7, 101.6, 101.7),  # after the window's last answer
     ])
     run = RunRecord(t0=100.0, t_end=103.0)
     run.requests = [Request(res.op, due=res.t_arrival - 0.001, send=res.t_arrival,
@@ -103,9 +103,9 @@ def test_readers_report_on_a_tiny_read_run():
     assert len(found) == sum(1 for r in rec.requests if r.ok) > 0
     # every answered read carries the backlog its policy was given ...
     assert all(isinstance(res.q, int) and res.q >= 0 for _, res in found)
-    # ... and every batched decode of the run's proxy lies inside the run
+    # ... and every read's decode by the run's proxy lies inside the run
     serial = found[0][0]
     mine = [d for d in TELEMETRY.decodes if d[0] == serial]
     t_last = max(res.t_done for _, res in found)
-    assert mine and all(rec.t0 <= start <= end <= t_last for _, start, end, _ in mine)
-    assert sum(batch for *_, batch in mine) == len(found)
+    assert mine and all(rec.t0 <= start <= end <= t_last for _, start, end in mine)
+    assert len(mine) == len(found)

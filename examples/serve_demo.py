@@ -5,7 +5,7 @@ fetches them with adaptive (n, k) ranged reads under an S3-like latency
 model, tolerating injected read failures; the LM then prefills + decodes.
 
 The fetch runs twice: once on the unfused path (the proxy's decoder thread
-batch-decodes completions on the host codec) and once through the
+decodes completions on the host codec) and once through the
 fused serving step — one jitted launch running the TOFEC admission update
 AND the batched MDS decode for the whole round. The fused step runs the
 default codec when it is jitted (``REPRO_CODEC_BACKEND=jnp`` or ``pallas``,

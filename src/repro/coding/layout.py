@@ -217,8 +217,7 @@ class SharedKeyLayout:
         which chunk level k served them, so a whole batch of reads — with
         heterogeneous chunk levels *and* heterogeneous erasure patterns —
         collapses into a single ``codec.decode`` call with per-item
-        ``present`` masks (the proxy's read-side amortization, the mirror of
-        :meth:`encode_files` on the write side).
+        ``present`` masks (the read-side mirror of :meth:`encode_files`).
         """
         if not items:
             return []

@@ -9,7 +9,7 @@ LM path is the substrate it feeds.
 Three fetch paths:
 
 * **unfused** — :meth:`ServingEngine.fetch_prompts` submits the whole round
-  through :meth:`Proxy.read_many`; the proxy's decoder thread batch-decodes
+  through :meth:`Proxy.read_many`; the proxy's decoder thread decodes the
   completions on the host codec.
 * **fused** — pass a :class:`FusedServingStep`: the proxy returns raw chunks
   (``raw=True``) and ONE jitted launch then runs the admission update *and*
@@ -429,8 +429,8 @@ class ServingEngine:
         *, fused: FusedServingStep | None = None, retries: int = 3,
     ) -> tuple[np.ndarray, list[float], list[tuple[int, int]], tuple[int, int] | None]:
         """Batched prompt fetch: the whole round is submitted up front (the
-        proxy's policy sees it as backlog) and reconstructed batched — by the
-        proxy's decoder thread (unfused) or by ``fused``'s single jitted
+        proxy's policy sees it as backlog) and reconstructed — by the proxy's
+        decoder thread (unfused) or, batched, by ``fused``'s single jitted
         admission+decode launch (raw chunks in, payloads out).
 
         Reads that exhaust their n − k failure budget (the backlog-adapted

@@ -23,18 +23,16 @@ fed by the fused serving controller), closing the §III control loop: each
 admission round encodes queued writes under the currently-adapted (n, k) via
 :meth:`SharedKeyLayout.encode_files`'s chunk-level code.
 
-Coding on BOTH directions of the hot path goes through the unified batched
-codec engine, amortized per admission round (the coding-overhead Ψ cap of
-FAST CLOUD §IV):
+Coding on both directions of the hot path goes through the unified codec
+engine:
 
-* writes — each round drains every queued write and encodes all same-layout
-  payloads with ONE batched :meth:`SharedKeyLayout.encode_files` call;
-* reads — completed reads accumulate (workers only collect chunks and hand
-  the finished request to the proxy's decoder thread) and the decoder
-  reconstructs them in batched :meth:`SharedKeyLayout.reconstruct_batch`
-  calls of at most :data:`DECODE_BATCH_MAX` reads, per-item ``present``
-  masks carrying each request's own erasure pattern and chunk level through
-  a single ``codec.decode``.
+* writes — each admission round drains every queued write and encodes all
+  same-layout payloads with ONE batched :meth:`SharedKeyLayout.encode_files`
+  call (the coding-overhead Ψ cap of FAST CLOUD §IV);
+* reads — workers only collect chunks and hand each finished request to
+  the proxy's decoder thread, which reconstructs the reads one at a time in
+  arrival order. On a TPU v5e host a 3 MiB read costs about 9 ms of host
+  time to decode whatever the batch, so batching them gains nothing there.
 
 The admission *rule* (inject the next request's tasks only when the task
 queue is drained and a thread idles) is the paper's, and the admit loop
@@ -51,7 +49,7 @@ tasks injected → first task starts → k-th chunk → answer) and its task
 counts on :class:`RequestResult`; :attr:`Proxy.thread_busy_s` sums the
 threads' time inside store calls. :data:`TELEMETRY` keeps both for every
 proxy of the process (results without payloads), for readers that come
-after the fact, with each batched decode's times. Under a JAX profiler
+after the fact, with each read decode's times. Under a JAX profiler
 session the admit loop, the workers and the decoder also write spans
 (``proxy.admit_round``, ``proxy.admit_wait``, ``proxy.task``,
 ``proxy.reconstruct``) into the trace, tagged with the request's ``rid``.
@@ -79,13 +77,6 @@ from repro.storage.backend import ObjectStore, StorageError
 _log = logging.getLogger(__name__)
 
 _serials = itertools.count()
-#: most reads per batched decode. On a TPU v5e host a 3 MiB read takes about
-#: 9 ms of host time to decode (gather, pad, copies to and from the device)
-#: whatever the batch, padding a batch to its power-of-two bucket costs as
-#: much as a real read, and a batch holds each of its reads until all of it
-#: is back: small power-of-two batches keep the decoder ahead of arrivals.
-DECODE_BATCH_MAX = 4
-
 
 @dataclasses.dataclass
 class RequestResult:
@@ -143,8 +134,8 @@ class Telemetry:
         #: (proxy serial, host time, Proxy.thread_busy_s, L): one when the
         #: proxy starts, then one per task a worker finished
         self.busy: deque = deque(maxlen=8 * maxlen)
-        #: (proxy serial, host start, host end, reads): one per batched
-        #: reconstruct of the decoder thread
+        #: (proxy serial, host start, host end): one per read the decoder
+        #: thread reconstructed
         self.decodes: deque = deque(maxlen=maxlen)
 
 
@@ -200,8 +191,8 @@ class Proxy:
         self._write_reqs: list[_Request] = []
         self._task_q: _queue.Queue = _queue.Queue()
         self._request_q: _queue.Queue = _queue.Queue()
-        # Completed (non-raw) reads awaiting a batched reconstruct; fed by
-        # the workers, drained by the decoder thread. The last worker to
+        # Completed (non-raw) reads awaiting their reconstruct; fed by the
+        # workers, drained by the decoder thread. The last worker to
         # exit closes it with None.
         self._decode_q: _queue.Queue = _queue.Queue()
         self._idle = L
@@ -260,8 +251,7 @@ class Proxy:
         """Batched fetch: submit every key up front, then collect.
 
         Submitting the whole round before waiting lets the policy see the
-        true backlog (TOFEC's q signal) and lets the decoder reconstruct
-        the completions in batched decode calls instead of one per request.
+        true backlog (TOFEC's q signal).
         """
         with obs.span("proxy.read_many", keys=len(keys), raw=raw):
             reqs = [self.read_async(k, layout, payload_len, cls_id, raw=raw)
@@ -371,64 +361,24 @@ class Proxy:
                 self._inject(req)
 
     def _decode_loop(self):
-        """The decoder thread: reads are decoded in arrival order, each codec
-        call taking the largest power of two of the queued reads up to
-        :data:`DECODE_BATCH_MAX`, until the last worker has exited (``None``,
-        the last item ever queued)."""
-        pending: deque[_Request] = deque()
-        closing = False
-        while pending or not closing:
-            if not pending:
-                item = self._decode_q.get()
-                if item is None:
-                    return
-                pending.append(item)
-            while not closing:
-                try:
-                    item = self._decode_q.get_nowait()
-                except _queue.Empty:
-                    break
-                if item is None:
-                    closing = True
-                else:
-                    pending.append(item)
-            batch = 1 << (min(len(pending), DECODE_BATCH_MAX).bit_length() - 1)
-            self._reconstruct([pending.popleft() for _ in range(batch)])
+        """The decoder thread: completed reads are decoded in arrival order
+        until the last worker has exited (``None``, the last item ever
+        queued)."""
+        for req in iter(self._decode_q.get, None):
+            self._reconstruct(req)
 
-    def _reconstruct(self, reqs: list[_Request]) -> None:
-        """One batched reconstruct per layout group of completed reads.
-
-        This is the read-side twin of :meth:`_encode_pending_writes`: any mix
-        of chunk levels and erasure patterns decodes in a single
-        ``codec.decode`` per layout via per-item ``present`` masks.
-        """
-        groups: dict[SharedKeyLayout, list[_Request]] = {}
-        for r in reqs:
-            groups.setdefault(r.layout, []).append(r)
-        for lay, group in groups.items():
-            t0 = time.monotonic()
-            try:
-                with obs.span("proxy.reconstruct", batch=len(group)):
-                    datas = lay.reconstruct_batch(
-                        [(r.k, r.completed, r.payload_len) for r in group],
-                        codec=self.codec)
-            except Exception as batch_err:
-                # Torn batch (e.g. one malformed chunk): fall back to
-                # per-request decode so one bad item can't wedge the rest.
-                _log.warning("batched reconstruct failed (%s); retrying "
-                             "per-request", batch_err)
-                datas = [self._reconstruct_one(lay, r) for r in group]
-            TELEMETRY.decodes.append((self.serial, t0, time.monotonic(), len(group)))
-            for r, data in zip(group, datas):
-                self._finish(r, data is not None, data=data)
-
-    def _reconstruct_one(self, lay: SharedKeyLayout, r: _Request) -> bytes | None:
+    def _reconstruct(self, req: _Request) -> None:
+        t0 = time.monotonic()
         try:
-            return lay.reconstruct(r.k, r.completed, r.payload_len, codec=self.codec)
+            with obs.span("proxy.reconstruct", rid=req.rid):
+                data = req.layout.reconstruct(req.k, req.completed, req.payload_len,
+                                              codec=self.codec)
         except Exception:
             _log.exception("reconstruct failed for read %r (k=%d, chunks=%s)",
-                           r.key, r.k, sorted(r.completed))
-            return None
+                           req.key, req.k, sorted(req.completed))
+            data = None
+        TELEMETRY.decodes.append((self.serial, t0, time.monotonic()))
+        self._finish(req, data is not None, data=data)
 
     def _encode_pending_writes(self, pending: "deque[_Request]") -> None:
         """One batched encode per (layout, n, k) group of queued writes.
@@ -469,6 +419,9 @@ class Proxy:
                 off, ln = req.layout.chunk_range(req.k, ci)
                 tasks.append((req, ci, req.coded[off : off + ln]))
         req.n_issued = len(tasks)
+        # The request leaves q before a worker can start any of its tasks.
+        with self._state_lock:
+            self._backlog -= 1
         if req.n_issued < req.k:
             with req.lock:
                 req.cancelled = True
@@ -477,8 +430,6 @@ class Proxy:
             req.t_injected = time.monotonic()
             for task in tasks:
                 self._task_q.put(task)
-        with self._state_lock:
-            self._backlog -= 1
 
     def _worker(self):
         while True:
@@ -532,10 +483,7 @@ class Proxy:
                     req.t_kth = time.monotonic()
                     req.cancelled = True  # preemptive cancellation of the rest
                     if not req.raw:
-                        # Hand off to the decoder thread: reads that complete
-                        # while it decodes reconstruct together in its next
-                        # batched decode.
-                        self._decode_q.put(req)
+                        self._decode_q.put(req)  # the decoder thread answers it
                     else:
                         self._finish(req, True)
                 elif req.failures > req.n_issued - req.k:

@@ -1,6 +1,5 @@
-"""Proxy batched read path: the decoder thread's batched decode, raw reads,
-and the paper's heavy-load adaptation (backlog pressure → fewer/larger
-chunks)."""
+"""Proxy read path: the decoder thread, raw reads, and the paper's
+heavy-load adaptation (backlog pressure → fewer/larger chunks)."""
 
 import threading
 import time
@@ -52,7 +51,7 @@ def _payloads(rng, count, nbytes):
 def test_read_many_batch_decodes_heterogeneous_erasures():
     """One round of concurrent reads with random per-item failures: every
     item reconstructs despite each surviving a different erasure pattern
-    (the decoder's batched decode path)."""
+    (the decoder thread's path)."""
     rng = np.random.default_rng(0)
     inner = MemoryStore()
     payloads = _payloads(rng, 8, LAYOUT.file_bytes - 11)
@@ -489,18 +488,15 @@ def test_close_answers_reads_handed_to_the_decoder():
     assert not proxy._decoder.is_alive()
 
 
-def test_decoder_splits_a_backlog_into_power_of_two_batches():
-    """Reads that pile up behind a slow decode go through in arrival order,
-    in codec calls of the largest power of two queued, at most
-    DECODE_BATCH_MAX: no batch is padded, and none grows past the cap."""
-    from repro.storage.proxy import DECODE_BATCH_MAX
-
+def test_decoder_answers_a_backlog_in_arrival_order():
+    """Reads that pile up behind a slow decode are each decoded in a codec
+    call of their own, on the decoder thread, and answered in arrival
+    order."""
     rng = np.random.default_rng(34)
     store = MemoryStore()
-    assert DECODE_BATCH_MAX == 4
-    count = 8  # one alone, then the 7 behind it as 4 + 2 + 1
+    count = 4
     payloads = _stored(store, rng, count)
-    codec = _SlowDecodeCodec(0.3)
+    codec = _SlowDecodeCodec(0.2)
     proxy = Proxy(store, StaticPolicy(1, 1), L=count, codec=codec)
     try:
         handles = [proxy.read_async("o/0", LAYOUT, LAYOUT.file_bytes)]
@@ -511,5 +507,6 @@ def test_decoder_splits_a_backlog_into_power_of_two_batches():
     finally:
         proxy.close()
     assert [r.data for r in results] == payloads
-    assert codec.batches == [1, 4, 2, 1]
+    assert codec.batches == [1] * count
+    assert set(codec.threads) == {"proxy-decoder"}
     assert [r.t_done for r in results] == sorted(r.t_done for r in results)

@@ -27,10 +27,15 @@ import math
 import numpy as np
 
 
+#: bytes of one element in each ``torch_dtype`` the model may be served in
+DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
+
+
 def sizes(cfg: dict) -> dict:
     """The model's sizes from a Hugging Face ``config.json``."""
     d, H = cfg["hidden_size"], cfg["num_attention_heads"]
     return {
+        "dtype_bytes": DTYPE_BYTES[cfg["torch_dtype"]],
         "d_model": d,
         "n_heads": H,
         "n_kv_heads": cfg["num_key_value_heads"],

@@ -11,7 +11,9 @@ Each round sends one request per client, all at once (``serve_round``):
 the prompts are fetched through the TOFEC proxy as raw chunks, decoded and
 prefilled in one launch, then decoded greedily token by token. Rounds follow
 each other until the window closes. Every generated token's host readback
-is timed where the program asks for the next decode step.
+is timed where the program asks for the next decode step. A traced run
+profiles the window's last ``trace_seconds``, through the end of the round
+that is under way at the close.
 
 The check, after the window: every served request's decoded prompt equals
 the stored tokens, and for a sample of requests drawn from the seed, every
@@ -92,15 +94,9 @@ def run(ctx) -> RunRecord:
     decode = engine._decode
     trace_s = float(tr["trace_seconds"])
 
-    def stop_trace_when_due():
-        prof = rec.profiler
-        if prof is not None and prof.t1 is None and time.monotonic() - prof.t0 >= trace_s:
-            prof.stop()
-
     def timed_decode(p, tok, cache):
         # called right after the previous token's readback on the host
         readbacks.append(time.monotonic())
-        stop_trace_when_due()
         with Span("serve.decode_call"):
             return decode(p, tok, cache)
 
@@ -128,10 +124,13 @@ def run(ctx) -> RunRecord:
         rec.setup_s = rec.t0 - ctx.t_proc0
         rec.t_end = rec.t0 + ctx.seconds
         while time.monotonic() < rec.t_end:
-            if ctx.trace and rec.profiler is None and rec.rounds:
+            if ctx.trace and rec.profiler is None and time.monotonic() >= rec.t_end - trace_s:
                 from benchlib.harness import Profiler
 
-                rec.profiler = Profiler()  # from the second round on, for trace_seconds
+                # The window's last trace_seconds, from a round's start to the
+                # last round's end: stopping the profiler and reading its trace
+                # take seconds, which must not stall a round inside the window.
+                rec.profiler = Profiler()
                 rec.profiler.start()
             keys = rng.choice(n_prompts, clients, replace=False)
             t_launch = time.monotonic()
@@ -140,8 +139,7 @@ def run(ctx) -> RunRecord:
             rec.launches.append((t_launch, rd.served, plen))
             idx = {f"prompt/{k}": int(k) for k in keys}
             served.append(([idx[k] for k in res.served_keys], res.tokens, res.prompts))
-            stop_trace_when_due()
-        if rec.profiler is not None and rec.profiler.t1 is None:
+        if rec.profiler is not None:
             rec.profiler.stop()
         rec.compiles_in_window = (ctx.compiles.count if ctx.compiles else 0) - compiles0
         rec.memory_peak_bytes = memory_peak_bytes(ctx.devices or jax.devices()[:1])

@@ -7,7 +7,8 @@ metric out of the result line.
 from __future__ import annotations
 
 from benchlib import stats
-from benchlib.work import codec_call_least_s, dense_prefill_flops
+from benchlib.work import (codec_call_least_s, dense_decode_step_work, dense_prefill_flops,
+                           least_s)
 
 #: the fused admission -> MDS decode -> prefill launch (``ClosedLoopServer``)
 FUSED_MODULE = "jit_core"
@@ -66,12 +67,21 @@ def codec_roofline_pct(run):
     return 100.0 * least / kernel_s
 
 
-def ttft_ms(run) -> list[float]:
-    out = []
-    for rd in run.rounds:
-        first = (rd.readbacks[0] - rd.send) * 1e3 if rd.readbacks else stats.MISSING
-        out += [first] * rd.served + [stats.MISSING] * (rd.requested - rd.served)
-    return out
+def ttft_round_ms(run) -> list[float]:
+    """Per round, its send to its first token's readback on the host. The
+    requests of a round share that readback, so a round counts once; a round
+    that left a request unserved is slower than every round."""
+    return [(rd.readbacks[0] - rd.send) * 1e3
+            if rd.readbacks and rd.served == rd.requested else stats.MISSING
+            for rd in run.rounds]
+
+
+def inter_token_ms(run):
+    """Mean gap between consecutive token readbacks of a round, over every
+    gap that closes inside the window."""
+    gaps = [(b - a) * 1e3 for rd in run.rounds
+            for a, b in zip(rd.readbacks, rd.readbacks[1:]) if b < run.t_end]
+    return stats.mean(gaps) if gaps else None
 
 
 def output_tokens_per_s(run):
@@ -106,3 +116,48 @@ def fused_step_mfu_pct(run):
     flops = sum(dense_prefill_flops(run.model, served, seq) for served, seq in traced)
     flops *= n / len(traced)  # per launch, as many as the trace holds
     return 100.0 * flops / (tr.module_s[FUSED_MODULE] * run.peaks.flops)
+
+
+def _rounds_with_prompt_len(run):
+    """(round, prompt length) of each round; the driver appends one launch
+    (time, served, prompt length) per round."""
+    if len(run.launches) != len(run.rounds):
+        raise ValueError("a serving run records one launch per round")
+    return [(rd, seq) for rd, (_, _, seq) in zip(run.rounds, run.launches)]
+
+
+def decode_step_roofline_pct(run):
+    """Least time of the traced cached decode steps over their device time.
+
+    Decode call j of a round follows the round's j-th readback and attends
+    prompt length + j + 1 positions in each served sequence. The mean least
+    time of the calls made inside the traced window, times the number of
+    decode modules the trace holds, over those modules' summed time."""
+    tr = run.trace
+    if tr is None or run.peaks is None or run.model is None:
+        return None
+    n = tr.module_n.get(DECODE_MODULE, 0)
+    least = [least_s(*dense_decode_step_work(run.model, [seq + j + 1] * rd.served),
+                     run.peaks.flops, run.peaks.hbm_bytes_per_s)[0]
+             for rd, seq in _rounds_with_prompt_len(run)
+             for j, t in enumerate(rd.readbacks) if run.in_trace(t)]
+    if not n or not least:
+        return None
+    return 100.0 * stats.mean(least) * n / tr.module_s[DECODE_MODULE]
+
+
+def serve_mfu_pct(run):
+    """Model FLOPs of the work served inside the window over (the window x
+    the chip's peak): a round's prefill of its served prompts once its first
+    token is read back, and the decode step behind each later token read
+    back (token j attends prompt length + j positions)."""
+    if run.peaks is None or run.model is None or not run.rounds:
+        return None
+    flops = 0.0
+    for rd, seq in _rounds_with_prompt_len(run):
+        for j, t in enumerate(rd.readbacks):
+            if t >= run.t_end:
+                break
+            flops += (dense_prefill_flops(run.model, rd.served, seq) if j == 0 else
+                      dense_decode_step_work(run.model, [seq + j] * rd.served)[0])
+    return 100.0 * flops / ((run.t_end - run.t0) * run.peaks.flops)

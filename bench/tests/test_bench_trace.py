@@ -75,5 +75,6 @@ def test_recorded_trace(tmp_path):
 def test_readers_leave_out_what_they_cannot_read():
     rec = RunRecord()
     for name in ("codec_roofline.read", "device_idle.read", "fused_step_mfu",
-                 "decode_step_ms", "ttft_p95_ms", "output_tokens_per_s", "read_p99_ms"):
+                 "decode_step_ms", "ttft_p50_ms", "output_tokens_per_s", "read_p99_ms",
+                 "decode_step_roofline", "inter_token_ms.serve", "mfu.serve"):
         assert bench_tiny.manifest.metric_reader(name)(rec) is None, name

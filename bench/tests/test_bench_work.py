@@ -39,12 +39,48 @@ def test_dense_prefill_flops_by_hand():
 def test_qwen_prefill_flops_scale():
     cfg = {"hidden_size": 1024, "num_attention_heads": 16, "num_key_value_heads": 16,
            "intermediate_size": 2816, "num_hidden_layers": 24, "vocab_size": 151936,
-           "rope_theta": 1e6, "rms_norm_eps": 1e-6, "initializer_range": 0.02}
+           "rope_theta": 1e6, "rms_norm_eps": 1e-6, "initializer_range": 0.02,
+           "torch_dtype": "bfloat16"}
     s = dense_lm.sizes(cfg)
     # 16 prompts of 1024 tokens: 16384 tokens x 617 MFLOP of weights, plus
     # attention and 16 last-position heads
     f = work.dense_prefill_flops(s, 16, 1024)
     assert 1.08e13 < f < 1.10e13
+
+
+def test_dense_decode_step_work_by_hand():
+    cfg = {"d_model": 4, "n_heads": 2, "n_kv_heads": 1, "head_dim": 2, "d_ff": 8,
+           "n_layers": 3, "vocab": 10, "dtype_bytes": 2}
+    # matmul weights per layer: q 4*4, k and v 4*2 each, o 4*4, MLP 3*4*8;
+    # the tied head 10*4, counted once
+    layer_mm, head = 16 + 8 + 8 + 16 + 96, 40
+    # two sequences attending 5 and 7 positions: 12 positions in all
+    ops = 2 * (3 * layer_mm + head) * 2 + 3 * (2 * 2 * 2 * 2) * 12
+    # weights with the q, k, v biases (4 + 2 + 2) and two norms of 4 per
+    # layer, and the final norm; keys and values (1 head of 2) of the 12
+    # attended positions read and of the 2 new ones written
+    weights = 3 * (layer_mm + 8 + 8) + head + 4
+    kv = 3 * 2 * 2 * (12 + 2)
+    assert work.dense_decode_step_work(cfg, [5, 7]) == (ops, (weights + kv) * 2)
+
+
+def test_least_time_names_its_bound():
+    assert work.least_s(10.0, 1.0, 10.0, 10.0) == (1.0, "flops")
+    assert work.least_s(1.0, 10.0, 10.0, 10.0) == (1.0, "bytes")
+
+
+def test_qwen_decode_step_is_bound_by_bytes():
+    cfg = {"hidden_size": 1024, "num_attention_heads": 16, "num_key_value_heads": 16,
+           "intermediate_size": 2816, "num_hidden_layers": 24, "vocab_size": 151936,
+           "rope_theta": 1e6, "rms_norm_eps": 1e-6, "initializer_range": 0.02,
+           "torch_dtype": "bfloat16"}
+    s = dense_lm.sizes(cfg)
+    # 16 sequences, each attending its 1024-token prompt and the new token:
+    # 0.93 GB of weights and 1.61 GB of keys and values
+    ops, byts = work.dense_decode_step_work(s, [1025] * 16)
+    assert 2.50e9 < byts < 2.58e9 and 1.6e10 < ops < 1.7e10
+    t, bound = work.least_s(ops, byts, 197e12, 819e9)
+    assert bound == "bytes" and t == pytest.approx(byts / 819e9)
 
 
 def test_reference_code_matches_the_stored_format():

@@ -9,6 +9,7 @@ from repro.configs import (
     grok_1_314b,
     mistral_nemo_12b,
     mixtral_8x7b,
+    moonlight_16b_a3b,
     pixtral_12b,
     qwen1_5_0_5b,
     whisper_base,
@@ -30,6 +31,7 @@ ALL_CONFIGS = {
         grok_1_314b,
         mixtral_8x7b,
         zamba2_2_7b,
+        moonlight_16b_a3b,
     ]
 }
 
@@ -46,5 +48,6 @@ SMOKE_CONFIGS = {
         grok_1_314b,
         mixtral_8x7b,
         zamba2_2_7b,
+        moonlight_16b_a3b,
     ]
 }

@@ -34,10 +34,25 @@ class ModelConfig:
     local_window: int = 4096
     attn_softcap: float | None = None
     logit_softcap: float | None = None
+    # multi-head latent attention (DeepSeek-V2 §2.1); on when kv_lora_rank > 0.
+    # No query compression: q comes from one d_model x heads projection.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0  # routed experts the router scores (its published width)
     top_k: int = 2
     capacity_factor: float = 1.25
+    router_score: str = "softmax"  # softmax | sigmoid (+ correction bias for the choice)
+    routed_scale: float = 1.0  # factor on the chosen weights, renormalised to sum to 1
+    n_shared_experts: int = 0  # always-on experts, one MLP of n x d_ff
+    first_dense_layers: int = 0  # leading layers with a dense MLP of d_ff_dense
+    d_ff_dense: int = 0
+    # the experts this chip holds: [expert_first, expert_first + experts_held)
+    # of the n_experts routed ones (0 = all of them; expert parallelism)
+    expert_first: int = 0
+    experts_held: int = 0
     # SSM / recurrent
     ssm_state: int = 64
     ssm_conv: int = 4
@@ -51,6 +66,7 @@ class ModelConfig:
     vision_patches: int = 1024  # stub ViT output length
     # numerics
     dtype: str = "bfloat16"
+    norm_eps: float = 1e-6
     # training-time knobs (hillclimbing levers; see EXPERIMENTS.md §Perf)
     remat_policy: str = "nothing"  # nothing | dots | full
     seq_shard_activations: bool = True  # Megatron-SP style residual sharding
@@ -77,6 +93,19 @@ class ModelConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.first_dense_layers if self.n_experts else 0
+
+    @property
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
 
@@ -95,17 +124,29 @@ class ModelConfig:
     def has_decoder(self) -> bool:
         return True  # all assigned archs have an autoregressive decoder
 
-    def param_count_dense(self) -> int:
-        """Analytic parameter estimate (used for MODEL_FLOPS = 6·N·D)."""
+    def _attn_params(self) -> int:
         d, hd = self.d_model, self.hd
+        if self.is_mla:
+            H, c, r = self.n_heads, self.kv_lora_rank, self.qk_rope_head_dim
+            qk = self.qk_nope_head_dim + r
+            return (d * H * qk + d * (c + r) + c
+                    + c * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * d)
+        return d * (self.n_heads * hd) * 2 + d * (self.n_kv_heads * hd) * 2
+
+    def param_count_dense(self) -> int:
+        """Analytic parameter estimate (used for MODEL_FLOPS = 6·N·D).
+        MoE: every routed expert of the model, whichever a chip holds."""
+        d = self.d_model
+        unit = 3 if self.glu else 2
         emb = self.vocab * d * 2  # embed + untied head
-        attn = d * (self.n_heads * hd) * 2 + d * (self.n_kv_heads * hd) * 2
+        attn = self._attn_params()
         if self.n_experts:
-            ff_unit = self.n_experts * (3 if self.glu else 2) * d * self.d_ff
+            ff_unit = self.n_experts * unit * d * self.d_ff
             router = d * self.n_experts
-            mlp = ff_unit + router
+            mlp = ff_unit + router + unit * d * self.n_shared_experts * self.d_ff
         elif self.d_ff:
-            mlp = (3 if self.glu else 2) * d * self.d_ff
+            mlp = unit * d * self.d_ff
         else:
             mlp = 0
         if self.family == "ssm":
@@ -125,7 +166,9 @@ class ModelConfig:
                 + di * d
             )
             return int(emb + self.n_layers * mamba + per_layer)
-        total = emb + self.n_layers * per_layer
+        n_dense = self.first_dense_layers if self.n_experts else 0
+        total = (emb + (self.n_layers - n_dense) * per_layer
+                 + n_dense * (attn + unit * d * self.d_ff_dense))
         if self.encoder_layers:
             total += self.encoder_layers * per_layer
         return int(total)
@@ -135,10 +178,9 @@ class ModelConfig:
         if not self.n_experts:
             return self.param_count_dense()
         full = self.param_count_dense()
-        d = self.d_model
-        ff_unit = (3 if self.glu else 2) * d * self.d_ff
-        moe_total = self.n_layers * self.n_experts * ff_unit
-        moe_active = self.n_layers * self.top_k * ff_unit
+        ff_unit = (3 if self.glu else 2) * self.d_model * self.d_ff
+        moe_total = self.n_moe_layers * self.n_experts * ff_unit
+        moe_active = self.n_moe_layers * self.top_k * ff_unit
         return int(full - moe_total + moe_active)
 
 

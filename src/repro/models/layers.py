@@ -174,6 +174,7 @@ def chunked_attention(
     SWA linear in sequence length.
     """
     B, Sq, H, hd = q.shape
+    hd_v = v.shape[-1]  # latent attention: values narrower than queries and keys
     Sq_real = Sq
     Skv = k.shape[1]
     Hkv = k.shape[2]
@@ -233,7 +234,7 @@ def chunked_attention(
             )
             return (o_acc, m_new, l_acc), None
 
-        o0 = jnp.zeros((B, qc, Hkv, G, hd), jnp.float32)
+        o0 = jnp.zeros((B, qc, Hkv, G, hd_v), jnp.float32)
         m0 = jnp.full((B, Hkv, G, qc), -1e30, jnp.float32)
         l0 = jnp.zeros((B, Hkv, G, qc), jnp.float32)
         (o, m, l), _ = jax.lax.scan(
@@ -243,8 +244,8 @@ def chunked_attention(
         return carry, out.astype(v.dtype)
 
     _, outs = jax.lax.scan(per_q_chunk, None, jnp.arange(nq), unroll=cfg.scan_unroll)
-    # outs: (nq, B, qc, Hkv, G, hd) → (B, Sq, H, hd), trimmed of q padding
-    out = jnp.moveaxis(outs, 0, 1).reshape(B, Sq, Hkv, G, hd).reshape(B, Sq, H, hd)
+    # outs: (nq, B, qc, Hkv, G, hd_v) → (B, Sq, H, hd_v), trimmed of q padding
+    out = jnp.moveaxis(outs, 0, 1).reshape(B, Sq, Hkv, G, hd_v).reshape(B, Sq, H, hd_v)
     return out[:, :Sq_real]
 
 
@@ -328,22 +329,27 @@ def decode_attention(
 def fill_cache_from_prefill(
     k: jax.Array, v: jax.Array, Smax: int
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Arrange the last Smax of (B, S, Hkv, hd) prefill K/V into ring slots.
+    """Arrange the last Smax of (B, S, ...) prefill K/V (or, for latent
+    attention, the latent and the rotary key) into ring slots.
 
     Position p lives in slot p mod Smax. The kept positions are consecutive,
     so the ring is the zero-padded tail rotated by a static shift: a pad and
     a roll, no scatter (the TPU compiler aborts on this batched scatter).
     """
-    B, S, Hkv, hd = k.shape
+    S = k.shape[1]
     take = min(S, Smax)
     shift = (S - take) % Smax
-    pad = ((0, 0), (0, Smax - take), (0, 0), (0, 0))
-    ck = jnp.roll(jnp.pad(k[:, S - take :], pad), shift, axis=1)
-    cv = jnp.roll(jnp.pad(v[:, S - take :], pad), shift, axis=1)
+    ck = jnp.roll(jnp.pad(k[:, S - take :], _seq_pad(k, Smax - take)), shift, axis=1)
+    cv = jnp.roll(jnp.pad(v[:, S - take :], _seq_pad(v, Smax - take)), shift, axis=1)
     positions = np.arange(S - take, S)
     sp = np.full((Smax,), -(2**30), np.int32)
     sp[positions % Smax] = positions
     return ck, cv, jnp.asarray(sp)
+
+
+def _seq_pad(a: jax.Array, n: int):
+    """Pad widths that add n positions after axis 1 of a (B, S, ...) array."""
+    return ((0, 0), (0, n)) + ((0, 0),) * (a.ndim - 2)
 
 
 # ---------------------------------------------------------------------------

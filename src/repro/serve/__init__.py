@@ -1,4 +1,5 @@
 from repro.serve.engine import (
+    ROUTING,
     ClosedLoopResult,
     ClosedLoopServer,
     FusedServingStep,

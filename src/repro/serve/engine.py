@@ -39,6 +39,7 @@ import dataclasses
 import os
 import threading
 import time
+from collections import deque
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +72,32 @@ POL_MPC = 1
 #: one cached decode step: the prefix of their ops' names in the HLO metadata
 FUSED_SCOPE = "serve.fused_launch"
 DECODE_SCOPE = "serve.decode_step"
+
+
+class RoutingRecord:
+    """Where an MoE model's routed tokens went, per closed-loop round, kept
+    for readers after the fact (a benchmark's metric readers), as the
+    proxy's ``TELEMETRY`` keeps its requests. Bounded: the oldest rounds go
+    first. Dense models record nothing.
+
+    ``rounds`` holds (host time at the round's end, prefill (MoE layers,
+    held experts + 1), decode (steps, MoE layers, held experts + 1)) int32:
+    the (token, choice) pairs each held expert took, the last column those
+    routed to experts held elsewhere, for the fused launch's prefill and for
+    each decode step whose token was read back. They are the caches'
+    ``expert_tokens``, copied to the host after the round's last token.
+    """
+
+    def __init__(self, maxlen: int = 1 << 12):
+        self.rounds: deque = deque(maxlen=maxlen)
+
+
+ROUTING = RoutingRecord()
+
+
+def _expert_tokens(cache):
+    """The routing counters an MoE model's step left in its cache, or None."""
+    return cache.get("expert_tokens") if isinstance(cache, dict) else None
 
 
 @jax.tree_util.register_dataclass
@@ -775,13 +802,20 @@ class ClosedLoopServer:
         # Generation continues at the padded batch (same trace each round);
         # rows are sliced back to the served subset on host at the end.
         gen = []
+        routed = [_expert_tokens(cache)]
         with obs.span("serve.generate", steps=steps):
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             for _ in range(steps):
                 gen.append(np.asarray(tok)[:, 0])
                 logits, cache = self.engine._decode(self.engine.params, tok, cache)
+                routed.append(_expert_tokens(cache))
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         tokens = np.stack(gen, axis=1)[: len(good)]
+        if routed[0] is not None and steps:
+            # Every step but the last was read back: its counters are there.
+            pre, *dec = jax.device_get(routed[:-1])
+            ROUTING.rounds.append((time.monotonic(), pre, np.stack(dec) if dec else
+                                   np.zeros((0, *pre.shape), pre.dtype)))
         # Pull the controller's pick to host only now: generation already
         # forced the launch, so this sync is free (reading it before the
         # decode loop would stall the round on the fused launch).

@@ -11,6 +11,9 @@ shape or a reshape) at no chip time. Covered here:
   phase's small prompt objects;
 * the ``ClosedLoopServer`` fused admission → decode → prefill launch at
   qwen1.5-0.5b widths, compiled from shapes;
+* that launch and the cached decode step of moonlight-16b-a3b at its
+  benchmark cell's shapes (16 prompts of 1024 tokens, 16 of the 64 routed
+  experts held, all 27 layers), which must fit one chip's 16 GB;
 * the MoE expert contraction, which must keep bf16 operands with f32
   accumulation on the TPU whatever its CPU lowering does.
 
@@ -72,17 +75,14 @@ def test_codec_kernel_compiles_for_v5e(one_chip, batch, m, k, B):
     assert compiled.output_shardings.device_set == one_chip.device_set
 
 
-def test_closed_loop_launch_compiles_at_qwen_widths(one_chip):
+def _closed_loop_launch(arch, params, batch, prompt_len, steps, sharding):
+    """The fused launch of ``arch`` compiled for ``sharding``, and its engine."""
     from repro.coding.codec import get_codec
     from repro.coding.layout import SharedKeyLayout
     from repro.core import PAPER_READ_3MB, RequestClass, TOFECPolicy
-    from repro.models.registry import get
     from repro.serve import ClosedLoopServer, FusedServingStep, ServePolicy, ServingEngine
     from repro.storage import MemoryStore, Proxy
 
-    batch, prompt_len, steps = 8, 512, 16
-    arch = get("qwen1.5-0.5b")
-    params = jax.eval_shape(arch.init, jax.random.key(0))
     engine = ServingEngine(arch, params, max_seq=prompt_len + steps)
     layout = SharedKeyLayout(K=4, r=2, strip_bytes=prompt_len)
     cls = RequestClass("prompt", layout.file_bytes / 2**20, PAPER_READ_3MB,
@@ -99,15 +99,50 @@ def test_closed_loop_launch_compiles_at_qwen_widths(one_chip):
         fn = srv._fn(("pfd", *bkey, prompt_len, layout.strip_bytes, False))
         args = (step.tables, step.carry, codec.backend.prep_mats(mats_p), rows_p,
                 np.float32(batch), np.float32(-1.0), params)
-        compiled = fn.lower(*_on(one_chip, args)).compile()
+        return fn.lower(*_on(sharding, args)).compile(), engine
     finally:
         proxy.close()
-    assert "tpu_custom_call" in compiled.as_text()  # the codec kernel is fused in
+
+
+def _bytes_used(compiled) -> int:
     mem = compiled.memory_analysis()
-    used = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
-    assert used < 16e9, used  # one v5e chip holds 16 GB
+    return mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+
+
+def test_closed_loop_launch_compiles_at_qwen_widths(one_chip):
+    from repro.models.registry import get
+
+    batch, prompt_len, steps = 8, 512, 16
+    arch = get("qwen1.5-0.5b")
+    params = jax.eval_shape(arch.init, jax.random.key(0))
+    compiled, _ = _closed_loop_launch(arch, params, batch, prompt_len, steps, one_chip)
+    assert "tpu_custom_call" in compiled.as_text()  # the codec kernel is fused in
+    assert _bytes_used(compiled) < 16e9  # one v5e chip holds 16 GB
     logits = compiled.out_info[4]
     assert logits.shape == (batch, 1, arch.cfg.vocab)
+
+
+def test_closed_loop_moe_launch_and_decode_fit_one_v5e(one_chip):
+    """moonlight-16b-a3b at its cell's shapes: 5.16 B parameters (10.33 GB)
+    on the chip, the latent cache, the grouped expert dispatch's buffers."""
+    import dataclasses
+
+    from repro.models import lm
+    from repro.models.registry import Arch, get
+
+    batch, prompt_len, steps = 16, 1024, 16
+    cfg = dataclasses.replace(get("moonlight-16b-a3b").cfg, experts_held=16)
+    arch = Arch(cfg=cfg, module=lm)
+    params = jax.eval_shape(arch.init, jax.random.key(0))
+    launch, engine = _closed_loop_launch(arch, params, batch, prompt_len, steps, one_chip)
+    assert _bytes_used(launch) < 16e9  # one v5e chip holds 16 GB
+    cache = jax.eval_shape(lambda: arch.init_cache(batch, prompt_len + steps))
+    assert launch.out_info[5]["ckv"].shape == (27, batch, prompt_len + steps, 512)
+    assert launch.out_info[5]["expert_tokens"].shape == (26, 17)
+    token = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
+    decode = engine._decode.lower(*_on(one_chip, (params, token, cache))).compile()
+    assert _bytes_used(decode) < 16e9
+    assert decode.out_info[0].shape == (batch, 1, cfg.vocab)
 
 
 def test_moe_expert_contraction_stays_bf16_on_v5e(one_chip):

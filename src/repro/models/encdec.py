@@ -123,8 +123,8 @@ def train_loss(params, cfg: ModelConfig, batch) -> jax.Array:
 def init_cache(cfg: ModelConfig, B: int, max_seq: int):
     L, Hkv, hd, T = cfg.n_layers, cfg.n_kv_heads, cfg.hd, cfg.encoder_seq
     return {
-        "k": jnp.zeros((L, B, max_seq, Hkv, hd), ly.dt(cfg)),
-        "v": jnp.zeros((L, B, max_seq, Hkv, hd), ly.dt(cfg)),
+        "k": jnp.zeros((L, B, max_seq, Hkv * hd), ly.dt(cfg)),
+        "v": jnp.zeros((L, B, max_seq, Hkv * hd), ly.dt(cfg)),
         "slot_pos": jnp.full((L, max_seq), -(2**30), jnp.int32),
         "cross_k": jnp.zeros((L, B, T, Hkv, hd), ly.dt(cfg)),
         "cross_v": jnp.zeros((L, B, T, Hkv, hd), ly.dt(cfg)),
@@ -203,12 +203,7 @@ def decode_step(params, cfg: ModelConfig, token, cache):
 
 
 def cache_logical_axes(cfg: ModelConfig, B: int):
-    if B == 1:
-        kv = (None, None, "kv_seq", None, None)
-    elif cfg.decode_cache_seq_shard:
-        kv = (None, "batch", "kv_seq", None, None)
-    else:
-        kv = (None, "batch", None, "kv_heads", None)
+    kv = (None, *ly.decode_cache_axes(cfg, B))
     xkv = (None, "batch", None, "kv_heads", None)
     return {
         "k": kv, "v": kv, "slot_pos": (None, None),

@@ -135,8 +135,8 @@ def init_cache(cfg: ModelConfig, B: int, max_seq: int):
     Hkv, hd = cfg.n_kv_heads, cfg.hd
     return {
         "mamba": _stacked_mamba_state(cfg, B),
-        "k": jnp.zeros((n_sites, B, Smax, Hkv, hd), ly.dt(cfg)),
-        "v": jnp.zeros((n_sites, B, Smax, Hkv, hd), ly.dt(cfg)),
+        "k": jnp.zeros((n_sites, B, Smax, Hkv * hd), ly.dt(cfg)),
+        "v": jnp.zeros((n_sites, B, Smax, Hkv * hd), ly.dt(cfg)),
         "slot_pos": jnp.full((n_sites, Smax), -(2**30), jnp.int32),
         "pos": jnp.int32(0),
     }
@@ -229,12 +229,7 @@ def decode_step(params, cfg: ModelConfig, token, cache):
 
 
 def cache_logical_axes(cfg: ModelConfig, B: int):
-    if B == 1:
-        kv = (None, None, "kv_seq", None, None)
-    elif cfg.decode_cache_seq_shard:
-        kv = (None, "batch", "kv_seq", None, None)
-    else:
-        kv = (None, "batch", None, "kv_heads", None)
+    kv = (None, *ly.decode_cache_axes(cfg, B))
     return {
         "mamba": (
             (None, "batch", None, "ff"),          # conv buffer (L, B, K-1, dconv)

@@ -17,6 +17,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.models.config import ModelConfig
 from repro.models.sharding import constrain
@@ -273,56 +274,98 @@ def attention(
     return out @ use_weight(cfg, params["wo"], "heads", None)
 
 
-def decode_attention(
+def decode_cache_axes(cfg: ModelConfig, B: int) -> tuple[str | None, ...]:
+    """Logical axes of one layer's (B, Smax, Hkv·hd) decode cache. B == 1
+    (long context) shards the sequence, not heads."""
+    if B == 1:
+        return (None, "kv_seq", None)
+    if cfg.decode_cache_seq_shard:
+        # §Perf: batch × sequence sharding = full 256-way cache split
+        # (kv_heads rarely divide the model axis; the sequence always does).
+        return ("batch", "kv_seq", None)
+    return ("batch", None, "kv_heads")
+
+
+def decode_qkv(params, cfg: ModelConfig, x: jax.Array, pos: jax.Array):
+    """The new position's projections: q (B, 1, H, hd) and the cache rows
+    k, v (B, 1, Hkv·hd) to write at slot ``pos % Smax``."""
+    B = x.shape[0]
+    q, k, v = _project_qkv(params, cfg, x, jnp.full((B, 1), pos, jnp.int32))
+    return q, k.reshape(B, 1, -1), v.reshape(B, 1, -1)
+
+
+def decode_attend(
     params,
     cfg: ModelConfig,
-    x: jax.Array,  # (B, 1, d)
-    cache_k: jax.Array,  # (B, Smax, Hkv, hd) — ring buffer when Smax < ctx
+    q: jax.Array,  # (B, 1, H, hd)
+    cache_k: jax.Array,  # (B, Smax, Hkv·hd), the new position already written
     cache_v: jax.Array,
     slot_pos: jax.Array,  # (Smax,) int32 absolute position per slot (−big = empty)
     pos: jax.Array,  # scalar int32: position of the new token
     *,
+    window: int | jax.Array | None = None,  # 0 = full
+) -> jax.Array:
+    """The new position's attention over a (possibly ring-buffer) cache.
+
+    A cache row holds a position's kv heads side by side (Hkv·hd lanes: dense
+    on the TPU's 128-lane tiles even where hd = 64 alone is not), and the
+    heads are contracted as block-diagonal products over that row: each
+    query fills its own head's hd lanes and zeros elsewhere, and each head
+    keeps its own block of the values' product. The row is read in the
+    layout it is stored in, so a step relayouts no cache; the extra products
+    are exact zeros, and a decode step stays bound by the cache's bytes.
+    Masking uses per-slot absolute positions, so a sliding-window cache of
+    size ``window`` serves unbounded contexts. Returns (B, 1, d).
+    """
+    B = q.shape[0]
+    hd, Hkv, G = cfg.hd, cfg.n_kv_heads, cfg.q_per_kv
+    axes = decode_cache_axes(cfg, B)
+    row = Layout(major_to_minor=(0, 1, 2))
+    cache_k = with_layout_constraint(constrain(cache_k, *axes), row)
+    cache_v = with_layout_constraint(constrain(cache_v, *axes), row)
+    eye = jnp.eye(Hkv, dtype=q.dtype)
+    q_row = jnp.einsum("bhgd,hj->bjdhg", q.reshape(B, Hkv, G, hd), eye)
+    q_row = q_row.reshape(B, Hkv * hd, Hkv * G)
+    s = jnp.einsum("bkc,bcn->bnk", cache_k, q_row, preferred_element_type=jnp.float32)
+    s = _softcap(s / math.sqrt(hd), cfg.attn_softcap)
+    mask = slot_pos <= pos
+    mask &= slot_pos >= 0
+    if window is not None:
+        mask &= (window <= 0) | (slot_pos > pos - window)
+    s = jnp.where(mask[None, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    o_row = jnp.einsum("bnk,bkc->bnc", p.astype(cache_v.dtype), cache_v,
+                       preferred_element_type=jnp.float32)
+    o = jnp.einsum("bhgjd,hj->bhgd", o_row.reshape(B, Hkv, G, Hkv, hd),
+                   eye.astype(jnp.float32)).astype(cache_v.dtype)
+    out = o.reshape(B, 1, cfg.n_heads * hd)
+    return out @ use_weight(cfg, params["wo"], "heads", None)
+
+
+def decode_attention(
+    params,
+    cfg: ModelConfig,
+    x: jax.Array,  # (B, 1, d)
+    cache_k: jax.Array,  # (B, Smax, Hkv·hd) — ring buffer when Smax < ctx
+    cache_v: jax.Array,
+    slot_pos: jax.Array,  # (Smax,)
+    pos: jax.Array,
+    *,
     window: int | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """One decode step with a (possibly ring-buffer) KV cache.
+    """One decode step of one layer: project, write the new position at
+    slot ``pos % Smax``, attend (:func:`decode_attend`).
 
-    Returns (out, new_k, new_v, new_slot_pos). The new token is written at
-    slot ``pos % Smax``; masking uses per-slot absolute positions, so a
-    sliding-window cache of size `window` supports unbounded contexts
-    (long_500k runs with O(window) memory).
+    Returns (out, new_k, new_v, new_slot_pos).
     """
-    B = x.shape[0]
-    hd = cfg.hd
-    positions = jnp.full((B, 1), pos, jnp.int32)
-    q, k_new, v_new = _project_qkv(params, cfg, x, positions)
-    Smax = cache_k.shape[1]
-    slot = jnp.mod(pos, Smax)
+    q, k_new, v_new = decode_qkv(params, cfg, x, pos)
+    slot = jnp.mod(pos, cache_k.shape[1])
     cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k_new, slot, axis=1)
     cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v_new, slot, axis=1)
     slot_pos = jax.lax.dynamic_update_slice_in_dim(
         slot_pos, jnp.full((1,), pos, slot_pos.dtype), slot, axis=0
     )
-    if B == 1:  # long-context: cache sharded along sequence, not heads
-        cache_k = constrain(cache_k, None, "kv_seq", None, None)
-        cache_v = constrain(cache_v, None, "kv_seq", None, None)
-    elif cfg.decode_cache_seq_shard:
-        cache_k = constrain(cache_k, "batch", "kv_seq", None, None)
-        cache_v = constrain(cache_v, "batch", "kv_seq", None, None)
-    else:
-        cache_k = constrain(cache_k, "batch", None, "kv_heads", None)
-        cache_v = constrain(cache_v, "batch", None, "kv_heads", None)
-    Hkv, G = cfg.n_kv_heads, cfg.q_per_kv
-    qh = q.reshape(B, 1, Hkv, G, hd)
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qh, cache_k, preferred_element_type=jnp.float32)
-    s = _softcap(s / math.sqrt(hd), cfg.attn_softcap)
-    mask = slot_pos <= pos
-    mask &= slot_pos >= 0
-    if window is not None:
-        mask &= slot_pos > pos - window
-    s = jnp.where(mask[None, None, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(cache_v.dtype), cache_v)
-    out = o.reshape(B, 1, cfg.n_heads * hd) @ use_weight(cfg, params["wo"], "heads", None)
+    out = decode_attend(params, cfg, q, cache_k, cache_v, slot_pos, pos, window=window)
     return out, cache_k, cache_v, slot_pos
 
 
@@ -330,13 +373,15 @@ def fill_cache_from_prefill(
     k: jax.Array, v: jax.Array, Smax: int
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Arrange the last Smax of (B, S, ...) prefill K/V (or, for latent
-    attention, the latent and the rotary key) into ring slots.
+    attention, the latent and the rotary key) into ring slots of (B, Smax,
+    row): a position's heads side by side, as :func:`decode_attend` reads.
 
     Position p lives in slot p mod Smax. The kept positions are consecutive,
     so the ring is the zero-padded tail rotated by a static shift: a pad and
     a roll, no scatter (the TPU compiler aborts on this batched scatter).
     """
-    S = k.shape[1]
+    B, S = k.shape[:2]
+    k, v = k.reshape(B, S, -1), v.reshape(B, S, -1)
     take = min(S, Smax)
     shift = (S - take) % Smax
     ck = jnp.roll(jnp.pad(k[:, S - take :], _seq_pad(k, Smax - take)), shift, axis=1)

@@ -8,10 +8,12 @@ MoE model's leading dense layers (``first_dense_layers``) are a stack of
 their own (``params["dense_layers"]``), scanned ahead of the MoE stack
 (``params["layers"]``).
 
-Attention is GQA (:mod:`repro.models.layers`) or multi-head latent
+Attention is GQA (:mod:`repro.models.layers`), whose decode cache holds a
+position's kv heads side by side, (L, B, Smax, Hkv·hd), or multi-head latent
 attention (:mod:`repro.models.mla`, ``kv_lora_rank > 0``), whose cache holds
 the latent ``ckv`` and the shared rotary key ``kpe`` per position in place
-of per-head keys and values. An MoE model's caches also carry
+of per-head keys and values. A decode step carries either cache through its
+layer scan and writes only the new position. An MoE model's caches also carry
 ``expert_tokens`` (MoE layers, held experts + 1) int32: the (token, choice)
 pairs each held expert took in the step that wrote the cache, the last
 column those routed to other chips' experts.
@@ -248,8 +250,8 @@ def init_cache(cfg: ModelConfig, B: int, max_seq: int):
         }
     else:
         cache = {
-            "k": jnp.zeros((L, B, Smax, Hkv, hd), ly.dt(cfg)),
-            "v": jnp.zeros((L, B, Smax, Hkv, hd), ly.dt(cfg)),
+            "k": jnp.zeros((L, B, Smax, Hkv * hd), ly.dt(cfg)),
+            "v": jnp.zeros((L, B, Smax, Hkv * hd), ly.dt(cfg)),
         }
     cache["slot_pos"] = jnp.full((L, Smax), -(2**30), jnp.int32)
     cache["pos"] = jnp.int32(0)
@@ -301,52 +303,50 @@ def _decode_mla(params, cfg: ModelConfig, x, cache):
     return x, new_cache
 
 
+def _decode_dense(params, cfg: ModelConfig, x, cache):
+    """Grouped-query attention decode, as :func:`_decode_mla`: the K/V caches
+    ride in the layer scan's carry, each layer writes only its new position
+    and attends over its slice in place, and a window flag per layer
+    (sliding, or gemma2's local layers; 0 = full) masks the slots."""
+    pos = cache["pos"]
+    Smax = cache["k"].shape[2]
+    slot = jnp.mod(pos, Smax)
+    sp = cache["slot_pos"]
+    sp = jax.lax.dynamic_update_slice(sp, jnp.full((sp.shape[0], 1), pos, sp.dtype), (0, slot))
+
+    def body(carry, inp):
+        x, K, V = carry
+        p, i, w = inp
+        h = ly.rmsnorm(p["ln1"], x, cfg.norm_eps)
+        q, k_new, v_new = ly.decode_qkv(p["attn"], cfg, h, pos)
+        K = jax.lax.dynamic_update_slice(K, k_new[None], (i, 0, slot, 0))
+        V = jax.lax.dynamic_update_slice(V, v_new[None], (i, 0, slot, 0))
+        x = x + ly.decode_attend(p["attn"], cfg, q, K[i], V[i], sp[i], pos, window=w)
+        h = ly.rmsnorm(p["ln2"], x, cfg.norm_eps)
+        mlp_out, tokens = _mlp_apply(cfg, p, h)
+        return (x + mlp_out, K, V), tokens
+
+    windows = jnp.asarray(_layer_windows(cfg), jnp.int32)
+    (x, K, V), tokens = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(cfg.n_layers), windows), unroll=cfg.scan_unroll)
+    new_cache = {"k": K, "v": V, "slot_pos": sp, "pos": pos + 1}
+    if tokens is not None:
+        new_cache["expert_tokens"] = tokens
+    return x, new_cache
+
+
 def decode_step(params, cfg: ModelConfig, token, cache):
     """token: (B, 1) int32 → (logits (B, 1, V) fp32, new cache)."""
     x = ly.embed(params["embedding"], cfg, token)
     if cfg.is_mla:
         x, new_cache = _decode_mla(params, cfg, x, cache)
-        x = ly.rmsnorm(params["ln_f"], x, cfg.norm_eps)
-        return ly.logits(params["embedding"], cfg, x), new_cache
-    if "dense_layers" in params:
+    elif "dense_layers" in params:
         raise NotImplementedError("leading dense layers are served with latent attention")
-    windows = jnp.asarray(_layer_windows(cfg), jnp.int32)
-    pos = cache["pos"]
-
-    def body(x, inp):
-        p, ck, cv, sp, w = inp
-        h = ly.rmsnorm(p["ln1"], x, cfg.norm_eps)
-        window = cfg.sliding_window
-        if cfg.local_global_period:
-            # decode: window flag folded into slot_pos masking via w.
-            window = None
-        out, ck, cv, sp = ly.decode_attention(
-            p["attn"], cfg, h, ck, cv, sp, pos, window=window
-        )
-        if cfg.local_global_period:
-            # local layers additionally mask to the window.
-            out_local, ck2, cv2, sp2 = ly.decode_attention(
-                p["attn"], cfg, h, ck, cv, sp, pos, window=cfg.local_window
-            )
-            is_local = w > 0
-            out = jnp.where(is_local, out_local, out)
-        x = x + out
-        h = ly.rmsnorm(p["ln2"], x, cfg.norm_eps)
-        mlp_out, tokens = _mlp_apply(cfg, p, h)
-        if tokens is None:
-            return x + mlp_out, (ck, cv, sp)
-        return x + mlp_out, (ck, cv, sp, tokens)
-
-    x, ys = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"], cache["slot_pos"], windows),
-        unroll=cfg.scan_unroll,
-    )
+    else:
+        x, new_cache = _decode_dense(params, cfg, x, cache)
     x = ly.rmsnorm(params["ln_f"], x, cfg.norm_eps)
-    lg = ly.logits(params["embedding"], cfg, x)
-    new_cache = {"k": ys[0], "v": ys[1], "slot_pos": ys[2], "pos": pos + 1}
-    if cfg.n_experts:
-        new_cache["expert_tokens"] = ys[3]
-    return lg, new_cache
+    return ly.logits(params["embedding"], cfg, x), new_cache
 
 
 def _prefill_layer(cfg: ModelConfig, S: int, Smax: int, carry, inp):
@@ -441,14 +441,7 @@ def cache_logical_axes(cfg: ModelConfig, B: int):
         lat = (None, "batch", None, None)
         axes = {"ckv": lat, "kpe": lat, "slot_pos": (None, None), "pos": ()}
     else:
-        if B == 1:  # long-context: shard the cache sequence, not heads
-            kv = (None, None, "kv_seq", None, None)
-        elif cfg.decode_cache_seq_shard:
-            # §Perf: batch × sequence sharding = full 256-way cache split
-            # (kv_heads rarely divide the model axis; the sequence always does).
-            kv = (None, "batch", "kv_seq", None, None)
-        else:
-            kv = (None, "batch", None, "kv_heads", None)
+        kv = (None, *ly.decode_cache_axes(cfg, B))
         axes = {"k": kv, "v": kv, "slot_pos": (None, None), "pos": ()}
     if cfg.n_experts:
         axes["expert_tokens"] = (None, None)

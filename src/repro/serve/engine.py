@@ -95,6 +95,19 @@ class RoutingRecord:
 ROUTING = RoutingRecord()
 
 
+#: The cache entries a decode step rewrites in place: attention keys and
+#: values (a latent model's ``ckv`` and ``kpe``). They are donated to the
+#: step, which writes its new position into the same buffers. The rest of a
+#: cache is not: a round keeps each step's ``expert_tokens`` past the next step.
+DONATED_CACHE = ("k", "v", "ckv", "kpe")
+
+
+def split_cache(cache: dict) -> tuple[dict, dict]:
+    """(the entries donated to a decode step, the rest) of a cache."""
+    kv = {n: a for n, a in cache.items() if n in DONATED_CACHE}
+    return kv, {n: a for n, a in cache.items() if n not in DONATED_CACHE}
+
+
 def _expert_tokens(cache):
     """The routing counters an MoE model's step left in its cache, or None."""
     return cache.get("expert_tokens") if isinstance(cache, dict) else None
@@ -439,11 +452,16 @@ class ServingEngine:
 
         # The XLA module is named after this function (``jit_decode_step``);
         # the scope names its ops in the HLO metadata and the device trace.
-        def decode_step(params, token, cache):
+        def decode_step(params, token, kv, rest):
             with jax.named_scope(DECODE_SCOPE):
-                return arch.decode_step(params, token, cache)
+                return arch.decode_step(params, token, {**kv, **rest})
 
-        self._decode = jax.jit(decode_step)
+        self._decode_step = jax.jit(decode_step, donate_argnums=(2,))
+
+    def _decode(self, params, token, cache):
+        """One cached decode step: (logits, the next cache). The cache's K/V
+        (:data:`DONATED_CACHE`) are donated and must not be read again."""
+        return self._decode_step(params, token, *split_cache(cache))
 
     # -- storage integration -------------------------------------------------
 

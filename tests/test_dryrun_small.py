@@ -107,6 +107,47 @@ def test_mini_dryrun_cell_sharded_compile_and_roofline():
     assert "RESULT True True True" in out
 
 
+def test_mini_dryrun_decode_cells_sharded_compile():
+    """The decode specs of ``launch.specs`` (cache shardings from each
+    module's ``cache_logical_axes``, the cache donated) compile on a 2×4
+    mesh: batch × kv-heads, the long-context B = 1 sequence split, the
+    batch × sequence lever, gemma2's local/global layers, the hybrid's and
+    the encoder-decoder's caches."""
+    out = _run_py("""
+        import dataclasses
+        import jax, jax.numpy as jnp
+        from jax.sharding import NamedSharding
+        from repro.models.registry import Arch, get
+        from repro.models.sharding import axis_rules, spec_for
+        from repro.launch.mesh import make_auto_mesh
+        from repro.launch.specs import _cache_shardings, _specs_tree
+
+        mesh = make_auto_mesh((2, 4), ("data", "model"))
+        for name, B, seq_shard in [("qwen1.5-0.5b", 8, False), ("qwen1.5-0.5b", 1, False),
+                                   ("mixtral-8x7b", 8, True), ("gemma2-2b", 4, False),
+                                   ("zamba2-2.7b", 8, False), ("whisper-base", 8, False)]:
+            arch = get(name, smoke=True)
+            cfg = dataclasses.replace(arch.cfg, decode_cache_seq_shard=seq_shard)
+            arch = Arch(cfg=cfg, module=arch.module)
+            with mesh, axis_rules(mesh):
+                params = jax.eval_shape(lambda: arch.init(jax.random.key(0)))
+                cache = jax.eval_shape(lambda: arch.init_cache(B, 64))
+                c_specs = _cache_shardings(mesh, arch, B, cache)
+                jfn = jax.jit(
+                    arch.decode_step,
+                    in_shardings=(_specs_tree(mesh, params, arch.logical_axes()),
+                                  NamedSharding(mesh, spec_for((B, 1), ("batch", None))),
+                                  c_specs),
+                    out_shardings=(None, c_specs), donate_argnums=(2,))
+                jfn.lower(params, jax.ShapeDtypeStruct((B, 1), jnp.int32), cache).compile()
+            print("CELL", name, B, c_specs["k"].spec)
+    """)
+    cells = [ln for ln in out.splitlines() if ln.startswith("CELL")]
+    assert len(cells) == 6, out
+    assert "CELL qwen1.5-0.5b 1 PartitionSpec(None, None, 'model', None)" in cells
+    assert "CELL mixtral-8x7b 8 PartitionSpec(None, 'data', 'model', None)" in cells
+
+
 def test_dryrun_results_schema():
     """Any artifacts already produced by the sweep have the right schema."""
     d = os.path.join(ROOT, "benchmarks", "results", "dryrun")

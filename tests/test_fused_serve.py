@@ -298,7 +298,7 @@ def test_closed_loop_round_is_one_launch_and_feeds_writes():
 def test_jitted_steps_keep_module_names_under_named_scopes():
     """The device trace keys on the XLA module names ``jit_core`` (the fused
     launch) and ``jit_decode_step``; their ops carry the named scopes."""
-    from repro.serve.engine import DECODE_SCOPE, FUSED_SCOPE
+    from repro.serve.engine import DECODE_SCOPE, FUSED_SCOPE, split_cache
 
     arch = get("qwen1.5-0.5b", smoke=True)
     params = arch.init(jax.random.key(0))
@@ -318,8 +318,8 @@ def test_jitted_steps_keep_module_names_under_named_scopes():
             np.float32(2), np.float32(-1.0), params)
     finally:
         proxy.close()
-    decode = engine._decode.lower(params, jnp.zeros((2, 1), jnp.int32),
-                                  arch.init_cache(2, 32))
+    decode = engine._decode_step.lower(params, jnp.zeros((2, 1), jnp.int32),
+                                       *split_cache(arch.init_cache(2, 32)))
     for lowered, fn, scope in [(fused, "core", FUSED_SCOPE),
                                (decode, "decode_step", DECODE_SCOPE)]:
         assert lowered.as_text().startswith(f"module @jit_{fn} ")

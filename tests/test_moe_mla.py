@@ -191,7 +191,7 @@ def test_closed_loop_moe_compiles_once_and_records_routing():
     finally:
         proxy.close()
     assert srv.traces == 1, f"{srv.traces} fused compiles for 4 rounds of one bucket"
-    assert eng._decode._cache_size() == 1
+    assert eng._decode_step._cache_size() == 1
     rounds = list(ROUTING.rounds)[n0:]
     assert len(rounds) == 4
     for _, pre, dec in rounds:

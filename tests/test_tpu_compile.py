@@ -11,6 +11,10 @@ shape or a reshape) at no chip time. Covered here:
   phase's small prompt objects;
 * the ``ClosedLoopServer`` fused admission → decode → prefill launch at
   qwen1.5-0.5b widths, compiled from shapes;
+* the cached decode step of qwen1.5-0.5b at its benchmark cell's shapes
+  (16 prompts of 1024 tokens, 16 tokens out), which must keep its K/V cache
+  in place: no relayout copy of a layer's cache, the cache donated, stored
+  without lane padding, and read in the layout the fused launch writes;
 * that launch and the cached decode step of moonlight-16b-a3b at its
   benchmark cell's shapes (16 prompts of 1024 tokens, 16 of the 64 routed
   experts held, all 27 layers), which must fit one chip's 16 GB;
@@ -22,6 +26,8 @@ one process may load the TPU library, and xdist workers import every test
 file), and JAX's persistent compile cache is off while these tests run — a
 described-device compile is written to it but cannot be read back.
 """
+
+import re
 
 import numpy as np
 import pytest
@@ -122,6 +128,49 @@ def test_closed_loop_launch_compiles_at_qwen_widths(one_chip):
     assert logits.shape == (batch, 1, arch.cfg.vocab)
 
 
+def _nbytes(tree) -> int:
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+
+def _copies_of_at_least(compiled, elements: int) -> list[str]:
+    """The optimized HLO's copies and transposes with a result of at least
+    ``elements`` elements."""
+    out = []
+    for ln in compiled.as_text().splitlines():
+        m = re.search(r"= \w+\[([\d,]*)\]\S* (copy|transpose)\(", ln)
+        if m and np.prod([int(d) for d in m.group(1).split(",") if d]) >= elements:
+            out.append(ln.strip()[:200])
+    return out
+
+
+def test_qwen_decode_keeps_its_cache_in_place_on_v5e(one_chip):
+    """qwen1.5-0.5b at its cell's shapes: each layer reads its K and V where
+    the step writes them (no relayout of a layer's slice, none of the
+    stack), the donated K/V are the step's output buffers, a cache row
+    (16 kv heads of 64) fills whole 128-lane tiles, and the fused launch
+    writes the cache in the layout the decode step reads."""
+    from repro.models.registry import get
+    from repro.serve.engine import split_cache
+
+    batch, prompt_len, steps = 16, 1024, 16
+    arch = get("qwen1.5-0.5b")
+    cfg = arch.cfg
+    params = jax.eval_shape(arch.init, jax.random.key(0))
+    launch, engine = _closed_loop_launch(arch, params, batch, prompt_len, steps, one_chip)
+    kv, rest = split_cache(jax.eval_shape(lambda: arch.init_cache(batch, prompt_len + steps)))
+    token = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
+    decode = engine._decode_step.lower(*_on(one_chip, (params, token, kv, rest))).compile()
+    layer_slice = batch * (prompt_len + steps) * cfg.n_kv_heads * cfg.hd
+    assert _copies_of_at_least(decode, layer_slice) == []
+    mem = decode.memory_analysis()
+    assert mem.alias_size_in_bytes >= _nbytes(kv) == 1_635_778_560
+    cache_args = mem.argument_size_in_bytes - _nbytes((params, token, rest))
+    assert cache_args <= 1.05 * _nbytes(kv)
+    written = launch.output_formats[5]
+    read = decode.input_formats[0][2]
+    assert all(written[n] == read[n] == decode.output_formats[1][n] for n in kv)
+
+
 def test_closed_loop_moe_launch_and_decode_fit_one_v5e(one_chip):
     """moonlight-16b-a3b at its cell's shapes: 5.16 B parameters (10.33 GB)
     on the chip, the latent cache, the grouped expert dispatch's buffers."""
@@ -129,6 +178,7 @@ def test_closed_loop_moe_launch_and_decode_fit_one_v5e(one_chip):
 
     from repro.models import lm
     from repro.models.registry import Arch, get
+    from repro.serve.engine import split_cache
 
     batch, prompt_len, steps = 16, 1024, 16
     cfg = dataclasses.replace(get("moonlight-16b-a3b").cfg, experts_held=16)
@@ -136,12 +186,13 @@ def test_closed_loop_moe_launch_and_decode_fit_one_v5e(one_chip):
     params = jax.eval_shape(arch.init, jax.random.key(0))
     launch, engine = _closed_loop_launch(arch, params, batch, prompt_len, steps, one_chip)
     assert _bytes_used(launch) < 16e9  # one v5e chip holds 16 GB
-    cache = jax.eval_shape(lambda: arch.init_cache(batch, prompt_len + steps))
+    kv, rest = split_cache(jax.eval_shape(lambda: arch.init_cache(batch, prompt_len + steps)))
     assert launch.out_info[5]["ckv"].shape == (27, batch, prompt_len + steps, 512)
     assert launch.out_info[5]["expert_tokens"].shape == (26, 17)
     token = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
-    decode = engine._decode.lower(*_on(one_chip, (params, token, cache))).compile()
+    decode = engine._decode_step.lower(*_on(one_chip, (params, token, kv, rest))).compile()
     assert _bytes_used(decode) < 16e9
+    assert decode.memory_analysis().alias_size_in_bytes >= _nbytes(kv)  # the latent cache
     assert decode.out_info[0].shape == (batch, 1, cfg.vocab)
 
 

@@ -81,24 +81,6 @@ def _multiclass_metrics(art: dict, metrics: dict) -> None:
                 entry.get("p99_spread"), "stat")
 
 
-def _serve_metrics(art: dict, metrics: dict) -> None:
-    for name in ("rounds", "steps", "prompt_len"):
-        _metric(metrics, name, art.get(name), "count")
-    for rec in art.get("results") or []:
-        b = rec.get("batch")
-        _metric(metrics, f"batch{b}/fused_req_per_s",
-                rec.get("fused_req_per_s"), "wallclock", "req/s")
-        _metric(metrics, f"batch{b}/speedup", rec.get("speedup"),
-                "wallclock", "x")
-    # Timeline/SLO block from the collected post-timing pass: the pick
-    # series ignores wallclock (threshold lane on a constant backlog
-    # signal), so the convergence round is structurally deterministic;
-    # dwell is a simulation statistic.
-    slo = art.get("slo") or {}
-    _metric(metrics, "slo/settle_round", slo.get("settle_round"), "count")
-    _metric(metrics, "slo/dwell_final", slo.get("dwell_final"), "stat")
-
-
 def _shard_metrics(art: dict, metrics: dict) -> None:
     for name in ("grid", "count", "big_grid", "big_count"):
         _metric(metrics, name, art.get(name), "count")
@@ -114,7 +96,6 @@ _NORMALIZERS = {
     "repro.fleet/BENCH_fleet": _frontier_metrics,
     "repro.taskq/BENCH_taskq": _frontier_metrics,
     "repro.sched/BENCH_multiclass": _multiclass_metrics,
-    "repro.serve/BENCH_serve": _serve_metrics,
     "repro.fleet/BENCH_shard": _shard_metrics,
 }
 

@@ -27,7 +27,6 @@ from repro.coding import rs
 from repro.coding.codec import Codec
 from repro.core import PAPER_READ_3MB, RequestClass, TOFECPolicy
 from repro.kernels.gf2mm import gf2mm, ops, ref
-from repro.serve import FusedServingStep
 
 
 def bench_gf2mm(n: int = 12, k: int = 6, B: int = 16384) -> list[str]:
@@ -98,229 +97,6 @@ def bench_codec_sweep(B: int = 4096) -> list[str]:
                     )
                 )
     return rows
-
-
-def bench_fused_serve(B: int = 4096, reps: int = 5) -> list[str]:
-    """Fused vs unfused TOFEC serving step across batch sizes and backends.
-
-    Fused: ONE jitted launch runs the admission update (tofec_step_jax) and
-    the batched decode of the whole round. Unfused: the pre-fused serving
-    path — a host policy update plus one ``codec.decode`` launch per object.
-    The acceptance bar (ISSUE 2): fused ≥ 1.5x unfused at batch ≥ 8 on the
-    jnp backend. Pallas runs in interpret mode on CPU, so its wall-clock is
-    the reference environment, not TPU perf.
-    """
-    cls = RequestClass("bench", 1.0, PAPER_READ_3MB, k_max=6, r_max=2.0, n_max=12)
-    n, k = 12, 6
-    rng = np.random.default_rng(11)
-    rows_out: list[str] = []
-    for backend in ("jnp", "pallas"):
-        codec = Codec(backend)
-        step = FusedServingStep.for_class(cls, L=16, codec=codec)
-        policy = TOFECPolicy.for_classes([cls], L=16)
-        for batch in (1, 8, 32):
-            data = rng.integers(0, 256, size=(batch, k, B), dtype=np.uint8)
-            coded = np.stack([rs.encode(data[i], n, k) for i in range(batch)])
-            present = np.stack([np.sort(rng.choice(n, size=k, replace=False))
-                                for _ in range(batch)])
-            strips = np.stack([coded[i][present[i]] for i in range(batch)])
-
-            def fused_once():
-                out, _ = step.decode_batch(strips, present, n=n, k=k, q=batch)
-                return out
-
-            def unfused_once():
-                outs = []
-                for i in range(batch):
-                    policy.select(q=batch, idle=0)
-                    outs.append(np.asarray(
-                        codec.decode(strips[i], tuple(present[i]), n, k)))
-                return np.stack(outs)
-
-            # warm both paths (compilation outside the timed region)
-            np.testing.assert_array_equal(fused_once(), data)
-            np.testing.assert_array_equal(unfused_once(), data)
-
-            t0 = time.monotonic()
-            for _ in range(reps):
-                fused_once()
-            dt_fused = (time.monotonic() - t0) / reps
-
-            t0 = time.monotonic()
-            for _ in range(reps):
-                unfused_once()
-            dt_unfused = (time.monotonic() - t0) / reps
-
-            mb = batch * k * B / 2**20
-            speedup = dt_unfused / max(dt_fused, 1e-9)
-            # dt_fused is already a per-call average, so calls=1 here.
-            timer = BenchTimer(f"fused_serve_{backend}_n{n}k{k}_b{batch}", calls=1)
-            timer.elapsed = dt_fused
-            rows_out.append(
-                timer.row(
-                    f"fused={mb / dt_fused:.1f}MB/s"
-                    f"|unfused={mb / dt_unfused:.1f}MB/s"
-                    f"|speedup={speedup:.2f}x"
-                )
-            )
-    return rows_out
-
-
-
-def bench_serve_closed_loop(batches: tuple = (8, 32), rounds: int = 8,
-                            steps: int = 2) -> list[str]:
-    """Sustained closed-loop serving throughput (req/s), fused vs unfused.
-
-    Fused: :class:`ClosedLoopServer` — ONE jitted launch per round covers
-    admission update + batched MDS decode + bytes→tokens + LM prefill, and
-    the controller's pick feeds the proxy write policy. Unfused: the engine's
-    pre-fused path — proxy-side host decode, then a separate prefill launch.
-    Same store, same prompts, same generation steps; the delta is the serving
-    control loop itself. The acceptance bar (ISSUE 7): fused ≥ unfused at
-    batch 8 and 32. Writes BENCH_serve.json for the CI serve smoke leg.
-
-    The fused step gets an explicit jnp codec so the numpy codec-backend CI
-    leg can still run this benchmark (the step refuses host-only backends).
-    """
-    import json as _json
-    import os as _os
-    from benchmarks.common import RESULTS_DIR
-    from repro.coding.layout import SharedKeyLayout
-    from repro.core import FeedbackPolicy, StaticPolicy
-    from repro.models import get
-    from repro.serve import ClosedLoopServer, ServePolicy, ServingEngine
-    from repro.storage import MemoryStore, Proxy
-
-    arch = get("qwen1.5-0.5b", smoke=True)
-    params = arch.init(jax.random.key(0))
-    eng = ServingEngine(arch, params, max_seq=96)
-    # 16 KB coded objects (prompt tokens in the head, as the serving tower
-    # stores them): big enough that the storage decode path is real work —
-    # the fused step's in-launch batched decode vs the proxy's per-object
-    # host decode — small enough that a CI smoke run stays fast.
-    prompt_len = 64
-    layout = SharedKeyLayout(K=4, r=2, strip_bytes=4096)
-    cls = RequestClass("serve", layout.file_bytes / 2**20, PAPER_READ_3MB,
-                       k_max=4, r_max=2.0, n_max=8)
-    rng = np.random.default_rng(13)
-
-    rows_out: list[str] = []
-    records = []
-    for batch in batches:
-        store = MemoryStore()
-        keys = []
-        for i in range(batch):
-            toks = rng.integers(0, arch.cfg.vocab, size=(prompt_len,)).astype(np.int32)
-            ServingEngine.store_prompt(store, f"p{batch}/{i}", layout, toks)
-            keys.append(f"p{batch}/{i}")
-
-        proxy_f = Proxy(store, StaticPolicy(8, 4), L=16,
-                        write_policy=FeedbackPolicy(8, 4))
-        step = FusedServingStep.for_policy(ServePolicy.tofec(), cls, 16,
-                                           codec=Codec("jnp"))
-        srv = ClosedLoopServer(eng, proxy_f, layout, step, prompt_len=prompt_len)
-        proxy_u = Proxy(store, StaticPolicy(8, 4), L=16)
-        fused_once = lambda: srv.serve_round(keys, steps=steps)
-        unfused_once = lambda: eng.serve(proxy_u, layout, keys,
-                                         prompt_len=prompt_len, steps=steps)
-        try:
-            # Warm both paths (compilation + codec caches), then INTERLEAVE
-            # the timed rounds: host-load drift between two separate timing
-            # windows would otherwise swamp the fused-vs-unfused delta.
-            fused_once()
-            unfused_once()
-            dt_fused = dt_unfused = 0.0
-            for _ in range(rounds):
-                t0 = time.monotonic()
-                fused_once()
-                dt_fused += time.monotonic() - t0
-                t0 = time.monotonic()
-                unfused_once()
-                dt_unfused += time.monotonic() - t0
-            dt_fused /= rounds
-            dt_unfused /= rounds
-        finally:
-            proxy_f.close()
-            proxy_u.close()
-
-        fused_rps = batch / dt_fused
-        unfused_rps = batch / dt_unfused
-        records.append({
-            "batch": batch,
-            "fused_req_per_s": fused_rps,
-            "unfused_req_per_s": unfused_rps,
-            "speedup": fused_rps / unfused_rps,
-        })
-        timer = BenchTimer(f"serve_closed_loop_b{batch}", calls=1)
-        timer.elapsed = dt_fused
-        rows_out.append(timer.row(
-            f"fused={fused_rps:.1f}req/s|unfused={unfused_rps:.1f}req/s"
-            f"|speedup={fused_rps / unfused_rps:.2f}x"))
-
-    # -- collected pass (untimed): re-serve with observability ON so the
-    # per-round timeline, the SLO/convergence monitor and the live dashboard
-    # exercise the exact fused path the timed rounds ran. The collect=True
-    # variant is a separate expected compilation and never overlaps the
-    # timed windows above; the timeline rides the launch, so the only extra
-    # host sync is the one snapshot at the end.
-    slo_batch = batches[0]
-    store = MemoryStore()
-    keys = []
-    for i in range(slo_batch):
-        toks = rng.integers(0, arch.cfg.vocab, size=(prompt_len,)).astype(np.int32)
-        ServingEngine.store_prompt(store, f"slo/{i}", layout, toks)
-        keys.append(f"slo/{i}")
-    proxy = Proxy(store, StaticPolicy(8, 4), L=16,
-                  write_policy=FeedbackPolicy(8, 4))
-    step = FusedServingStep.for_policy(ServePolicy.tofec(), cls, 16,
-                                       codec=Codec("jnp"))
-    srv = ClosedLoopServer(eng, proxy, layout, step, prompt_len=prompt_len)
-    _obs.set_enabled(True)
-    try:
-        for _ in range(rounds):
-            srv.serve_round(keys, steps=steps)
-        snap = srv.timeline.snapshot()
-    finally:
-        _obs.set_enabled(None)
-        proxy.close()
-
-    spec = _obs.SLOSpec(target_s=0.5, percentile=0.99, window=4)
-    events = _obs.EventLog("serve_bench")
-    report = _obs.slo_report(snap, spec, label="serve_bench", events=events)
-    conv = report["convergence"]
-    slo_block = {
-        "settle_round": conv["settle_slot"],
-        "dwell_final": conv["dwell_final"],
-        "final_code": conv["final_code"],
-        "max_burn_rate": report["max_burn_rate"],
-        "breach_slots": report["breach_slots"],
-        "p99_last": report["percentile_last_s"],
-    }
-    rows_out.append(
-        f"serve_slo: settle_round={slo_block['settle_round']}"
-        f"|code={conv['final_code']}|dwell={conv['dwell_final']:.2f}"
-        f"|max_burn={report['max_burn_rate']:.2f}")
-
-    _os.makedirs(RESULTS_DIR, exist_ok=True)
-    artifact = {
-        "schema": "repro.serve/BENCH_serve/v1",
-        "meta": _obs.run_meta(),
-        "rounds": rounds, "steps": steps, "prompt_len": prompt_len,
-        "layout": {"K": layout.K, "N": layout.N,
-                   "strip_bytes": layout.strip_bytes},
-        "results": records,
-        "slo": slo_block,
-        "slo_report": {k: v for k, v in report.items() if k != "events"},
-    }
-    with open(_os.path.join(RESULTS_DIR, "BENCH_serve.json"), "w") as f:
-        _json.dump(artifact, f, indent=1)
-    events.write(_os.path.join(RESULTS_DIR, "serve_events.ndjson"))
-    _obs.html_report(
-        _os.path.join(RESULTS_DIR, "serve_dashboard.html"),
-        {"serve": snap}, slo=report,
-        meta={"bench": "serve_closed_loop", "batch": slo_batch,
-              "rounds": rounds, "steps": steps})
-    return rows_out
 
 
 def bench_fleet_sweep(count: int = 1024, grids: tuple = (8, 64, 256)) -> list[str]:
@@ -668,8 +444,6 @@ def bench_ckpt_encode(leaf_mb: int = 1) -> list[str]:
 ALL_KERNEL = [
     bench_gf2mm,
     bench_codec_sweep,
-    bench_fused_serve,
-    bench_serve_closed_loop,
     bench_fleet_sweep,
     bench_multiclass_sweep,
     bench_taskq_engine,

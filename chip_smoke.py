@@ -220,6 +220,7 @@ def phase_serving(clock, seed: int):
     from repro.models.registry import get
     from repro.serve import ClosedLoopServer, FusedServingStep, ServePolicy, ServingEngine
     from repro.storage import LatencyStore, MemoryStore, Proxy
+    from repro.storage.proxy import store_coded_object
 
     arch = get(MODEL)
     cfg = arch.cfg
@@ -233,7 +234,7 @@ def phase_serving(clock, seed: int):
         inner = MemoryStore()
         keys = [f"prompt/{i}" for i in range(len(prompts))]
         for key, toks in zip(keys, prompts):
-            ServingEngine.store_prompt(inner, key, layout, toks)
+            store_coded_object(inner, key, layout, toks.tobytes())
         cls = RequestClass("prompt", layout.file_bytes / MIB, PAPER_READ_3MB,
                            k_max=layout.K, r_max=float(layout.r), n_max=layout.N)
         codec = get_codec()

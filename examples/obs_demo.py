@@ -1,16 +1,16 @@
-"""Telemetry demo: every repro.obs layer over one serving loop, one exact
-task-level sweep and two profiled kernels.
+"""Telemetry demo: every repro.obs layer over one exact task-level sweep
+and two profiled kernels.
 
 Turns collection on (:func:`repro.obs.set_enabled` — the programmatic twin
-of ``REPRO_OBS=1``), runs a small closed-loop serve and a TaskqSweep grid,
-then exports everything the layer produces:
+of ``REPRO_OBS=1``), runs a small TaskqSweep grid, then exports everything
+the layer produces:
 
-* the device-folded metrics snapshots (round/request counters, picked-(n,k)
-  and idle-thread histograms, queue high-water marks) plus their Prometheus
-  text exposition;
-* the per-round / per-window **timelines** (arrival rate, backlog, picks,
-  delay-histogram deltas) and the :func:`repro.obs.slo_report` judged over
-  them — burn rate, breach events, controller pick-settling;
+* the device-folded metrics snapshot (request counters, picked-(n,k) and
+  idle-thread histograms, queue high-water marks) plus its Prometheus text
+  exposition;
+* the per-window **timeline** (arrival rate, backlog, picks, delay-histogram
+  deltas) and the :func:`repro.obs.slo_report` judged over it — burn rate,
+  breach events, controller pick-settling;
 * the launch **profiler** table — XLA cost_analysis FLOPs/bytes vs measured
   wallclock, roofline bound per compiled kernel;
 * the ASCII **dashboard** (sparkline timelines + SLO tiles + p99 exemplar
@@ -19,8 +19,7 @@ then exports everything the layer produces:
 * the per-request **flight recorder**: the sweep's slowest cell replayed
   with ``flight=True`` (aggregate engines stream, flight replays one case)
   → ``flight_trace.json`` (simulated-clock Perfetto trace, one track per
-  pool thread) + ``flight_records.ndjson`` (``repro.obs/flight/v1``), and
-  the serving loop's per-round phase ring;
+  pool thread) + ``flight_records.ndjson`` (``repro.obs/flight/v1``);
 * the shared compile-accounting snapshot across every engine touched;
 * a JAX profiler trace of the whole run: the host spans (compile / launch /
   fetch / admission / task / decode boundaries) beside the device's ops on
@@ -31,7 +30,6 @@ Run:  PYTHONPATH=src python examples/obs_demo.py [--fast] [--out DIR]
 """
 
 import argparse
-import dataclasses
 import glob
 import json
 import os
@@ -42,52 +40,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.coding.codec import Codec
-from repro.coding.layout import SharedKeyLayout
-from repro.configs.qwen1_5_0_5b import CONFIG as QWEN
-from repro.core import PAPER_READ_3MB, FeedbackPolicy, RequestClass, StaticPolicy
+from repro.core import PAPER_READ_3MB, RequestClass
 from repro.core.controller import TofecTables
 from repro.core.jax_sim import JaxSimParams, simulate_tofec_scan
 from repro.core.static_optimizer import build_class_plan
 from repro.core.traces import TraceStore
 from repro.fleet import PolicySpec, grid_cases
-from repro.models.registry import Arch, _FAMILY_MODULES
-from repro.serve import ClosedLoopServer, FusedServingStep, ServePolicy, ServingEngine
-from repro.storage import MemoryStore, Proxy
 from repro.taskq import TaskqSweep
 
 CLS = RequestClass("read3mb", 3.0, PAPER_READ_3MB, k_max=6, r_max=2.0, n_max=12)
 L = 16
-CFG = dataclasses.replace(
-    QWEN, name="obs-demo", n_layers=2, d_model=128, n_heads=4, n_kv_heads=4,
-    d_ff=256, vocab=2048,
-)
-
-
-def serve_rounds(rounds: int, steps: int) -> tuple:
-    arch = Arch(cfg=CFG, module=_FAMILY_MODULES["dense"])
-    eng = ServingEngine(arch, arch.init(jax.random.key(0)), max_seq=64)
-    prompt_len = 16
-    layout = SharedKeyLayout(K=4, r=2, strip_bytes=prompt_len)
-    store = MemoryStore()
-    rng = np.random.default_rng(0)
-    keys = []
-    for i in range(4):
-        toks = rng.integers(0, CFG.vocab, size=(prompt_len,)).astype(np.int32)
-        ServingEngine.store_prompt(store, f"p/{i}", layout, toks)
-        keys.append(f"p/{i}")
-    proxy = Proxy(store, StaticPolicy(8, 4), L=8,
-                  write_policy=FeedbackPolicy(layout.N, layout.K))
-    step = FusedServingStep.for_policy(ServePolicy.tofec(), CLS, L,
-                                       codec=Codec("jnp"))
-    server = ClosedLoopServer(eng, proxy, layout, step, prompt_len=prompt_len)
-    try:
-        for _ in range(rounds):
-            server.serve_round(keys, steps=steps)
-        return (server.metrics.snapshot(), server.timeline.snapshot(),
-                server.flight.records())
-    finally:
-        proxy.close()
 
 
 def taskq_grid(count: int) -> tuple:
@@ -103,7 +65,11 @@ def taskq_grid(count: int) -> tuple:
     # Flight zoom: replay the grid's slowest cell with the recorder on.
     worst = int(np.argmax(res.to_numpy()["total"].mean(axis=1)))
     log = sweep.replay_flight(res, dp, worst)
-    return res.metrics.snapshot(), res.timeline.snapshot(), log
+    tl = res.timeline.snapshot()
+    # The SLO monitor judges one series of slots: the slowest cell's.
+    cell = {**tl, "series": {n: v[worst] for n, v in tl["series"].items()},
+            "hists": {n: v[worst] for n, v in tl["hists"].items()}}
+    return res.metrics.snapshot(), tl, cell, log
 
 
 def profile_kernels(count: int) -> None:
@@ -142,24 +108,19 @@ def main() -> None:
     opts.python_tracer_level = 0  # spans and XLA events, no Python calls
     with jax.profiler.trace(trace_dir, create_perfetto_trace=True,
                             profiler_options=opts):
-        serve_snap, serve_tl, serve_flight = serve_rounds(
-            rounds=2 if args.fast else 4, steps=2 if args.fast else 4)
-        taskq_snap, taskq_tl, flight_log = taskq_grid(
+        taskq_snap, taskq_tl, worst_tl, flight_log = taskq_grid(
             count=128 if args.fast else 512)
         profile_kernels(count=128 if args.fast else 1024)
 
         spec = obs.SLOSpec(target_s=0.25, percentile=0.99, window=4)
         events = obs.EventLog("obs_demo")
         exemplars = flight_log.exemplars(3)
-        report = obs.slo_report(serve_tl, spec, label="obs_demo", events=events,
+        report = obs.slo_report(worst_tl, spec, label="obs_demo", events=events,
                                 exemplars=exemplars)
     (trace_path,) = glob.glob(os.path.join(trace_dir, "**", "perfetto_trace.json.gz"),
                               recursive=True)
     profile = obs.profile_snapshot()
 
-    print("== serving metrics ==")
-    print(obs.to_prometheus(serve_snap, prefix="repro",
-                            labels={"run": "obs_demo", "plane": "serve"}))
     print("== taskq metrics ==")
     print(obs.to_prometheus(taskq_snap, prefix="repro",
                             labels={"run": "obs_demo", "plane": "taskq"}))
@@ -169,22 +130,20 @@ def main() -> None:
         print(f"  {label}: traces={row['traces']} launches={row['launches']}")
 
     print("\n== dashboard ==")
-    print(obs.ascii_dashboard({"serve": serve_tl, "taskq": taskq_tl},
+    print(obs.ascii_dashboard({"taskq": taskq_tl},
                               slo=report, profile=profile,
                               exemplars=exemplars))
 
     os.makedirs(out_dir, exist_ok=True)
     snap_path = os.path.join(out_dir, "obs_metrics.json")
     with open(snap_path, "w") as f:
-        json.dump({"meta": obs.run_meta(), "serve": serve_snap,
-                   "taskq": taskq_snap,
+        json.dump({"meta": obs.run_meta(), "taskq": taskq_snap,
                    "slo": {k: v for k, v in report.items() if k != "events"},
-                   "serve_flight": serve_flight,
                    "profile": profile,
                    "compile": obs.compile_snapshot()}, f, indent=1)
     dash_path = obs.html_report(
         os.path.join(out_dir, "obs_dashboard.html"),
-        {"serve": serve_tl, "taskq": taskq_tl}, slo=report, profile=profile,
+        {"taskq": taskq_tl}, slo=report, profile=profile,
         exemplars=exemplars, meta={"run": "obs_demo", "fast": bool(args.fast)})
     events_path = events.write(os.path.join(out_dir, "obs_events.ndjson"))
     flight_trace = flight_log.write_trace(

@@ -1,23 +1,19 @@
-"""Serving demo: batched decode with erasure-coded prompt storage.
+"""Serving demo: a language model fed from erasure-coded prompt storage.
 
-Prompts live in the emulated store as Shared-Key coded objects; the proxy
-fetches them with adaptive (n, k) ranged reads under an S3-like latency
-model, tolerating injected read failures; the LM then prefills + decodes.
+Prompts live in the emulated store as Shared-Key coded objects behind an
+S3-like latency model. A ClosedLoopServer serves them in rounds: the proxy
+fetches each round's prompts with adaptive (n, k) ranged reads, and one
+jitted step covers the admission update → batched MDS decode →
+bytes→tokens → LM prefill, before greedy decoding. The controller's (n, k)
+pick is fed back into the proxy's write policy, so queued writes encode
+under the adapted code.
 
-The fetch runs twice: once on the unfused path (the proxy's decoder thread
-decodes completions on the host codec) and once through the
-fused serving step — one jitted launch running the TOFEC admission update
-AND the batched MDS decode for the whole round. The fused step runs the
-default codec when it is jitted (``REPRO_CODEC_BACKEND=jnp`` or ``pallas``,
-or the Pallas kernel a TPU defaults to) and the Pallas codec otherwise: the
-compiled kernel on a TPU, the interpreter on the CPU.
+The fused step runs the default codec when it is jitted
+(``REPRO_CODEC_BACKEND=jnp`` or ``pallas``, or the Pallas kernel a TPU
+defaults to) and the Pallas codec otherwise: the compiled kernel on a TPU,
+the interpreter on the CPU.
 
-``--closed-loop`` runs the full serving tower instead: a ClosedLoopServer
-whose single jitted step covers admission update → batched decode →
-bytes→tokens → LM prefill, with the controller's (n, k) pick fed back into
-the proxy's write policy so queued writes re-encode under the adapted code.
-
-Run:  PYTHONPATH=src python examples/serve_demo.py [--closed-loop] [--fast]
+Run:  PYTHONPATH=src python examples/serve_demo.py [--fast]
 """
 
 import argparse
@@ -42,7 +38,7 @@ from repro.serve import (
     ServePolicy,
     ServingEngine,
 )
-from repro.storage import FaultyStore, LatencyStore, MemoryStore, Proxy
+from repro.storage import LatencyStore, MemoryStore, Proxy
 from repro.storage.proxy import store_coded_object
 
 CFG = dataclasses.replace(
@@ -51,18 +47,13 @@ CFG = dataclasses.replace(
 )
 
 
-def _setup(fast: bool, p_fail: float = 0.15):
+def run(fast: bool):
     arch = Arch(cfg=CFG, module=_FAMILY_MODULES["dense"])
-    params = arch.init(jax.random.key(0))
-    eng = ServingEngine(arch, params, max_seq=96)
+    eng = ServingEngine(arch, arch.init(jax.random.key(0)), max_seq=96)
 
     prompt_len = 32
     layout = SharedKeyLayout(K=4, r=2, strip_bytes=prompt_len)
     inner = MemoryStore()
-    store = FaultyStore(
-        LatencyStore(inner, PAPER_READ_3MB, time_scale=1e-3, seed=2),
-        p_fail=p_fail, seed=3,
-    )
     rng = np.random.default_rng(1)
     keys = []
     for i in range(4 if fast else 6):
@@ -75,48 +66,11 @@ def _setup(fast: bool, p_fail: float = 0.15):
     codec = get_codec()
     if not codec.backend.jitted:  # the host numpy oracle cannot run fused
         codec = get_codec("pallas")
-    return eng, layout, inner, store, keys, cls, codec, prompt_len, rng
-
-
-def run_fused_fetch(fast: bool):
-    eng, layout, _, store, keys, cls, codec, prompt_len, _ = _setup(fast)
-    steps = 4 if fast else 8
-    fused = FusedServingStep.for_class(cls, L=8, codec=codec)
-    proxy = Proxy(store, TOFECPolicy.for_classes([cls], L=8), L=8)
-    try:
-        res = eng.serve(proxy, layout, keys, prompt_len=prompt_len, steps=steps)
-        print("generated token grid (batch × steps):")
-        print(res.tokens)
-        print("\nper-prompt storage fetch: code (n,k), delay")
-        for key, code, d in zip(keys, res.codes, res.storage_total_s):
-            print(f"  {key}: ({code[0]},{code[1]})  {d * 1e3:.1f} ms wall")
-        print(f"\n15% injected read-failure rate absorbed by erasure coding; "
-              f"{sum(r.failures for r in proxy.results)} task failures total")
-
-        fres = eng.serve(proxy, layout, keys, prompt_len=prompt_len, steps=steps,
-                         fused=fused)
-        match = np.array_equal(fres.tokens, res.tokens)
-        print(f"\nfused serving step ({codec.name} backend): one jitted launch "
-              f"ran the TOFEC admission update + batched decode of all "
-              f"{len(keys)} prompts")
-        print(f"  tokens match unfused path: {match}")
-        print(f"  controller pick for the next round: (n,k)={fres.next_code}, "
-              f"compiled traces so far: {fused.traces}")
-    finally:
-        proxy.close()
-
-
-def run_closed_loop(fast: bool):
-    # Writes must land durably for the round-trip, so no injected failures
-    # on this path (reads would shrug them off; the demo writes too).
-    eng, layout, inner, _, keys, cls, codec, prompt_len, rng = _setup(
-        fast, p_fail=0.0)
-    store = LatencyStore(inner, PAPER_READ_3MB, time_scale=1e-3, seed=2)
     steps = 4 if fast else 8
     rounds = 2 if fast else 4
     write_pol = FeedbackPolicy(layout.N, layout.K)
-    proxy = Proxy(store, TOFECPolicy.for_classes([cls], L=8), L=8,
-                  write_policy=write_pol)
+    proxy = Proxy(LatencyStore(inner, PAPER_READ_3MB, time_scale=1e-3, seed=2),
+                  TOFECPolicy.for_classes([cls], L=8), L=8, write_policy=write_pol)
     step = FusedServingStep.for_policy(ServePolicy.tofec(), cls, 8, codec=codec)
     srv = ClosedLoopServer(eng, proxy, layout, step, prompt_len=prompt_len)
     try:
@@ -128,11 +82,15 @@ def run_closed_loop(fast: bool):
             print(f"\nround {rnd}: served {len(res.served_keys)}/{len(keys)} "
                   f"prompts, controller pick (n,k)={res.next_code} "
                   f"(pushed to write policy: {write_pol.code})")
+            print("  generated token grid (batch × steps):")
+            print(res.tokens)
+            for key, code, d in zip(res.served_keys, res.codes, res.storage_total_s):
+                print(f"  {key}: read ({code[0]},{code[1]})  {d * 1e3:.1f} ms wall")
             # queue a write: it encodes under the fed-back code at the next
             # admission round — the write path follows the controller.
             payload = rng.integers(0, 256, layout.file_bytes,
                                    dtype=np.uint8).tobytes()
-            srv.put(f"out/{rnd}", payload)
+            proxy.write_async(f"out/{rnd}", layout, payload)
         proxy.flush_writes()
         wres = [r for r in proxy.results if r.op == "write"]
         print(f"\n{len(wres)} writes flushed; codes used: "
@@ -148,16 +106,9 @@ def run_closed_loop(fast: bool):
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--closed-loop", action="store_true",
-                    help="run the closed-loop serving tower (fused admission "
-                         "+ decode + prefill, write policy fed back)")
     ap.add_argument("--fast", action="store_true",
-                    help="smaller batch/steps for CI smoke runs")
-    args = ap.parse_args()
-    if args.closed_loop:
-        run_closed_loop(args.fast)
-    else:
-        run_fused_fetch(args.fast)
+                    help="fewer prompts, rounds and steps for CI smoke runs")
+    run(ap.parse_args().fast)
 
 
 if __name__ == "__main__":

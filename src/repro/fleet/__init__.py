@@ -21,6 +21,7 @@ launches:
   :mod:`repro.taskq` through the common chunked-sweep base.
 """
 
+from repro.core.controller import PolicySpec
 from repro.fleet.frontier import (
     FrontierPoint,
     capacity_estimates,
@@ -37,13 +38,9 @@ from repro.fleet.shard import (
 )
 from repro.fleet.sweep import (
     FleetSweep,
-    PolicySpec,
     SweepCase,
     SweepResult,
-    fixedk_tables,
     grid_cases,
-    policy_tables,
-    static_tables,
     tenant_cases,
 )
 from repro.fleet.workloads import (
@@ -70,9 +67,6 @@ __all__ = [
     "PolicySpec",
     "grid_cases",
     "tenant_cases",
-    "policy_tables",
-    "static_tables",
-    "fixedk_tables",
     "FrontierPoint",
     "frontier",
     "frontier_points",

@@ -32,10 +32,8 @@ import types
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-#: Finite stand-in for +inf in float32 sorts (sorts past any real delay).
-BIG = float(np.finfo(np.float32).max)
+from repro.core.controller import BIG
 
 
 def masked_percentiles(x, qs, mask=None):

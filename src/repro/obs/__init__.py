@@ -1,4 +1,5 @@
-"""repro.obs — unified telemetry across the serving tower and sweep engines.
+"""repro.obs — telemetry of the sweep engines, and the host spans and compile
+accounting every engine shares.
 
 Layers (see ROADMAP "Conventions"):
 
@@ -21,8 +22,7 @@ Layers (see ROADMAP "Conventions"):
   timeline snapshots, SLO reports, and profiler tables;
 * per-request flight recorder — :class:`FlightLog` over the exact engine's
   static ``flight=True`` records (simulated-time Chrome traces, NDJSON
-  streams, p99 exemplar mining) and :class:`FlightRing` for the serving
-  loop's per-round phase breakdown.
+  streams, p99 exemplar mining).
 
 The device-resident planes are gated on ``REPRO_OBS=1`` (or
 :func:`set_enabled`); disabled, they cost one branch per site and change no
@@ -64,7 +64,6 @@ from repro.obs.dashboard import ascii_dashboard, html_report, sparkline
 from repro.obs.flight import (
     FLIGHT_SCHEMA,
     FlightLog,
-    FlightRing,
     exemplar_panel,
     oracle_task_rows,
     write_trace_doc,
@@ -105,7 +104,6 @@ __all__ = [
     "sparkline",
     "FLIGHT_SCHEMA",
     "FlightLog",
-    "FlightRing",
     "exemplar_panel",
     "oracle_task_rows",
     "write_trace_doc",

@@ -2,8 +2,8 @@
 
 Every jitted engine used to carry its own counter dataclass
 (``FleetSweep.stats`` / ``SchedSweep`` / ``TaskqSweep``'s ``SweepStats``,
-the codec's ``CodecStats``, and the bare ``traces`` ints on
-``FusedServingStep`` / ``ClosedLoopServer``).  :class:`CompileStats` is the
+the codec's ``CodecStats``, and the bare ``traces`` int on
+``ClosedLoopServer``).  :class:`CompileStats` is the
 one implementation: the old names stay importable as thin aliases and the
 attribute APIs (``.traces``, ``.launches``, ``.cases``, ``.by_mesh``,
 ``.calls``, ``.items``, ``.reset()``) are unchanged, so existing tests and

@@ -20,10 +20,6 @@ and the departure instant for every request.  This module is the host side:
 * :meth:`FlightLog.exemplars` + :func:`exemplar_panel` — the top-K slowest
   valid requests and their task-race anatomy as an ASCII breakdown (the
   HTML twin renders in :func:`repro.obs.dashboard.html_report`).
-* :class:`FlightRing` — the serving loop's per-round flight recorder:
-  admit → decode → generate phase durations on a compacted simulated round
-  clock (rounds butt against each other, no inter-round idle), so serve
-  dashboards show where breached rounds spent their budget.
 
 Clock convention: flight traces are **simulated seconds** scaled to µs
 (``ts = sim_s * 1e6``), written as Chrome ``trace_event`` JSON by
@@ -48,10 +44,8 @@ point with ``flight=True`` and returns a :class:`FlightLog`.
 """
 from __future__ import annotations
 
-import dataclasses
 import json
 import os
-from collections import deque
 
 import numpy as np
 
@@ -314,112 +308,3 @@ def exemplar_panel(exemplars: list[dict], width: int = 44) -> str:
                 f"  t{task['lane']:02d} {thr} |{''.join(row)}| "
                 f"{task['kind']}")
     return "\n".join(lines)
-
-
-# ---------------------------------------------------------------------------
-# Serving-loop flight ring
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class RoundFlight:
-    """One serving round's phase breakdown on the compacted round clock.
-
-    ``t0`` is the round's start in simulated seconds (the cumulative sum of
-    all prior rounds' phase durations — rounds butt against each other, so
-    the trace shows budget *composition*, not host idle time).  ``phases``
-    is the ordered (name, seconds) list: admit (proxy fetch), decode (the
-    fused admission+decode+prefill launch) and generate (the token loop)."""
-
-    round: int
-    t0: float
-    phases: tuple
-    requested: int
-    served: int
-    code: tuple
-
-    @property
-    def total_s(self) -> float:
-        return float(sum(d for _, d in self.phases))
-
-
-class FlightRing:
-    """Fixed-capacity host-side ring of :class:`RoundFlight` records.
-
-    The serving twin of the taskq flight plane: the closed-loop server
-    appends one record per collected round (obs-gated, like its timeline
-    ring) and the last ``capacity`` rounds stay resident; older rounds fall
-    off the front."""
-
-    def __init__(self, capacity: int = 256, label: str = "serve"):
-        if capacity < 1:
-            raise ValueError("capacity must be >= 1")
-        self.capacity = int(capacity)
-        self.label = label
-        self._rounds: deque[RoundFlight] = deque(maxlen=self.capacity)
-        self._clock = 0.0
-        self._count = 0
-
-    def __len__(self) -> int:
-        return len(self._rounds)
-
-    def record(self, phases, *, requested: int, served: int,
-               code: tuple) -> RoundFlight:
-        """Append one round; ``phases`` is an ordered (name, seconds) list."""
-        rf = RoundFlight(
-            round=self._count, t0=self._clock,
-            phases=tuple((str(n), float(d)) for n, d in phases),
-            requested=int(requested), served=int(served), code=tuple(code),
-        )
-        self._rounds.append(rf)
-        self._clock += rf.total_s
-        self._count += 1
-        return rf
-
-    def rounds(self) -> list[RoundFlight]:
-        return list(self._rounds)
-
-    def records(self) -> list[dict]:
-        """NDJSON-ready dicts, one per retained round (oldest first)."""
-        return [{
-            "schema": FLIGHT_SCHEMA,
-            "label": self.label,
-            "round": rf.round,
-            "t0": rf.t0,
-            "total_s": rf.total_s,
-            "phases": {n: d for n, d in rf.phases},
-            "requested": rf.requested,
-            "served": rf.served,
-            "code": list(rf.code),
-        } for rf in self._rounds]
-
-    def to_trace_events(self) -> list:
-        """Round slices with nested phase slices on one simulated track."""
-        pid = 0
-        events: list = [{
-            "ph": "M", "name": "process_name", "pid": pid,
-            "args": {"name": f"flight:{self.label} (simulated round time)"},
-        }, {
-            "ph": "M", "name": "thread_name", "pid": pid, "tid": 0,
-            "args": {"name": "serve rounds"},
-        }]
-        for rf in self._rounds:
-            events.append({
-                "name": f"round{rf.round}", "ph": "X", "cat": "flight",
-                "ts": rf.t0 * 1e6, "dur": rf.total_s * 1e6,
-                "pid": pid, "tid": 0,
-                "args": {"requested": rf.requested, "served": rf.served,
-                         "code": list(rf.code)},
-            })
-            t = rf.t0
-            for name, dur in rf.phases:
-                events.append({
-                    "name": name, "ph": "X", "cat": "flight",
-                    "ts": t * 1e6, "dur": dur * 1e6, "pid": pid, "tid": 0,
-                    "args": {"round": rf.round},
-                })
-                t += dur
-        return events
-
-    def write_trace(self, path: str) -> str:
-        return write_trace_doc(path, self.to_trace_events())

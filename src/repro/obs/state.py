@@ -1,8 +1,8 @@
 """Enablement switch for the device-resident telemetry planes.
 
 ``REPRO_OBS=1`` in the environment, or :func:`set_enabled` for programmatic
-control (tests), turns on the ``collect`` variants: the metrics and timeline
-buffers (and the serving loop's flight ring) that ride the jitted launches.
+control (tests), turns on the ``collect`` variants of the sweep engines: the
+metrics and timeline buffers that ride their jitted launches.
 Host spans (:func:`repro.obs.span`) do not consult it: the JAX profiler's
 session is their switch. The flag is read at *call* time, never baked into
 module state, so flipping it mid-process works — engines that jit-cache on

@@ -12,8 +12,8 @@ modes share one type:
   per window.  The window is ``timeline_window(T_bucket)``, derived from
   the pow2 time bucket so it rides the jit cache key without ever splitting
   a bucket (two runs sharing a time bucket share a window — and a trace).
-* **ring** (the serving loop): :meth:`TimelineBuf.append` writes one slot
-  per round at ``pos % capacity``, overwriting the oldest round once the
+* **ring**: :meth:`TimelineBuf.append` writes one slot per round at
+  ``pos % capacity``, overwriting the oldest round once the
   ring wraps; :meth:`TimelineBuf.snapshot` restores oldest-first order.
 
 Delay histograms use fixed log-spaced buckets (:data:`DELAY_BINS` bins,

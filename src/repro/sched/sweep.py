@@ -25,7 +25,8 @@ import numpy as np
 
 from repro import obs
 from repro.coding.codec import pow2_bucket
-from repro.fleet.sweep import ChunkedVmapSweep, PolicySpec, policy_tables
+from repro.core.controller import PolicySpec, policy_tables
+from repro.fleet.sweep import ChunkedVmapSweep
 from repro.fleet.workloads import TenantMix
 from repro.sched.scan import DISC_FIFO, DISC_PRIORITY, DISC_WFQ
 

@@ -1,10 +1,10 @@
 from repro.serve.engine import (
+    DONATED_CACHE,
     ROUTING,
     ClosedLoopResult,
     ClosedLoopServer,
     FusedServingStep,
     ServePolicy,
-    ServeResult,
     ServeTables,
     ServingEngine,
     serve_policy_step,

@@ -39,8 +39,8 @@ queue is drained and a thread idles) is the paper's, and the admit loop
 does nothing else on the read path: a decode never holds up an injection.
 The backlog each request's policy sees is the paper's q, the requests
 submitted whose tasks are not yet on the task queue. Callers
-that want the raw chunks instead (e.g. the fused serving step in
-:mod:`repro.serve.engine`, which decodes *inside* its jitted step) pass
+that want the raw chunks instead (the serving round in
+:mod:`repro.serve.engine`, which decodes *inside* its fused launch) pass
 ``raw=True``; those requests skip proxy-side decode and return their
 surviving chunks + indices in :attr:`RequestResult.chunks`.
 

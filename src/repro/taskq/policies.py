@@ -10,7 +10,7 @@ heterogeneous mix of threshold and greedy points compiles once:
 
 * ``POL_TABLE`` — the threshold form ``1 + #{h > q̄}`` shared with the fleet
   (:func:`repro.core.controller.tofec_threshold_step`), covering TOFEC,
-  static codes and fixed-k via :func:`repro.fleet.sweep.policy_tables`.
+  static codes and fixed-k via :func:`repro.core.controller.policy_tables`.
 * ``POL_GREEDY`` — §V-A's Greedy heuristic, previously exiled to the host
   event simulator because it needs the instantaneous idle-thread count the
   fluid scan cannot provide. :func:`greedy_select` is its traceable form,
@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.fleet.sweep import PolicySpec, policy_tables
+from repro.core.controller import PolicySpec, policy_tables
 
 #: Per-grid-point policy ids (runtime data, never a static arg).
 POL_TABLE = 0
@@ -75,7 +75,7 @@ class EncodedPolicy:
 
 def encode_policy(spec: PolicySpec, cls, L: int, hk_len: int, hn_len: int,
                   plan=None) -> EncodedPolicy:
-    """Resolve a :class:`repro.fleet.sweep.PolicySpec` for the task engine."""
+    """Resolve a :class:`repro.core.controller.PolicySpec` for the task engine."""
     h_k = np.zeros(hk_len, np.float32)
     h_n = np.zeros(hn_len, np.float32)
     if spec.kind == "greedy":

@@ -21,6 +21,7 @@ from repro.core import (
     build_class_plan,
     tofec_threshold_step,
 )
+from repro.core.controller import fixedk_tables, static_tables
 from repro.core.jax_sim import JaxSimParams, simulate_tofec_scan
 from repro.core.simulator import piecewise_poisson_arrivals, poisson_arrivals, simulate
 from repro.core.traces import TraceSampler
@@ -35,10 +36,8 @@ from repro.fleet import (
     TenantMix,
     capacity_estimates,
     convergence_stats,
-    fixedk_tables,
     frontier_points,
     grid_cases,
-    static_tables,
     tenant_cases,
     write_fleet_artifact,
 )

@@ -1,7 +1,7 @@
 """Per-request flight recorder: event-level oracle parity at the pinned
 parity grid points, flight-off bit-identity, exemplar-miner determinism
-under padding, one-case replay equality with the sweep cell, the serving
-FlightRing, and the satellite window/no-data regression guards."""
+under padding, one-case replay equality with the sweep cell, and the satellite
+window/no-data regression guards."""
 
 import json
 
@@ -17,7 +17,6 @@ from repro.obs.flight import (
     FLIGHT_SCHEMA,
     KINDS,
     FlightLog,
-    FlightRing,
     exemplar_panel,
     oracle_task_rows,
 )
@@ -275,38 +274,6 @@ def test_slo_report_links_exemplars(small):
     assert [e["req"] for e in report["exemplars"]] == [e["req"] for e in ex]
     breach = [e for e in report["events"].events if e["kind"] == "slo_breach"]
     assert breach and breach[0]["exemplar_reqs"] == [e["req"] for e in ex]
-
-
-# ---------------------------------------------------------------------------
-# Serving FlightRing
-# ---------------------------------------------------------------------------
-
-
-def test_flight_ring_compacted_clock_and_eviction(tmp_path):
-    ring = FlightRing(capacity=2, label="serve")
-    ring.record([("admit", 0.1), ("decode", 0.2)],
-                requested=4, served=4, code=(8, 4))
-    ring.record([("admit", 0.3)], requested=4, served=3, code=(8, 4))
-    ring.record([("admit", 0.5)], requested=4, served=4, code=(12, 6))
-    assert len(ring) == 2  # oldest round fell off the front
-    r1, r2 = ring.rounds()
-    assert (r1.round, r2.round) == (1, 2)
-    # Compacted simulated clock: rounds butt against each other and the
-    # clock keeps counting past evicted rounds.
-    assert r1.t0 == pytest.approx(0.3) and r2.t0 == pytest.approx(0.6)
-    assert r1.total_s == pytest.approx(0.3)
-
-    recs = ring.records()
-    assert all(r["schema"] == FLIGHT_SCHEMA for r in recs)
-    assert recs[-1]["code"] == [12, 6] and recs[-1]["phases"] == {"admit": 0.5}
-
-    path = ring.write_trace(str(tmp_path / "serve_flight.json"))
-    doc = json.load(open(path))
-    slices = [e for e in doc["traceEvents"] if e["ph"] == "X"]
-    assert {e["name"] for e in slices} == {"round1", "round2", "admit"}
-
-    with pytest.raises(ValueError):
-        FlightRing(capacity=0)
 
 
 # ---------------------------------------------------------------------------

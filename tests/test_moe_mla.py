@@ -165,6 +165,7 @@ def test_closed_loop_moe_compiles_once_and_records_routing():
     from repro.core import PAPER_READ_3MB, RequestClass, StaticPolicy
     from repro.serve import ROUTING, ClosedLoopServer, FusedServingStep, ServePolicy, ServingEngine
     from repro.storage import MemoryStore, Proxy
+    from repro.storage.proxy import store_coded_object
 
     cfg = dataclasses.replace(get("moonlight-16b-a3b", smoke=True).cfg,
                               expert_first=2, experts_held=4)
@@ -177,7 +178,7 @@ def test_closed_loop_moe_compiles_once_and_records_routing():
     rng = np.random.default_rng(8)
     for i in range(4):
         toks = rng.integers(0, cfg.vocab, (plen,)).astype(np.int32)
-        ServingEngine.store_prompt(store, f"p/{i}", layout, toks)
+        store_coded_object(store, f"p/{i}", layout, toks.tobytes())
         keys.append(f"p/{i}")
     cls = RequestClass("prompt", layout.file_bytes / 2**20, PAPER_READ_3MB,
                        k_max=4, r_max=2.0, n_max=8)

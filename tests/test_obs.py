@@ -192,76 +192,37 @@ def test_taskq_scan_entry_point_collect_arg(obs_off):
 
 
 # ---------------------------------------------------------------------------
-# Closed-loop serving: device metrics ride the fused step
+# Closed-loop serving: the round's spans in the profiler's trace
 # ---------------------------------------------------------------------------
 
 
-def _serve_tokens(rounds=2, steps=2):
+def test_closed_loop_metrics_invariant_and_exact(tmp_path):
+    """A round writes ``serve.round``, ``serve.fetch``, ``serve.launch`` and
+    ``serve.generate`` into the profiler's trace, one ``serve.round`` per
+    round, each phase inside its round."""
     import jax
 
-    from repro.coding.codec import Codec
-    from repro.coding.layout import SharedKeyLayout
-    from repro.core import FeedbackPolicy, StaticPolicy
     from repro.models import get
-    from repro.serve import ClosedLoopServer, FusedServingStep, ServePolicy, ServingEngine
-    from repro.storage import MemoryStore, Proxy
+
+    from serve_helpers import closed_loop
 
     arch = get("qwen1.5-0.5b", smoke=True)
     params = arch.init(jax.random.key(2))
-    eng = ServingEngine(arch, params, max_seq=64)
-    prompt_len = 16
-    layout = SharedKeyLayout(K=4, r=2, strip_bytes=prompt_len)
-    store = MemoryStore()
-    rng = np.random.default_rng(6)
-    keys = []
-    for i in range(3):
-        toks = rng.integers(0, arch.cfg.vocab, size=(prompt_len,)).astype(np.int32)
-        ServingEngine.store_prompt(store, f"p/{i}", layout, toks)
-        keys.append(f"p/{i}")
-    proxy = Proxy(store, StaticPolicy(8, 4), L=8,
-                  write_policy=FeedbackPolicy(layout.N, layout.K))
-    step = FusedServingStep.for_policy(ServePolicy.tofec(), CLS, L,
-                                       codec=Codec("jnp"))
-    server = ClosedLoopServer(eng, proxy, layout, step, prompt_len=prompt_len)
-    try:
-        results = [server.serve_round(keys, steps=steps) for _ in range(rounds)]
-        return [np.asarray(r.tokens) for r in results], server
-    finally:
-        proxy.close()
-
-
-def test_closed_loop_metrics_invariant_and_exact(tmp_path):
-    obs.set_enabled(False)
-    try:
-        toks_off, server_off = _serve_tokens()
-    finally:
-        obs.set_enabled(None)
-    obs.set_enabled(True)
-    try:
+    prompts = np.random.default_rng(6).integers(0, arch.cfg.vocab, (3, 16))
+    with closed_loop(arch, params, prompts, max_seq=64, request_class=CLS) as (srv, keys):
+        srv.serve_round(keys, steps=2)  # compile outside the trace
         with profiled(tmp_path) as events:
-            toks_on, server_on = _serve_tokens()
-        # Generated tokens bit-identical with collection on; still one trace.
-        for a, b in zip(toks_off, toks_on):
-            np.testing.assert_array_equal(a, b)
-        assert server_on.traces == server_off.traces == 1
-        assert server_off.metrics is None
-        snap = server_on.metrics.snapshot()
-        c = snap["counters"]
-        assert c["serve_rounds"] == 2
-        assert c["serve_requested"] == 2 * 3
-        assert c["serve_served"] == 2 * 3
-        assert c["serve_decode_errors"] == 0
-        assert sum(snap["hists"]["serve_batch"]) == 2
-        assert sum(snap["hists"]["serve_pick_n"]) == 2
-        assert snap["highs"]["serve_q_hi"] >= 0.0
-        # The round's host spans are in the profiler's trace, one per round.
-        names = {ev[0] for ev in events}
-        assert {"serve.round", "serve.fetch", "serve.launch"} <= names
-        assert len(named(events, "serve.round")) == 2
-        # Prometheus exposition of the same snapshot is well-formed.
-        assert "repro_serve_rounds_total 2" in obs.to_prometheus(snap)
-    finally:
-        obs.set_enabled(None)
+            for _ in range(2):
+                srv.serve_round(keys, steps=2)
+    rounds = named(events, "serve.round")
+    assert len(rounds) == 2
+    for phase in ("serve.fetch", "serve.launch", "serve.generate"):
+        spans = named(events, phase)
+        assert len(spans) == 2, phase
+        for (_, r0, r1, _), (_, p0, p1, _) in zip(sorted(rounds, key=lambda e: e[1]),
+                                                  sorted(spans, key=lambda e: e[1])):
+            assert r0 <= p0 <= p1 <= r1, phase
+    assert srv.traces == 1
 
 
 # ---------------------------------------------------------------------------
@@ -504,37 +465,8 @@ def test_sweep_timeline_rejects_bad_window():
 
 
 # ---------------------------------------------------------------------------
-# Serve timeline + SLO/convergence monitor
+# SLO/convergence monitor
 # ---------------------------------------------------------------------------
-
-
-def test_serve_timeline_and_slo_report():
-    obs.set_enabled(True)
-    try:
-        toks, server = _serve_tokens(rounds=3)
-        assert server.traces == 1  # the collect variant still compiles once
-        snap = server.timeline.snapshot()
-        assert snap["window"] == 1 and snap["slots"] == 3
-        np.testing.assert_array_equal(snap["series"]["served"], [3, 3, 3])
-        np.testing.assert_array_equal(snap["hists"]["delay"].sum(axis=1),
-                                      [3, 3, 3])
-        assert (snap["series"]["pick_n"] >= snap["series"]["pick_k"]).all()
-        spec = obs.SLOSpec(target_s=60.0, percentile=0.99, window=2)
-        report = obs.slo_report(snap, spec, label="t")
-        conv = report["convergence"]
-        assert conv["settled"] and 0 <= conv["settle_slot"] < 3
-        assert conv["dwell_final"] > 0
-        assert report["max_burn_rate"] == 0.0  # nothing violates a 60 s target
-        assert report["percentile_last_s"] > 0
-        kinds = [e["kind"] for e in report["events"].events]
-        assert "controller_converged" in kinds and "slo_breach" not in kinds
-    finally:
-        obs.set_enabled(None)
-
-
-def test_serve_timeline_absent_when_disabled(obs_off):
-    _, server = _serve_tokens(rounds=1)
-    assert server.timeline is None
 
 
 def test_slo_burn_rate_and_breach_events(obs_on, tmp_path):
@@ -699,36 +631,3 @@ def test_html_report_self_contained(obs_on, tmp_path):
     assert "<script" in html
     # Self-contained: no external fetches.
     assert "https://" not in html and "http://" not in html
-
-
-# ---------------------------------------------------------------------------
-# Perf gate: serve SLO fields
-# ---------------------------------------------------------------------------
-
-
-def test_gate_serve_slo_fields(tmp_path):
-    from benchmarks import gate
-
-    art = {
-        "schema": "repro.serve/BENCH_serve/v1",
-        "rounds": 2, "steps": 2, "prompt_len": 16,
-        "results": [{"batch": 4, "fused_req_per_s": 100.0, "speedup": 1.1}],
-        "slo": {"settle_round": 1, "dwell_final": 0.5,
-                "max_burn_rate": 0.0, "p99_last": 0.02},
-    }
-    m = gate.normalize(art)
-    # Settle round is structurally deterministic -> count class; dwell is a
-    # simulation statistic -> stat class (±10%).
-    assert m["slo/settle_round"]["kind"] == "count"
-    assert m["slo/dwell_final"]["kind"] == "stat"
-    res_dir, base_dir = tmp_path / "res", tmp_path / "base"
-    res_dir.mkdir()
-    (res_dir / "BENCH_serve.json").write_text(json.dumps(art))
-    gate.update(str(res_dir), str(base_dir))
-    assert gate.check(str(res_dir), str(base_dir)) == 0
-    # Settle-round drift fails exactly; dwell within tolerance passes.
-    drift = dict(art, slo=dict(art["slo"], settle_round=2, dwell_final=0.52))
-    (res_dir / "BENCH_serve.json").write_text(json.dumps(drift))
-    fails, warns, notes = gate.check_file(
-        str(res_dir / "BENCH_serve.json"), str(base_dir / "BENCH_serve.json"))
-    assert len(fails) == 1 and "settle_round" in fails[0]

@@ -1,58 +1,50 @@
-"""Serving engine: storage-fed prompts → prefill → batched decode."""
+"""Serving engine: storage-fed prompts → prefill → batched decode, through
+the one served path, ``ClosedLoopServer.serve_round``."""
 
 import numpy as np
 import pytest
 
+from repro.coding.codec import Codec
 from repro.coding.layout import SharedKeyLayout
 from repro.core import StaticPolicy
 from repro.models import get
-from repro.serve import ServingEngine
-from repro.storage import MemoryStore, Proxy
+from repro.serve import ClosedLoopServer, FusedServingStep, ServePolicy, ServingEngine
+from repro.storage import FaultyStore, MemoryStore, Proxy
 
 import jax
 import jax.numpy as jnp
+
+from serve_helpers import closed_loop, greedy_reference, prompt_class
+
+
+def _prompts(arch, rows, plen, seed):
+    return np.random.default_rng(seed).integers(0, arch.cfg.vocab, (rows, plen)).astype(np.int32)
 
 
 def test_generate_shapes_and_determinism():
     arch = get("qwen1.5-0.5b", smoke=True)
     params = arch.init(jax.random.key(0))
-    eng = ServingEngine(arch, params, max_seq=64)
-    rng = np.random.default_rng(0)
-    prompts = rng.integers(0, arch.cfg.vocab, size=(3, 8)).astype(np.int32)
-    out1 = eng.generate(prompts, steps=5)
-    out2 = eng.generate(prompts, steps=5)
-    assert out1.shape == (3, 5)
-    np.testing.assert_array_equal(out1, out2)
+    with closed_loop(arch, params, _prompts(arch, 3, 8, 0), max_seq=64) as (srv, keys):
+        out1 = srv.serve_round(keys, steps=5)
+        out2 = srv.serve_round(keys, steps=5)
+    assert out1.tokens.shape == (3, 5)
+    np.testing.assert_array_equal(out1.tokens, out2.tokens)
 
 
 def test_serve_via_erasure_coded_prompt_storage():
     arch = get("qwen1.5-0.5b", smoke=True)
     params = arch.init(jax.random.key(1))
-    eng = ServingEngine(arch, params, max_seq=64)
-
-    prompt_len = 16
-    layout = SharedKeyLayout(K=4, r=2, strip_bytes=prompt_len)  # 4·16B strips
-    store = MemoryStore()
-    rng = np.random.default_rng(2)
-    keys = []
-    truth = []
-    for i in range(3):
-        toks = rng.integers(0, arch.cfg.vocab, size=(prompt_len,)).astype(np.int32)
-        key = f"prompt/{i}"
-        ServingEngine.store_prompt(store, key, layout, toks)
-        keys.append(key)
-        truth.append(toks)
-
-    proxy = Proxy(store, StaticPolicy(4, 2), L=8)
-    try:
-        res = eng.serve(proxy, layout, keys, prompt_len=prompt_len, steps=4)
-        assert res.tokens.shape == (3, 4)
-        assert all(c == (4, 2) for c in res.codes)
-        # Cross-check: direct generation from the ground-truth prompts.
-        direct = eng.generate(np.stack(truth), steps=4)
-        np.testing.assert_array_equal(res.tokens, direct)
-    finally:
-        proxy.close()
+    truth = _prompts(arch, 3, 16, 2)
+    with closed_loop(arch, params, truth, max_seq=64,
+                     read_policy=StaticPolicy(4, 2)) as (srv, keys):
+        res = srv.serve_round(keys, steps=4)
+    assert res.tokens.shape == (3, 4)
+    assert res.ok == [True] * 3 and res.served_keys == keys
+    assert all(c == (4, 2) for c in res.codes)
+    np.testing.assert_array_equal(np.asarray(res.prompts)[:3], truth)
+    # Cross-check: plain generation from the ground-truth prompts.
+    np.testing.assert_array_equal(res.tokens,
+                                  greedy_reference(arch, params, truth, 4, max_seq=64))
 
 
 def test_decode_step_donates_only_the_kv_cache():
@@ -74,53 +66,35 @@ def test_decode_step_donates_only_the_kv_cache():
 
 @pytest.mark.parametrize("name", ["qwen1.5-0.5b", "mixtral-8x7b"])
 def test_generate_matches_an_undonated_decode_loop(name):
+    """The round's tokens, decoded with the K/V cache donated step to step,
+    equal an undonated ``arch.prefill`` + ``arch.decode_step`` loop."""
     arch = get(name, smoke=True)
     params = arch.init(jax.random.key(4))
-    eng = ServingEngine(arch, params, max_seq=32)
-    prompts = np.random.default_rng(4).integers(0, arch.cfg.vocab, (3, 12)).astype(np.int32)
+    prompts = _prompts(arch, 3, 12, 4)
     steps = 12  # mixtral's window of 8 slots wraps
-    logits, cache = jax.jit(lambda p, b: arch.prefill(p, b, max_seq=32))(
-        params, {"tokens": jnp.asarray(prompts)})
-    decode = jax.jit(arch.decode_step)
-    want = []
-    for _ in range(steps):
-        tok = jnp.argmax(logits, axis=-1).astype(np.int32)
-        want.append(np.asarray(tok)[:, 0])
-        logits, cache = decode(params, tok, cache)
-    np.testing.assert_array_equal(eng.generate(prompts, steps), np.stack(want, axis=1))
+    with closed_loop(arch, params, prompts, max_seq=32) as (srv, keys):
+        res = srv.serve_round(keys, steps=steps)
+    np.testing.assert_array_equal(res.tokens,
+                                  greedy_reference(arch, params, prompts, steps, max_seq=32))
 
 
 def test_closed_loop_moe_round_keeps_routing_counters_readable():
     """A closed-loop round of an MoE model donates each step's K/V cache to
     the next step, and reads every step's routing counters after its loop."""
-    from repro.coding.codec import Codec
-    from repro.core import PAPER_READ_3MB, RequestClass
-    from repro.serve import ROUTING, ClosedLoopServer, FusedServingStep, ServePolicy
+    from repro.serve import ROUTING
 
     arch = get("mixtral-8x7b", smoke=True)
     cfg = arch.cfg
-    eng = ServingEngine(arch, arch.init(jax.random.key(5)), max_seq=24)
-    plen, steps, keys = 16, 12, []
-    layout = SharedKeyLayout(K=4, r=2, strip_bytes=plen)
-    store = MemoryStore()
-    rng = np.random.default_rng(5)
-    for i in range(4):
-        ServingEngine.store_prompt(store, f"p/{i}", layout,
-                                   rng.integers(0, cfg.vocab, (plen,)).astype(np.int32))
-        keys.append(f"p/{i}")
-    cls = RequestClass("prompt", layout.file_bytes / 2**20, PAPER_READ_3MB,
-                       k_max=4, r_max=2.0, n_max=8)
-    proxy = Proxy(store, StaticPolicy(8, 4), L=8)
-    step = FusedServingStep.for_policy(ServePolicy.tofec(), cls, 16, codec=Codec("jnp"))
-    srv = ClosedLoopServer(eng, proxy, layout, step, prompt_len=plen)
+    params = arch.init(jax.random.key(5))
+    plen, steps = 16, 12
     n0 = len(ROUTING.rounds)
-    try:
+    with closed_loop(arch, params, _prompts(arch, 4, plen, 5), max_seq=24) as (srv, keys):
         res = srv.serve_round(keys, steps=steps)
         again = srv.serve_round(keys, steps=steps)
-    finally:
-        proxy.close()
     np.testing.assert_array_equal(res.tokens, again.tokens)
-    np.testing.assert_array_equal(res.tokens, eng.generate(np.asarray(res.prompts)[:4], steps))
+    np.testing.assert_array_equal(
+        res.tokens, greedy_reference(arch, params, np.asarray(res.prompts)[:4], steps,
+                                     max_seq=24))
     rounds = list(ROUTING.rounds)[n0:]
     assert len(rounds) == 2
     for _, pre, dec in rounds:
@@ -128,3 +102,66 @@ def test_closed_loop_moe_round_keeps_routing_counters_readable():
         assert (pre.sum(axis=1) == 4 * plen * cfg.top_k).all()
         assert dec.shape == (steps - 1, cfg.n_moe_layers, cfg.n_experts + 1)
         assert (dec.sum(axis=2) == 4 * cfg.top_k).all()
+
+
+def test_round_drops_reads_that_failed_beyond_n_minus_k():
+    """A prompt whose every chunk read fails leaves the round: ``ok`` masks
+    it, and ``served_keys``, ``tokens`` and ``prompts`` hold the served rows
+    in order."""
+    arch = get("qwen1.5-0.5b", smoke=True)
+    params = arch.init(jax.random.key(6))
+    truth = _prompts(arch, 4, 16, 6)
+    faulty = []
+
+    def lossy(inner):
+        faulty.append(FaultyStore(inner))
+        return faulty[0]
+
+    with closed_loop(arch, params, truth, max_seq=32, store=lossy) as (srv, keys):
+        faulty[0].lose_object(keys[1])
+        faulty[0].lose_object(keys[3])
+        res = srv.serve_round(keys, steps=3)
+    assert res.ok == [True, False, True, False]
+    assert res.served_keys == [keys[0], keys[2]]
+    assert res.tokens.shape == (2, 3) and len(res.codes) == 2
+    np.testing.assert_array_equal(np.asarray(res.prompts)[:2], truth[[0, 2]])
+    np.testing.assert_array_equal(
+        res.tokens, greedy_reference(arch, params, truth[[0, 2]], 3, max_seq=32))
+
+
+def test_round_with_every_read_failed_raises():
+    arch = get("qwen1.5-0.5b", smoke=True)
+    params = arch.init(jax.random.key(7))
+    with closed_loop(arch, params, _prompts(arch, 2, 16, 7), max_seq=32,
+                     store=lambda inner: FaultyStore(inner, p_fail=1.0)) as (srv, keys):
+        with pytest.raises(RuntimeError, match="all 2 prompt fetches failed"):
+            srv.serve_round(keys, steps=2)
+    assert srv.traces == 0  # nothing was launched
+
+
+def test_server_rejects_prompts_longer_than_the_layout():
+    arch = get("qwen1.5-0.5b", smoke=True)
+    layout = SharedKeyLayout(K=4, r=2, strip_bytes=16)  # 64 bytes: 16 tokens
+    step = FusedServingStep.for_policy(ServePolicy.tofec(), prompt_class(layout), 16,
+                                       codec=Codec("jnp"))
+    proxy = Proxy(MemoryStore(), StaticPolicy(8, 4), L=8)
+    try:
+        eng = ServingEngine(arch, arch.init(jax.random.key(8)), max_seq=32)
+        ClosedLoopServer(eng, proxy, layout, step, prompt_len=16)
+        with pytest.raises(ValueError, match="prompt_len 17 needs 68 bytes"):
+            ClosedLoopServer(eng, proxy, layout, step, prompt_len=17)
+    finally:
+        proxy.close()
+
+
+def test_padding_rows_do_not_leak_into_served_tokens():
+    """A round of 3 runs at the padded bucket batch 4: its requests' greedy
+    tokens equal theirs in a full round of 4."""
+    arch = get("qwen1.5-0.5b", smoke=True)
+    params = arch.init(jax.random.key(9))
+    with closed_loop(arch, params, _prompts(arch, 4, 16, 9), max_seq=32) as (srv, keys):
+        three = srv.serve_round(keys[:3], steps=6)
+        four = srv.serve_round(keys, steps=6)
+    assert srv.traces == 1  # 3 and 4 share the batch bucket 4
+    assert three.tokens.shape == (3, 6)
+    np.testing.assert_array_equal(three.tokens, four.tokens[:3])

@@ -14,7 +14,8 @@ from repro.core import PAPER_READ_3MB, RequestClass, StaticPolicy, TOFECPolicy, 
 from repro.core.controller import GreedyPolicy
 from repro.core.simulator import simulate
 from repro.core.traces import TraceStore
-from repro.fleet import PolicySpec, grid_cases, policy_tables
+from repro.core.controller import policy_tables
+from repro.fleet import PolicySpec, grid_cases
 from repro.fleet.stats import masked_percentiles
 from repro.taskq import (
     TaskqSweep,

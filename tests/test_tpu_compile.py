@@ -102,7 +102,7 @@ def _closed_loop_launch(arch, params, batch, prompt_len, steps, sharding):
         mats = codec.decode_mats(present, layout.N, layout.K)
         rows = np.zeros((batch, layout.K, layout.strip_bytes), np.uint8)
         mats_p, rows_p, bkey = codec.pad_to_bucket("dec", mats, rows, layout.N, layout.K)
-        fn = srv._fn(("pfd", *bkey, prompt_len, layout.strip_bytes, False))
+        fn = srv._fn(("pfd", *bkey, prompt_len, layout.strip_bytes))
         args = (step.tables, step.carry, codec.backend.prep_mats(mats_p), rows_p,
                 np.float32(batch), np.float32(-1.0), params)
         return fn.lower(*_on(sharding, args)).compile(), engine
